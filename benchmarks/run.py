@@ -21,6 +21,7 @@ import time
 import traceback
 
 from benchmarks.common import HEADER
+from repro.utils.compile_cache import enable_compile_cache
 
 BENCHES = [
     ("fig1+2_logreg", "benchmarks.bench_logreg"),
@@ -57,6 +58,7 @@ def main(argv=None) -> int:
         help="also write rows as JSON (a directory gets BENCH_<timestamp>.json)",
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     timestamp = time.strftime("%Y%m%d_%H%M%S")
     print(HEADER)

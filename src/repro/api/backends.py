@@ -42,7 +42,7 @@ from typing import Any, Dict, Optional, Protocol, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.api.sampling import ShardKernel, _shard_axes, make_shard_kernel
+from repro.api.sampling import ShardKernel, _shard_axes, chain_mesh, make_shard_kernel
 from repro.models.bayes import BayesModel
 from repro.samplers.adaptation import warmup_chain
 
@@ -277,7 +277,6 @@ class MeshChunkBackend:
     def __init__(self, model: BayesModel, sk: ShardKernel, axes, shards,
                  mesh_shape: Tuple[int, int], *, burn_in, warmup, step_size,
                  check_hlo: bool, cache_key: Tuple):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         need = int(mesh_shape[0]) * int(mesh_shape[1])
@@ -291,7 +290,7 @@ class MeshChunkBackend:
             )
         self.cache_key = cache_key
         self.mesh_shape = tuple(int(x) for x in mesh_shape)
-        self.mesh = jax.make_mesh(self.mesh_shape, ("data", "model"))
+        self.mesh = chain_mesh(self.mesh_shape, ("data", "model"))
         self._check_hlo = check_hlo
         self._checked: set = set()
         self._n_checked = 0
@@ -310,16 +309,16 @@ class MeshChunkBackend:
             functools.partial(_chunk_one, sk), in_axes=(axes, 0, 0, 0, 0)
         )
         self.setup = jax.jit(
-            shard_map(
+            jax.shard_map(
                 setup_v,
                 mesh=self.mesh,
                 in_specs=(self._shard_specs, P("data"), P("data")),
                 out_specs=P("data"),
-                check_rep=False,
+                check_vma=False,
             )
         )
         self._chunk = jax.jit(
-            shard_map(
+            jax.shard_map(
                 chunk_v,
                 mesh=self.mesh,
                 in_specs=(
@@ -327,7 +326,7 @@ class MeshChunkBackend:
                     P("data"),
                 ),
                 out_specs=P("data"),
-                check_rep=False,
+                check_vma=False,
             )
         )
 

@@ -303,6 +303,9 @@ def main(argv=None) -> Optional[Dict[str, Any]]:
         )
 
     from repro.api.spec import RunSpec  # after initialize — see note above
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     spec = RunSpec(
         model=args.model, sampler=args.sampler, combiner=args.combiner,

@@ -55,6 +55,7 @@ from repro.api.pipeline import (
     resolve_metric,
 )
 from repro.api.sampling import (
+    chain_mesh,
     is_padded,
     _shard_axes,
     make_shard_kernel,
@@ -62,6 +63,7 @@ from repro.api.sampling import (
 )
 from repro.core.subposterior import partition_data
 from repro.models.bayes import get_model
+from repro.utils.compile_cache import enable_compile_cache
 
 Signature = Tuple[Any, ...]
 
@@ -202,7 +204,6 @@ def _fanout_sample(
     cells must stay independent on the mesh. Returns the program count.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     from repro.distributed.epmcmc import assert_no_cross_chain_collectives
 
@@ -235,7 +236,7 @@ def _fanout_sample(
              (shards, counts, keys, jnp.float32(spec.step_size)))
         )
 
-    mesh = jax.make_mesh((ndev,), ("data",))
+    mesh = chain_mesh((ndev,), ("data",))
     sharding = NamedSharding(mesh, P("data"))
     n_programs = 0
     for sig, cells in groups.items():
@@ -246,10 +247,10 @@ def _fanout_sample(
         inputs = [c[4] for c in cells] + [cells[-1][4]] * (pad_to - n_cells)
         stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *inputs)
         stacked = jax.tree.map(lambda x: jax.device_put(x, sharding), stacked)
-        fan = shard_map(
+        fan = jax.shard_map(
             jax.vmap(raw), mesh=mesh,
             in_specs=(P("data"),) * 4, out_specs=P("data"),
-            check_rep=False,
+            check_vma=False,
         )
         compiled = jax.jit(fan).lower(*stacked).compile()
         assert_no_cross_chain_collectives(compiled.as_text(), mesh)
@@ -431,6 +432,7 @@ def main(argv=None) -> MatrixResult:
         help="mesh_fanout shards independent cells over visible devices",
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     split = lambda s: tuple(x for x in s.split(",") if x)
     specs = [

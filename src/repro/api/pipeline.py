@@ -92,6 +92,19 @@ def groundtruth_step_size(spec: RunSpec) -> float:
     return spec.step_size
 
 
+def gather_draws(theta: jnp.ndarray) -> jnp.ndarray:
+    """The draws on one device, as the combine stage takes them.
+
+    On a mesh the chains' draws stay sharded over the data axis. Combiners
+    are single-device programs, and XLA cannot partition the Pallas kernels
+    inside them (Mosaic refuses a sharded operand), so combining starts by
+    gathering the draws: the one communication step of the method.
+    """
+    if len(theta.sharding.device_set) == 1:
+        return theta
+    return jax.device_put(theta, jax.devices()[0])
+
+
 def combine_spec_draws(
     spec: RunSpec,
     base_key: jax.Array,
@@ -110,6 +123,7 @@ def combine_spec_draws(
     # late import — epmcmc pulls the heavy LM stack
     from repro.distributed.epmcmc import combine_gathered
 
+    theta = gather_draws(theta)
     kc = jax.random.fold_in(base_key, 3)
     options = dict({"rescale": True, "n_batch": 1}, **dict(spec.combiner_options))
     out: Dict[str, CombineResult] = {}
@@ -365,6 +379,9 @@ class Pipeline:
                 check_hlo=self.check_hlo,
             )
             res, t_done, complete = rs.result, rs.t_done, rs.complete
+        # stage timers read the clock after the device finishes, not after
+        # the enqueue
+        jax.block_until_ready((res.theta, res.accept))
         self.timings["sample_s"] = self.timings.get("sample_s", 0.0) + (
             time.time() - t0
         )
@@ -395,6 +412,7 @@ class Pipeline:
                 sgld_batch=spec.sgld_batch,
                 sampler_options=spec.sampler_options,
             )
+            jax.block_until_ready(self._groundtruth)
             self.timings["groundtruth_s"] = time.time() - t0
         return self._groundtruth
 
@@ -556,6 +574,7 @@ class Pipeline:
                     k_names[name], states[name], spec.T,
                     **filter_options(fn, options),
                 )
+            jax.block_until_ready(final)
             self.timings["stream_combine_s"] = time.time() - t0
             # the finals ARE the combine-stage results (bitwise for the
             # buffered implementations) — let score() reuse them
@@ -608,7 +627,7 @@ class Pipeline:
         spec = self.spec
         t_start = time.time()
         draws = self.sample()  # the fused program, or the cached draws
-        theta = draws.theta
+        theta = gather_draws(draws.theta)
         chunk = spec.stream_every
         counts_T = jnp.full((spec.M,), spec.T, jnp.int32)
 
@@ -668,6 +687,7 @@ class Pipeline:
                 k_names[name], host_state, spec.T,
                 **filter_options(fn, options),
             )
+        jax.block_until_ready(final)
         self.timings["stream_combine_s"] = time.time() - t0
         if self._combined is None and set(names) == set(spec.combiner_names()):
             self._combined = dict(final)
@@ -703,6 +723,7 @@ class Pipeline:
                 )
             t0 = time.time()
             self._combined = combine_spec_draws(spec, self._key, draws.theta)
+            jax.block_until_ready(self._combined)
             self.timings["combine_s"] = time.time() - t0
         return self._combined
 

@@ -376,6 +376,20 @@ def is_padded(model, shards, counts, sampler) -> bool:
     return padded
 
 
+def chain_mesh(shape: Tuple[int, ...], axis_names: Tuple[str, ...]):
+    """A device mesh for the chain programs, with ``Auto`` axes.
+
+    The chain programs leave sharding to GSPMD propagation: their outputs
+    flow on into unsharded combine code. ``jax.make_mesh`` defaults to
+    ``Explicit`` axes, under which that code refuses the sharded draws.
+    """
+    from jax.sharding import AxisType
+
+    return jax.make_mesh(
+        tuple(shape), axis_names, axis_types=(AxisType.Auto,) * len(axis_names)
+    )
+
+
 def _sample_on_mesh(vmapped, shards, counts, keys, model, mesh_shape, check_hlo):
     """shard_map the vmapped per-shard sampler over the mesh data axis.
 
@@ -384,24 +398,20 @@ def _sample_on_mesh(vmapped, shards, counts, keys, model, mesh_shape, check_hlo)
     can be asserted collective-free *before* it runs — the machine-checked
     "embarrassingly parallel" property.
     """
-    from functools import partial
-
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     # late import: epmcmc pulls the (heavy) LM stack this path otherwise skips
     from repro.distributed.epmcmc import assert_no_cross_chain_collectives
 
-    mesh = jax.make_mesh(tuple(mesh_shape), ("data", "model"))
+    mesh = chain_mesh(mesh_shape, ("data", "model"))
     shard_specs = _shard_axes(shards, model.shard_keys, P("data"), P())
-    in_specs = (shard_specs, P("data"), P("data"))
-    body = partial(
-        shard_map,
+    body = jax.shard_map(
+        vmapped,
         mesh=mesh,
-        in_specs=in_specs,
+        in_specs=(shard_specs, P("data"), P("data")),
         out_specs=(P("data"), P("data")),
-        check_rep=False,
-    )(vmapped)
+        check_vma=False,
+    )
     compiled = jax.jit(body).lower(shards, counts, keys).compile()
     checked = None
     if check_hlo:

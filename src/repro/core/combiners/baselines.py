@@ -54,7 +54,7 @@ def consensus_weighted(
     total = jnp.sum(precs, axis=0)
     chol = jnp.linalg.cholesky(total)
     gathered = ragged_gather(samples, counts)  # (M, T, d)
-    weighted = jnp.einsum("mij,mtj->ti", precs, gathered)
+    weighted = jnp.einsum("mij,mtj->ti", precs, gathered, precision=jax.lax.Precision.HIGHEST)
     return jax.scipy.linalg.cho_solve((chol, True), weighted.T).T
 
 

@@ -105,6 +105,10 @@ class ImgWeightModel(NamedTuple):
 # ---------------------------------------------------------------------------
 
 
+# f32 matmuls: TPU's default runs them at bf16 input precision
+_F32 = jax.lax.Precision.HIGHEST
+
+
 class _ImgCarry(NamedTuple):
     key: jax.Array
     t_idx: jnp.ndarray  # (M,) current component indices
@@ -257,8 +261,8 @@ def _img_kernel_sweep(
     cand = samples[jnp.arange(M)[None, :], c]  # (B, M, d) cand[b,m]=samples[m,c[b,m]]
     delta = cand - carry.theta_sel  # (B, M, d) Δ_m
     nsq = jnp.sum(cand**2, axis=-1) - jnp.sum(carry.theta_sel**2, axis=-1)  # (B, M)
-    b_dot = jnp.einsum("bd,bmd->bm", carry.mean, delta)  # θ̄₀·Δ_m
-    gram = jnp.einsum("bmd,bnd->bmn", delta, delta)  # Δ_j·Δ_m
+    b_dot = jnp.einsum("bd,bmd->bm", carry.mean, delta, precision=_F32)  # θ̄₀·Δ_m
+    gram = jnp.einsum("bmd,bnd->bmn", delta, delta, precision=_F32)  # Δ_j·Δ_m
     msq0 = jnp.sum(carry.mean**2, axis=-1)  # (B,)
 
     h32 = h.astype(jnp.float32)
@@ -334,7 +338,7 @@ def _img_kernel_sweep(
     a_mask, n_acc = final[-2], final[-1]
 
     af = a_mask.astype(dtype)
-    mean_new = carry.mean + jnp.einsum("bm,bmd->bd", af, delta) / M
+    mean_new = carry.mean + jnp.einsum("bm,bmd->bd", af, delta, precision=_F32) / M
     sumsq_new = carry.sumsq + jnp.sum(af * nsq, axis=-1)
     return carry._replace(
         key=key_next,
@@ -487,7 +491,7 @@ def semiparametric_model(
     moments = jax.vmap(lambda s, mk: fit_moments(s, mk))(samples, masks)
     prod = product_moments(moments.mean, moments.cov)
     lam_m = jnp.linalg.inv(prod.cov + 1e-10 * jnp.eye(d))  # Σ̂_M^{-1}
-    eta_m = lam_m @ prod.mean  # Σ̂_M^{-1} μ̂_M
+    eta_m = jnp.matmul(lam_m, prod.mean, precision=_F32)  # Σ̂_M^{-1} μ̂_M
 
     if nonparametric_weights:
         aux = None

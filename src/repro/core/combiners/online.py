@@ -82,35 +82,12 @@ def online_update_chunk(
     (None ⇒ all C rows). Invalid rows may hold arbitrary garbage — they are
     excluded with ``where``, never mask-multiplied (0·NaN would leak).
     """
-    M, C, d = chunk.shape
-    cc = (
-        jnp.full((M,), C, jnp.int32)
-        if chunk_counts is None
-        else chunk_counts.astype(jnp.int32)
-    )
-    mask = (jnp.arange(C)[None, :] < cc[:, None])[..., None]  # (M, C, 1)
-    n_b = cc.astype(chunk.dtype)
-    n_b_safe = jnp.maximum(n_b, 1.0)
-    valid = jnp.where(mask, chunk, 0.0)
-    mean_b = jnp.sum(valid, axis=1) / n_b_safe[:, None]  # (M, d)
-    cent = jnp.where(mask, chunk - mean_b[:, None, :], 0.0)
-    m2_b = jnp.einsum("mci,mcj->mij", cent, cent)  # (M, d, d)
+    from repro.kernels.online_update.ref import online_moments_update_ref
 
-    n_a = state.count
-    n = n_a + n_b
-    n_safe = jnp.maximum(n, 1.0)
-    delta = mean_b - state.mean
-    mean = state.mean + delta * (n_b / n_safe)[:, None]
-    m2 = state.m2 + m2_b + jnp.einsum("mi,mj->mij", delta, delta) * (
-        n_a * n_b / n_safe
-    )[:, None, None]
-    # machines contributing nothing this chunk keep their state untouched
-    upd = (n_b > 0)[:, None]
-    return OnlineMoments(
-        count=n,
-        mean=jnp.where(upd, mean, state.mean),
-        m2=jnp.where(upd[..., None], m2, state.m2),
+    count, mean, m2 = online_moments_update_ref(
+        state.count, state.mean, state.m2, chunk, chunk_counts
     )
+    return OnlineMoments(count=count, mean=mean, m2=m2)
 
 
 def online_update_chunk_kernel(

@@ -121,7 +121,7 @@ def weierstrass(
         k, k_ref, k_pool = jax.random.split(k, 3)
         # refinement: categorical over each machine's valid prefix with
         # logits −‖θ − θᵐ_t‖²/(2h²), drawn via Gumbel-max in one shot.
-        cross = jnp.einsum("mtd,bd->bmt", samples, theta)
+        cross = jnp.einsum("mtd,bd->bmt", samples, theta, precision=jax.lax.Precision.HIGHEST)
         qsq = jnp.sum(theta**2, axis=-1)  # (B,)
         sq = csq[None, :, :] - 2.0 * cross + qsq[:, None, None]
         logits = -0.5 * sq / (h[:, None, None] ** 2)

@@ -61,7 +61,7 @@ def fit_moments(
     if diag:
         var = jnp.sum(centered**2, axis=0) / denom + jitter
         return GaussianMoments(mean=mean, cov=var)
-    cov = centered.T @ centered / denom
+    cov = jnp.matmul(centered.T, centered, precision=jax.lax.Precision.HIGHEST) / denom
     cov = cov + jitter * jnp.eye(d, dtype=samples.dtype)
     return GaussianMoments(mean=mean, cov=cov)
 
@@ -86,7 +86,7 @@ def product_moments(
 
     def precision_and_weighted_mean(mu, cov):
         prec, _ = _chol_inverse(cov)
-        return prec, prec @ mu
+        return prec, jnp.matmul(prec, mu, precision=jax.lax.Precision.HIGHEST)
 
     precs, wmeans = jax.vmap(precision_and_weighted_mean)(means, covs)
     lam = jnp.sum(precs, axis=0) + jitter * jnp.eye(d, dtype=means.dtype)

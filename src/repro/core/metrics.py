@@ -53,7 +53,7 @@ def log_mean_gaussian_cross(
         sq = (
             jnp.sum(xc**2, -1)[:, None]
             + jnp.sum(y**2, -1)[None, :]
-            - 2.0 * xc @ y.T
+            - 2.0 * jnp.matmul(xc, y.T, precision=jax.lax.Precision.HIGHEST)
         )
         logk = -0.5 * sq / var
         block_lse = jax.scipy.special.logsumexp(logk, axis=(0, 1), b=vc[:, None])
@@ -129,7 +129,7 @@ def kde_logpdf(
         sq = (
             jnp.sum(qc**2, -1)[:, None]
             + jnp.sum(samples**2, -1)[None, :]
-            - 2.0 * qc @ samples.T
+            - 2.0 * jnp.matmul(qc, samples.T, precision=jax.lax.Precision.HIGHEST)
         )
         return jax.scipy.special.logsumexp(-0.5 * sq / h**2, axis=1)
 

@@ -30,9 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names this TPUCompilerParams; newer releases renamed it
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 _NEG_INF = -1e30
 
 
@@ -147,7 +144,7 @@ def flash_attention_fwd_kernel(
             pltpu.VMEM((block_q * g,), jnp.float32),
             pltpu.VMEM((block_q * g, hd_v), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS_CLS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -6,8 +6,11 @@ TPU-native layout (not a CUDA port — there is no warp/SMEM notion here):
   *arbitrary* (sequential-accumulate) over feature blocks.
 - Each grid step loads a (block_p, M, block_d) VMEM tile — the M axis stays
   fully resident (M ≤ 64 machines ⇒ ≤ 64·block_p·block_d·4B, sized for VMEM).
-- SSE is accumulated across d-blocks in an f32 VMEM scratch (block_p,); the
-  log-normalizer is applied once on the last d-block.
+- SSE is accumulated across d-blocks in an f32 VMEM scratch (block_p, 1)
+  and scaled by −1/(2h²) once on the last d-block; ``ops.py`` subtracts the
+  log-normalizer. The output is the ``(P, 1)`` column of those rows (a 2-D
+  block whose last dim equals the array's), and the scalar bandwidth ``h``
+  is read from SMEM.
 - All reductions are VPU-friendly (axis=1/2 sums over a dense tile); no
   gather/scatter — the caller materializes the (P, M, d) selection, which for
   Algorithm-1-style sweeps is a cheap take_along_axis outside the kernel.
@@ -26,11 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names this TPUCompilerParams; newer releases renamed it
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
-
-def _img_weights_kernel(theta_ref, h_ref, out_ref, acc_ref, *, n_dblocks: int, m: int, d: int):
+def _img_weights_kernel(theta_ref, h_ref, out_ref, acc_ref, *, n_dblocks: int):
     j = pl.program_id(1)  # d-block index (sequential accumulation axis)
 
     @pl.when(j == 0)
@@ -39,15 +39,13 @@ def _img_weights_kernel(theta_ref, h_ref, out_ref, acc_ref, *, n_dblocks: int, m
 
     t = theta_ref[...].astype(jnp.float32)  # (block_p, M, block_d)
     mean = jnp.mean(t, axis=1, keepdims=True)
-    sse = jnp.sum((t - mean) ** 2, axis=(1, 2))  # (block_p,)
-    acc_ref[...] += sse
+    sq = jnp.sum((t - mean) ** 2, axis=2)  # (block_p, M)
+    acc_ref[...] += jnp.sum(sq, axis=1, keepdims=True)  # (block_p, 1)
 
     @pl.when(j == n_dblocks - 1)
     def _finalize():
         h = h_ref[0]
-        inv2h2 = 0.5 / (h * h)
-        log_norm = m * (d / 2.0) * jnp.log(2.0 * jnp.pi * h * h)
-        out_ref[...] = -acc_ref[...] * inv2h2 - log_norm
+        out_ref[...] = -acc_ref[...] * (0.5 / (h * h))
 
 
 @functools.partial(jax.jit, static_argnames=("block_p", "block_d", "interpret"))
@@ -61,21 +59,18 @@ def img_log_weights_kernel(
 ) -> jnp.ndarray:
     P, M, d = theta.shape
     n_p, n_d = P // block_p, d // block_d
-    kernel = functools.partial(
-        _img_weights_kernel, n_dblocks=n_d, m=M, d=theta.shape[2]
-    )
     return pl.pallas_call(
-        kernel,
+        functools.partial(_img_weights_kernel, n_dblocks=n_d),
         grid=(n_p, n_d),
         in_specs=[
             pl.BlockSpec((block_p, M, block_d), lambda i, j: (i, 0, j)),
-            pl.BlockSpec(memory_space=pl.ANY),  # h: tiny scalar operand
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # h: (1,) scalar operand
         ],
-        out_specs=pl.BlockSpec((block_p,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((P,), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_p,), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS_CLS(
+        out_specs=pl.BlockSpec((block_p, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((P, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_p, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(theta, h)
+    )(theta, h)[:, 0]

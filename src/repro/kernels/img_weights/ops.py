@@ -46,10 +46,7 @@ def img_log_weights(
     out = img_log_weights_kernel(
         padded, h_arr, block_p=block_p, block_d=block_d, interpret=interpret
     )
-    # padded d-features contribute 0 SSE but DO enter the log-normalizer the
-    # kernel applies with the *padded* d; correct by the normalizer delta.
-    if dp != d:
-        h32 = jnp.asarray(h, jnp.float32)
-        delta = M * ((dp - d) / 2.0) * jnp.log(2.0 * jnp.pi * h32 * h32)
-        out = out + delta
-    return out[:P]
+    # the log-normalizer, by the reference's expression and outside the
+    # kernel: M·(d/2) multiplies the error of the TPU's approximate ``log``
+    h32 = jnp.asarray(h, jnp.float32)
+    return out[:P] - M * (d / 2.0) * jnp.log(2.0 * jnp.pi * h32 * h32)

@@ -60,7 +60,7 @@ def machine_kde_log_density_ref(
         sq = (
             jnp.sum(qc**2, axis=-1)[None, :, None]
             + csq[:, None, :]
-            - 2.0 * jnp.einsum("qd,mtd->mqt", qc, samples)
+            - 2.0 * jnp.einsum("qd,mtd->mqt", qc, samples, precision=jax.lax.Precision.HIGHEST)
         )
         logk = -0.5 * sq / (h[:, None, None] ** 2)
         logk = jnp.where(mask[:, None, :], logk, -jnp.inf)
@@ -111,4 +111,6 @@ def kde_log_density_ref(
     d = q.shape[-1]
     sq = jnp.sum((q[:, None, :] - s[None, :, :]) ** 2, axis=-1)  # (nq, ns)
     lse = jax.scipy.special.logsumexp(-0.5 * sq / (h * h), axis=1)
-    return lse - jnp.log(s.shape[0]) - 0.5 * d * jnp.log(2.0 * jnp.pi * h * h)
+    # the batched op's normalizer expression: log is approximate on a TPU,
+    # and d/2 multiplies the difference between two ways of writing it
+    return lse - jnp.log(s.shape[0]) - 0.5 * d * (2.0 * jnp.log(h) + _LOG2PI)

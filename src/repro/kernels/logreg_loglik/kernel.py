@@ -26,9 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names this TPUCompilerParams; newer releases renamed it
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _logreg_kernel(x_ref, y_ref, w_ref, beta_ref, loglik_ref, grad_ref, acc_l, acc_g, *, n_blocks: int):
     i = pl.program_id(0)
@@ -43,11 +40,11 @@ def _logreg_kernel(x_ref, y_ref, w_ref, beta_ref, loglik_ref, grad_ref, acc_l, a
     w = w_ref[...].astype(jnp.float32)  # (block_n, 1)
     beta = beta_ref[...].astype(jnp.float32)  # (d, C)
 
-    z = y * jax.lax.dot(x, beta, preferred_element_type=jnp.float32)  # (block_n, C)
+    z = y * jax.lax.dot(x, beta, precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)  # (block_n, C)
     # log σ(z) = −softplus(−z), computed stably on the VPU
     loglik = -jnp.sum(w * jnp.logaddexp(0.0, -z), axis=0)  # (C,)
     coeff = w * y * jax.nn.sigmoid(-z)  # (block_n, C)
-    grad = jax.lax.dot(x.T, coeff, preferred_element_type=jnp.float32)  # (d, C)
+    grad = jax.lax.dot(x.T, coeff, precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)  # (d, C)
 
     acc_l[...] += loglik
     acc_g[...] += grad
@@ -93,7 +90,7 @@ def logreg_loglik_grad_kernel(
             pltpu.VMEM((C,), jnp.float32),
             pltpu.VMEM((d, C), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS_CLS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -27,8 +27,8 @@ def logreg_loglik_grad_ref(
     X = X.astype(jnp.float32)
     y = y.astype(jnp.float32)
     beta = beta.astype(jnp.float32)
-    z = y * (X @ beta)  # (N,)
+    z = y * jnp.matmul(X, beta, precision=jax.lax.Precision.HIGHEST)  # (N,)
     loglik = jnp.sum(jax.nn.log_sigmoid(z))
     coeff = y * jax.nn.sigmoid(-z)  # (N,)
-    grad = X.T @ coeff
+    grad = jnp.matmul(X.T, coeff, precision=jax.lax.Precision.HIGHEST)
     return scale * loglik, scale * grad

@@ -56,15 +56,15 @@ def online_moments_update(
     )
     Cp, dp = _round_up(C, 8), _round_up(d, 128)
     chunk_p = jnp.zeros((M, Cp, dp), jnp.float32).at[:, :C, :d].set(chunk)
-    mean_p = jnp.zeros((M, dp), jnp.float32).at[:, :d].set(mean)
+    mean_p = jnp.zeros((M, 1, dp), jnp.float32).at[:, 0, :d].set(mean)
     m2_p = jnp.zeros((M, dp, dp), jnp.float32).at[:, :d, :d].set(m2)
     scalars = (
-        jnp.zeros((M, 128), jnp.float32)
-        .at[:, 0].set(cc)
-        .at[:, 1].set(count.astype(jnp.float32))
+        jnp.zeros((M, 1, 128), jnp.float32)
+        .at[:, 0, 0].set(cc)
+        .at[:, 0, 1].set(count.astype(jnp.float32))
     )
     mean_o, m2_o = online_update_kernel(
         chunk_p, scalars, mean_p, m2_p, interpret=interpret
     )
     n_b = cc.astype(chunk.dtype)
-    return count + n_b, mean_o[:, :d], m2_o[:, :d, :d]
+    return count + n_b, mean_o[:, 0, :d], m2_o[:, :d, :d]

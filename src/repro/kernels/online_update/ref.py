@@ -12,6 +12,7 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 
@@ -34,16 +35,19 @@ def online_moments_update_ref(
     valid = jnp.where(mask, chunk, 0.0)
     mean_b = jnp.sum(valid, axis=1) / n_b_safe[:, None]  # (M, d)
     cent = jnp.where(mask, chunk - mean_b[:, None, :], 0.0)
-    m2_b = jnp.einsum("mci,mcj->mij", cent, cent)  # (M, d, d)
+    m2_b = jnp.einsum(  # (M, d, d)
+        "mci,mcj->mij", cent, cent, precision=jax.lax.Precision.HIGHEST
+    )
 
     n_a = count
     n = n_a + n_b
     n_safe = jnp.maximum(n, 1.0)
     delta = mean_b - mean
     mean_new = mean + delta * (n_b / n_safe)[:, None]
-    m2_new = m2 + m2_b + jnp.einsum("mi,mj->mij", delta, delta) * (
-        n_a * n_b / n_safe
-    )[:, None, None]
+    outer = jnp.einsum(
+        "mi,mj->mij", delta, delta, precision=jax.lax.Precision.HIGHEST
+    )
+    m2_new = m2 + m2_b + outer * (n_a * n_b / n_safe)[:, None, None]
     upd = (n_b > 0)[:, None]
     return (
         n,

@@ -49,6 +49,7 @@ from repro.api import Pipeline, RunSpec
 from repro.core.combiners import available_combiners
 from repro.models.bayes import available_models
 from repro.samplers import available_samplers
+from repro.utils.compile_cache import enable_compile_cache
 
 # historical internals, now owned by repro.api.sampling — resolved lazily so
 # importing this CLI module stays cheap and old imports keep working (warned)
@@ -184,6 +185,7 @@ def main(argv=None) -> dict:
         "CI smoke contract); 0 = serve without probing",
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     pipe = Pipeline(
         build_spec(args),

@@ -44,7 +44,7 @@ driver — this package serves *posteriors*, not tokens.
 """
 
 from repro.serve.client import ServeClient, ServeError  # noqa: F401
-from repro.serve.handlers import HANDLERS, answer  # noqa: F401
+from repro.serve.handlers import HANDLERS, BadRequest, answer  # noqa: F401
 from repro.serve.server import PosteriorServer, serve_pipeline  # noqa: F401
 from repro.serve.state import EstimateSnapshot, ServeState  # noqa: F401
 

@@ -21,6 +21,11 @@ import asyncio
 import json
 from typing import Any, Dict
 
+# bytes per protocol line, both ways: a logpdf request carries its whole
+# (Q, d) batch of points on one line (asyncio's default is 64 KiB, which a
+# 256-point batch at d=50 already exceeds)
+LINE_LIMIT = 1 << 24
+
 
 class ServeError(RuntimeError):
     """An ``ok=False`` response, with the server's code/reason attached."""
@@ -40,7 +45,7 @@ class ServeClient:
 
     @classmethod
     async def connect(cls, host: str, port: int) -> "ServeClient":
-        reader, writer = await asyncio.open_connection(host, port)
+        reader, writer = await asyncio.open_connection(host, port, limit=LINE_LIMIT)
         return cls(reader, writer)
 
     async def request(self, op: str, **params: Any) -> Dict[str, Any]:

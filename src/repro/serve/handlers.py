@@ -29,7 +29,11 @@ Responses are ``{"ok": True, "op", "combiner", "result", "staleness"}`` or
 ``{"ok": False, "error": {"code", "reason", ...}, "staleness"}``. The typed
 :class:`~repro.core.combiners.api.EstimateUnavailable` maps to ``code=503``
 (the combiner folds but cannot refresh — retry another name or wait for
-completion); unknown ops/combiners/bad params map to ``code=400``.
+completion); a request the validators refuse (:class:`BadRequest`: unknown
+op or combiner, bad params) maps to ``code=400``. Any other failure is the
+server's own (a kernel that does not compile, a device error) and
+propagates: :func:`answer` raises it, and the TCP loop reports it as
+``code=500``.
 """
 
 from __future__ import annotations
@@ -45,6 +49,18 @@ from repro.serve.state import ServeState
 DEFAULT_PROBS = (0.05, 0.25, 0.5, 0.75, 0.95)
 
 
+class BadRequest(ValueError):
+    """A request the query surface refuses as malformed (``code=400``)."""
+
+
+def _param(params: Dict[str, Any], key: str, cast, default):
+    """``cast(params.get(key, default))``, a :class:`BadRequest` if it fails."""
+    try:
+        return cast(params.get(key, default))
+    except (TypeError, ValueError) as exc:
+        raise BadRequest(f"{key}: {exc}") from None
+
+
 def handle_mean_cov(state: ServeState, name: str, params: Dict[str, Any]):
     snap = state.snapshot(name)
     return {
@@ -56,19 +72,19 @@ def handle_mean_cov(state: ServeState, name: str, params: Dict[str, Any]):
 
 
 def handle_quantiles(state: ServeState, name: str, params: Dict[str, Any]):
-    probs = [float(p) for p in params.get("probs", DEFAULT_PROBS)]
+    probs = _param(params, "probs", lambda ps: [float(p) for p in ps], DEFAULT_PROBS)
     if not probs or any(not (0.0 <= p <= 1.0) for p in probs):
-        raise ValueError(f"probs must lie in [0, 1], got {probs}")
+        raise BadRequest(f"probs must lie in [0, 1], got {probs}")
     snap = state.snapshot(name)
     q = np.quantile(snap.samples, probs, axis=0)  # (P, d)
     return {"probs": probs, "quantiles": q.tolist()}
 
 
 def handle_draws(state: ServeState, name: str, params: Dict[str, Any]):
-    n = int(params.get("n", 16))
+    n = _param(params, "n", int, 16)
     if n <= 0:
-        raise ValueError(f"n must be positive, got {n}")
-    seed = int(params.get("seed", 0))
+        raise BadRequest(f"n must be positive, got {n}")
+    seed = _param(params, "seed", int, 0)
     snap = state.snapshot(name)
     # deterministic per (snapshot, seed): same request, same draws
     idx = np.random.default_rng(seed).integers(0, snap.samples.shape[0], size=n)
@@ -79,18 +95,18 @@ def handle_logpdf(state: ServeState, name: str, params: Dict[str, Any]):
     import jax.numpy as jnp
 
     if "points" not in params:
-        raise ValueError("logpdf needs 'points': one d-vector or a list of them")
-    pts = np.asarray(params["points"], dtype=np.float32)
+        raise BadRequest("logpdf needs 'points': one d-vector or a list of them")
+    pts = _param(params, "points", lambda p: np.asarray(p, dtype=np.float32), None)
     if pts.ndim == 1:
         pts = pts[None, :]
     if pts.ndim != 2:
-        raise ValueError(f"points must be (d,) or (Q, d), got shape {pts.shape}")
+        raise BadRequest(f"points must be (d,) or (Q, d), got shape {pts.shape}")
     reduce = str(params.get("reduce", "product"))
     if reduce not in ("product", "mixture"):
-        raise ValueError(f"reduce must be 'product' or 'mixture', got {reduce!r}")
+        raise BadRequest(f"reduce must be 'product' or 'mixture', got {reduce!r}")
     theta, counts = state.logpdf_inputs()
     if pts.shape[1] != theta.shape[-1]:
-        raise ValueError(
+        raise BadRequest(
             f"points are {pts.shape[1]}-dimensional, posterior is "
             f"{theta.shape[-1]}-dimensional"
         )
@@ -124,9 +140,10 @@ HANDLERS = {
 
 
 def answer(state: ServeState, request: Dict[str, Any]) -> Dict[str, Any]:
-    """Dispatch one request dict; never raises — failures become typed
-    ``{"ok": False, "error": ...}`` responses (still carrying staleness, so
-    even a 503 tells the reader where the stream is)."""
+    """Dispatch one request dict. Refused requests and unavailable estimates
+    become typed ``{"ok": False, "error": ...}`` responses (still carrying
+    staleness, so even a 503 tells the reader where the stream is); any other
+    failure is raised to the caller, never reported as the client's fault."""
     op = request.get("op")
     name: Optional[str] = request.get("combiner") or (
         state.setup.names[0] if state.setup.names else None
@@ -137,8 +154,12 @@ def answer(state: ServeState, request: Dict[str, Any]) -> Dict[str, Any]:
     try:
         handler = HANDLERS.get(op)
         if handler is None:
-            raise KeyError(
+            raise BadRequest(
                 f"unknown op {op!r}; available: {sorted(HANDLERS)}"
+            )
+        if name not in state.setup.names:
+            raise BadRequest(
+                f"combiner {name!r} not served; serving: {state.setup.names}"
             )
         result = handler(state, name, request)
         return {
@@ -152,7 +173,7 @@ def answer(state: ServeState, request: Dict[str, Any]) -> Dict[str, Any]:
             "error": {"code": 503, "reason": exc.reason, "combiner": exc.combiner},
             "staleness": state.staleness(name),
         }
-    except (KeyError, ValueError, TypeError) as exc:
+    except BadRequest as exc:
         return {
             "ok": False, **base,
             "error": {"code": 400, "reason": str(exc)},

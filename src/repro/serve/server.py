@@ -43,11 +43,13 @@ from __future__ import annotations
 import asyncio
 import json
 import time
+import traceback
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from repro.api.pipeline import Pipeline
 from repro.api.streaming import StreamChunk
 from repro.serve import handlers
+from repro.serve.client import LINE_LIMIT
 from repro.serve.state import ServeState
 
 
@@ -112,7 +114,7 @@ class PosteriorServer:
         self._loop = asyncio.get_running_loop()
         self._queue = asyncio.Queue(maxsize=self._queue_depth)
         self._tcp = await asyncio.start_server(
-            self._handle_conn, self.host, self.port
+            self._handle_conn, self.host, self.port, limit=LINE_LIMIT
         )
         self.port = self._tcp.sockets[0].getsockname()[1]
         self._folder = asyncio.create_task(self._fold_loop())
@@ -203,9 +205,17 @@ class PosteriorServer:
                         "staleness": self.state.staleness(),
                     }
                 else:
-                    resp = await self._loop.run_in_executor(
-                        None, handlers.answer, self.state, req
-                    )
+                    try:
+                        resp = await self._loop.run_in_executor(
+                            None, handlers.answer, self.state, req
+                        )
+                    except Exception as exc:  # the server's fault, not the reader's
+                        traceback.print_exc()
+                        resp = {
+                            "ok": False,
+                            "error": {"code": 500, "reason": repr(exc)},
+                            "staleness": self.state.staleness(),
+                        }
                 writer.write(json.dumps(resp).encode() + b"\n")
                 await writer.drain()
         except (ConnectionResetError, BrokenPipeError, asyncio.CancelledError):
@@ -272,7 +282,9 @@ def serve_pipeline(
     ops = list(PROBE_OPS)
     if probe_logpdf:
         d = pipeline._model.d
-        ops.append({"op": "logpdf", "points": [[0.0] * d]})
+        # 64 points: the KDE op's min_kernel_n, so the probe reaches the
+        # kernel where one runs (fewer take the jnp reference)
+        ops.append({"op": "logpdf", "points": [[0.0] * d] * 64})
 
     async def _probe(server: PosteriorServer, latencies: List[float],
                      errors: List[str], idx: int) -> int:

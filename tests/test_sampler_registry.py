@@ -29,6 +29,7 @@ from repro.samplers import (
 
 MEAN = jnp.array([1.0, -2.0])
 STD = jnp.array([0.8, 1.4])
+N_CHAINS = 8
 
 
 def logpdf(theta):
@@ -73,10 +74,16 @@ def test_registry_contains_the_paper_surface():
 @pytest.mark.parametrize("name", sorted(canonical_samplers()))
 def test_conformance_moments_probabilities_determinism(name):
     kern = _build(name)
-    run = jax.jit(
+    # N_CHAINS independent chains: SGLD at ε=0.05 mixes over ~40 steps in the
+    # wide coordinate, so one 4500-draw chain has an MCSE of the mean near
+    # 0.2 — as large as the tolerance, which then passes or fails with the
+    # PRNG stream. Pooling independent chains shrinks the MCSE by
+    # √N_CHAINS and leaves the tolerances a few MCSEs wide for every sampler.
+    keys = jax.random.split(jax.random.PRNGKey(0), N_CHAINS)
+    run = jax.jit(jax.vmap(
         lambda k: run_chain(k, kern, jnp.zeros(2), 6000, burn_in=1500)
-    )
-    pos, info = run(jax.random.PRNGKey(0))
+    ))
+    pos, info = run(keys)
 
     # accept_prob is a probability at every step
     assert float(info.accept_prob.min()) >= 0.0
@@ -85,11 +92,12 @@ def test_conformance_moments_probabilities_determinism(name):
 
     # analytic posterior moments (MCSE-sized tolerances; SGLD adds a small
     # discretization bias at ε=0.05)
-    np.testing.assert_allclose(pos.mean(0), MEAN, atol=0.25)
-    np.testing.assert_allclose(pos.std(0), STD, atol=0.3)
+    pooled = pos.reshape(-1, 2)
+    np.testing.assert_allclose(pooled.mean(0), MEAN, atol=0.25)
+    np.testing.assert_allclose(pooled.std(0), STD, atol=0.3)
 
     # fixed-seed determinism: an identical rerun is bitwise identical
-    pos2, _ = run(jax.random.PRNGKey(0))
+    pos2, _ = run(keys)
     np.testing.assert_array_equal(np.asarray(pos), np.asarray(pos2))
 
 

@@ -8,7 +8,8 @@ Three layers of coverage, mirroring the package split:
   never double-folded (extends test_streaming's interrupt→resume contract
   to the serving loop — the satellite);
 - **handlers**: the pure query surface — all four posterior ops plus
-  status, typed 503 for EstimateUnavailable, 400s for malformed requests,
+  status, typed 503 for EstimateUnavailable, 400s for malformed requests
+  only (a failing computation raises, and answers 500 over TCP),
   staleness metadata on every response;
 - **server**: the asyncio loop end to end — concurrent TCP readers during
   live sampling, monotone staleness counters, chunks never dropped under
@@ -233,6 +234,23 @@ def test_answer_rejects_malformed_requests(folded_state):
     assert answer(folded_state, {"op": "draws", "n": 0})["error"]["code"] == 400
 
 
+def _refused(*_args, **_kwargs):
+    # a kernel the TPU compiler refuses surfaces as a plain ValueError
+    raise ValueError("Mosaic failed to compile TPU kernel")
+
+
+def test_answer_raises_compute_failures_instead_of_400(folded_state, monkeypatch):
+    """A computation that fails is the server's fault: ``answer`` raises it
+    instead of reporting the request as malformed."""
+    from repro.serve import handlers
+
+    monkeypatch.setattr(handlers, "machine_kde_scores", _refused)
+    with pytest.raises(ValueError, match="Mosaic"):
+        answer(folded_state, {"op": "logpdf", "points": [[0.0] * 10]})
+    # validation still answers 400 on the same state
+    assert answer(folded_state, {"op": "logpdf"})["error"]["code"] == 400
+
+
 def test_answer_before_any_fold_is_503_with_position():
     pipe = Pipeline(SPEC)
     state = _serve_state(pipe)
@@ -356,6 +374,53 @@ def test_client_ask_raises_typed_serve_error():
                 await client.ask("mean_cov", combiner="consensus")
             assert exc.value.code == 503
             assert exc.value.staleness["complete"]
+        finally:
+            await client.close()
+            await server.stop()
+
+    asyncio.run(main())
+
+
+def test_server_reports_compute_failure_as_500(monkeypatch):
+    """Over TCP a failing computation answers 500, not a client-error 400,
+    and the connection keeps serving."""
+    from repro.serve import handlers
+
+    monkeypatch.setattr(handlers, "machine_kde_scores", _refused)
+    spec = dataclasses.replace(SPEC, combiner=("parametric",))
+
+    async def main():
+        server = PosteriorServer(Pipeline(spec), refresh="every")
+        await server.start()
+        await server.wait_complete()
+        client = await ServeClient.connect(server.host, server.port)
+        try:
+            resp = await client.request("logpdf", points=[[0.0] * 10])
+            assert not resp["ok"]
+            assert resp["error"]["code"] == 500
+            assert "Mosaic" in resp["error"]["reason"]
+            assert (await client.request("status"))["ok"]
+        finally:
+            await client.close()
+            await server.stop()
+
+    asyncio.run(main())
+
+
+def test_server_answers_a_logpdf_batch_beyond_64k():
+    """A logpdf batch travels as one protocol line; 2048 points at d=10 is
+    several hundred KiB, past asyncio's default 64 KiB line limit."""
+    spec = dataclasses.replace(SPEC, combiner=("parametric",))
+    points = np.random.default_rng(0).normal(size=(2048, 10)).tolist()
+
+    async def main():
+        server = PosteriorServer(Pipeline(spec), refresh="every")
+        await server.start()
+        await server.wait_complete()
+        client = await ServeClient.connect(server.host, server.port)
+        try:
+            result = await client.ask("logpdf", points=points)
+            assert len(result["log_density"]) == 2048
         finally:
             await client.close()
             await server.stop()
