@@ -1,0 +1,145 @@
+"""One run of one cell: set-up, the measured window, the check, the result.
+
+Set-up makes the data on the device from the seed, partitions it and runs
+one whole job, which compiles every program the window uses. The window
+then runs whole jobs back to back for ``--seconds``. With ``--trace 1`` the
+traffic mix's ``trace_jobs`` jobs follow under the profiler, and the
+per-layer metrics are read from their trace. Then the device's peak memory
+is read, the reference is computed on the host, and every job is compared
+with it: the check is the same with and without the trace.
+"""
+
+from __future__ import annotations
+
+import json
+import math
+import shutil
+import sys
+import tempfile
+import time
+from pathlib import Path
+from typing import Any, Dict, List, Optional
+
+import jax
+import numpy as np
+
+from chipbench import check, trace
+from chipbench.cell import Cell, reader
+from chipbench.device import CompileClock, describe, peak_of
+from chipbench.jobs import Jobs, seed_key
+
+
+def _log(**fields) -> None:
+    print(json.dumps(fields), file=sys.stderr, flush=True)
+
+
+def _traced(jobs: Jobs, first: int, n: int, chips: int, keep: Optional[Path]):
+    """Run ``n`` jobs under the profiler; ``(outputs, Trace)``."""
+    log_dir = Path(keep) if keep else Path(tempfile.mkdtemp(prefix="chipbench_trace_"))
+    try:
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0  # no per-function Python events
+        options.enable_hlo_proto = False
+        jax.profiler.start_trace(str(log_dir), profiler_options=options)
+        try:
+            outputs, _ = jobs.loop(first, 0.0, min_jobs=n)
+        finally:
+            jax.profiler.stop_trace()
+        return outputs, trace.load(trace.find_xplane(log_dir), chips)
+    finally:
+        if not keep:
+            shutil.rmtree(log_dir, ignore_errors=True)
+
+
+def execute(cell: Cell, seed: int, seconds: float, traced: bool,
+            devices: List[jax.Device], t_start: float,
+            keep_trace: Optional[Path] = None) -> Dict[str, Any]:
+    """Run the cell once and return the result line's object."""
+    clock = CompileClock()
+    cfg = cell.config
+    key = seed_key(seed)
+    data = cell.model.make_data(jax.random.fold_in(key, 0), cfg)
+    jobs = Jobs(cfg, cell.traffic, cell.chips, data, key)
+    warm = jobs.run(0)  # compiles every program the window drives
+    del warm
+    setup_compiles = clock.snapshot()
+    t_window = time.perf_counter()
+    setup_s = t_window - t_start
+
+    breakdown = None
+    outputs, window_s = jobs.loop(1, seconds)
+    timing = {"window_s": window_s, "job_s": window_s / len(outputs)}
+    window_compiles = CompileClock.since(setup_compiles, clock.snapshot())
+    if traced:
+        # a few more jobs under the profiler, checked with the window's
+        more, tr = _traced(
+            jobs, 1 + len(outputs), int(cell.traffic["trace_jobs"]), cell.chips,
+            keep_trace,
+        )
+        outputs += more
+    dev = describe(devices)
+
+    metrics: Dict[str, Dict[str, Any]] = {}
+    if traced:
+        ctx = {"trace": tr, "cell": cell, "jobs": len(more),
+               "peak": peak_of(dev["kind"]) if dev["platform"] == "tpu" else None}
+        for m in cell.per_layer:
+            value = reader(m["name"]).read(ctx)
+            if value is not None:
+                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+        span = trace.busy_seconds(tr)
+        if span is not None:
+            dev["busy_s"], dev["window_s"] = span
+        breakdown = {"device_ops": trace.top_ops(tr), "idle_gaps": trace.idle_gaps(tr)}
+    else:
+        values = {"job_s": timing["job_s"], "setup_s": setup_s}
+        for m in cell.end_to_end:
+            metrics[m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
+
+    # the check: every job of the window against the reference, on the host
+    t_ref = time.perf_counter()
+    ref = cell.model.laplace(np.asarray(data["x"]), np.asarray(data["y"]), cfg)
+    ms = [check.moments(np.asarray(o.theta), np.asarray(o.combined)) for o in outputs]
+    per_job, worst = check.readings(ms, ref)
+    failed = sum(not check.judge(r, cell.limits) for r in per_job)
+    if not check.judge({k: worst[k] for k in check.WINDOW_NAMES}, cell.limits):
+        failed = len(outputs)  # the window's average is every job's
+    _log(info="run", workload=cell.name, seed=seed, trace=int(traced), setup_s=setup_s,
+         setup_compiles=setup_compiles, window_compiles=window_compiles,
+         jobs=len(outputs), sample_s=[o.sample_s for o in outputs],
+         combine_s=[o.combine_s for o in outputs], reference_s=time.perf_counter() - t_ref,
+         readings={k: [r[k] for r in per_job] for k in check.JOB_NAMES}, **timing)
+    result: Dict[str, Any] = {
+        "correct": bool(outputs) and failed == 0,
+        "attempted": len(outputs),
+        "failed": failed,
+        "metrics": metrics,
+        "device": dev,
+    }
+    if breakdown is not None:
+        result["breakdown"] = breakdown
+    result["check"] = {
+        k: {"value": worst[k], "limit": float(limit)} for k, limit in cell.limits.items()
+    }
+    return result
+
+
+def finite(obj):
+    """JSON has no infinity: an infinite reading prints as a large number."""
+    if isinstance(obj, float) and not math.isfinite(obj):
+        return 1e300 if obj > 0 else -1e300
+    if isinstance(obj, dict):
+        return {k: finite(v) for k, v in obj.items()}
+    if isinstance(obj, list):
+        return [finite(v) for v in obj]
+    return obj
+
+
+def report(result: Dict[str, Any]) -> None:
+    """The check's numbers as the last lines of standard error, then the
+    result as the last line of standard output."""
+    for name, c in result["check"].items():
+        verdict = "ok" if c["value"] <= c["limit"] else "FAIL"
+        print(f"check {name} {c['value']!r} limit {c['limit']!r} {verdict}",
+              file=sys.stderr, flush=True)
+    print(json.dumps(finite(result)), flush=True)

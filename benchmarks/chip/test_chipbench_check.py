@@ -1,0 +1,217 @@
+"""The check that decides ``correct``: its numbers, its control, its faults.
+
+The control and the faults run a whole cell on the CPU, with the harness's
+look for a chip skipped: ``logreg-paper.batch-semiparametric`` at its own
+size, and the covertype cells' traffic on the covertype design cut to a
+twelfth of its rows, against those cells' limits. A sound run gets a few
+jobs in its window; a broken one gets one.
+Each fault breaks the timed path underneath the harness, in the program,
+and the run has to come out not correct.
+"""
+
+from __future__ import annotations
+
+import sys
+import time
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+HERE = Path(__file__).resolve().parent
+sys.path.insert(0, str(HERE))
+sys.path.insert(0, str(HERE.parents[1] / "src"))
+
+from chipbench import cell, check, harness  # noqa: E402
+from chipbench.jobs import seed_key  # noqa: E402
+
+SEED = 2**33 + 12345
+
+
+def _ref(d=3, m=2, seed=0):
+    rng = np.random.default_rng(seed)
+    a = rng.normal(size=(m, d, d))
+    sub_cov = a @ a.transpose(0, 2, 1) + d * np.eye(d)
+    full_cov = np.linalg.inv(np.linalg.inv(sub_cov).sum(0))
+    return {"sub_mean": rng.normal(size=(m, d)), "sub_cov": sub_cov,
+            "full_mean": rng.normal(size=d), "full_cov": full_cov}
+
+
+def _exact(mean, cov, t, rng):
+    """Draws whose sample mean and covariance are exactly ``mean``, ``cov``."""
+    z = rng.normal(size=(t, mean.shape[-1]))
+    z -= z.mean(0)
+    z = z @ np.linalg.inv(np.linalg.cholesky(np.cov(z.T)).T)
+    return mean + z @ np.linalg.cholesky(cov).T
+
+
+def test_numbers_are_zero_on_exact_moments():
+    ref, rng = _ref(), np.random.default_rng(1)
+    sub = np.stack([_exact(ref["sub_mean"][i], ref["sub_cov"][i], 500, rng) for i in range(2)])
+    comb = _exact(ref["full_mean"], ref["full_cov"], 500, rng)
+    got = check.numbers(check.moments(sub, comb), ref)
+    assert max(got.values()) < 1e-9
+    window = check.window_numbers([check.moments(sub, comb)] * 3, ref)
+    assert max(window.values()) < 1e-9
+
+
+def test_numbers_by_hand():
+    ref, rng = _ref(), np.random.default_rng(2)
+    sd = np.sqrt(np.diagonal(ref["sub_cov"], axis1=1, axis2=2))
+    sub = np.stack([_exact(ref["sub_mean"][i], ref["sub_cov"][i], 500, rng) for i in range(2)])
+    sub[1] += 0.5 * sd[1]  # shard 1 off by half an sd in every coordinate
+    sub[0] = ref["sub_mean"][0] + 2.0 * (sub[0] - ref["sub_mean"][0])  # sd doubled
+    comb = _exact(ref["full_mean"], ref["full_cov"], 500, rng)
+    got = check.numbers(check.moments(sub, comb), ref)
+    assert got["sub_mean"] == pytest.approx(0.5)
+    assert got["sub_sd"] == pytest.approx(np.log(2.0))
+    assert got["comb_mean"] < 1e-9 and got["comb_sd"] < 1e-9
+
+
+def test_a_chain_that_never_moves_fails_every_limit():
+    ref, rng = _ref(), np.random.default_rng(3)
+    sub = np.stack([_exact(ref["sub_mean"][i], ref["sub_cov"][i], 100, rng) for i in range(2)])
+    sub[0] = sub[0, :1]  # the first draw, repeated
+    comb = _exact(ref["full_mean"], ref["full_cov"], 100, rng)
+    got = check.numbers(check.moments(sub, comb), ref)
+    assert got["sub_sd"] > 20  # sd at rounding level: log ratio ~ -35
+    assert not check.judge(got, {k: 1.0 for k in check.NAMES})
+
+
+def test_the_window_averages_job_means_and_reads_the_worst_job():
+    ref, rng = _ref(), np.random.default_rng(4)
+    sd = np.sqrt(np.diagonal(ref["sub_cov"], axis1=1, axis2=2))
+    ms = []
+    for shift in (+0.4, -0.4, +0.1):  # per-job mean errors, in sds
+        sub = np.stack([_exact(ref["sub_mean"][i], ref["sub_cov"][i], 300, rng)
+                        for i in range(2)]) + shift * sd[:, None, :]
+        ms.append(check.moments(sub, _exact(ref["full_mean"], ref["full_cov"], 300, rng)))
+    per_job, worst = check.readings(ms, ref)
+    assert [r["sub_mean"] for r in per_job] == pytest.approx([0.4, 0.4, 0.1])
+    assert worst["sub_mean"] == pytest.approx(0.4)
+    assert worst["sub_mean_window"] == pytest.approx(0.1 / 3)
+    assert worst["comb_mean_window"] < 1e-9
+
+
+# -- whole runs on the CPU ----------------------------------------------------
+
+LOGREG = "logreg-paper.batch-semiparametric"
+COVTYPE = "covtype-paper.batch-parametric"
+
+
+def _cell(workload):
+    c = cell.find(workload)
+    if c.config["name"] == "covtype-paper":
+        # a twelfth of the rows keeps a CPU job to seconds; the shards keep
+        # the configuration's M, so each holds ~1,000 rows
+        c = c._replace(config={**c.config, "N": c.config["N"] // 12})
+    return c._replace(chips=1)
+
+
+def _run(c, seconds=0.0):
+    return harness.execute(c, SEED, seconds, False, jax.devices()[:1], time.perf_counter())
+
+
+@pytest.fixture
+def fresh_programs(monkeypatch):
+    """Compiled sampling programs are cached per process; a fault that
+    patches what they are built from needs them built anew."""
+    from repro.api import backends, streaming
+
+    monkeypatch.setattr(backends, "_BACKEND_CACHE", {})
+    monkeypatch.setattr(streaming, "_FUSED_SAMPLE_CACHE", {})
+    return monkeypatch
+
+
+def _frozen_step(mp):
+    from repro.samplers import registry
+    from repro.samplers.base import MCMCKernel
+
+    real = registry.mala_kernel
+
+    def frozen(logpdf, step_size=0.05):
+        k = real(logpdf, step_size=step_size)
+        return MCMCKernel(k.init, lambda key, state: (state, k.step(key, state)[1]))
+
+    mp.setattr(registry, "mala_kernel", frozen)
+
+
+def _half_batch(mp):
+    from repro.api import sampling
+
+    real = sampling.make_subposterior_logpdf
+
+    def half(log_prior, log_lik, shard, num_shards, *, count=None, per_datum=None):
+        n = shard["x"].shape[0] // 2
+        kept = {k: (v[:n] if per_datum is None or k in per_datum else v)
+                for k, v in shard.items()}
+        return real(log_prior, lambda th, d: 2.0 * log_lik(th, d), kept,
+                    num_shards, count=None, per_datum=per_datum)
+
+    mp.setattr(sampling, "make_subposterior_logpdf", half)
+
+
+def _answer_altered(mp):
+    from repro.api import backends
+
+    real = backends._chunk_one
+
+    def altered(sk, shard, count, eps, state, keys):
+        state, theta, acc = real(sk, shard, count, eps, state, keys)
+        return state, theta.at[..., 0].set(0.0), acc  # a lost write
+
+    mp.setattr(backends, "_chunk_one", altered)
+
+
+FAULTS = {
+    "frozen_step": (_frozen_step, [LOGREG, COVTYPE]),
+    "half_batch": (_half_batch, [LOGREG, COVTYPE]),
+    "answer_altered": (_answer_altered, [LOGREG, COVTYPE]),
+}
+CASES = [(f, w) for f, (_, ws) in FAULTS.items() for w in ws]
+
+
+def _per_job(limits):
+    return {k: v for k, v in limits.items() if k in check.JOB_NAMES}
+
+
+def test_a_sound_run_is_correct():
+    # a few jobs, so the window's average sheds some Monte Carlo error
+    result = _run(_cell(LOGREG), seconds=8.0)
+    assert result["correct"], result["check"]
+    assert result["attempted"] >= 2 and result["failed"] == 0
+
+
+def test_a_sound_run_on_a_twelfth_of_the_rows_passes_the_per_job_limits():
+    # the window's limits are set at the cell's own size: on ~1,000-row
+    # shards the parametric product's own bias is larger than on 12,105
+    c = _cell(COVTYPE)
+    result = _run(c, seconds=8.0)
+    reading = {k: v["value"] for k, v in result["check"].items()}
+    assert check.judge(reading, _per_job(c.limits)), result["check"]
+
+
+@pytest.mark.parametrize("fault,workload", CASES)
+def test_a_broken_timed_path_is_not_correct(fault, workload, fresh_programs):
+    FAULTS[fault][0](fresh_programs)
+    result = _run(_cell(workload))
+    assert not result["correct"], result["check"]
+
+
+@pytest.mark.parametrize("workload", [LOGREG, COVTYPE])
+def test_the_control_is_not_correct_and_its_float32_witness_is(workload):
+    c = _cell(workload)
+    key = seed_key(SEED)
+    data = c.model.make_data(jax.random.fold_in(key, 0), c.config)
+    ref = c.model.laplace(np.asarray(data["x"]), np.asarray(data["y"]), c.config)
+    readings = {}
+    for dtype in (jnp.bfloat16, jnp.float32):
+        sub, comb = c.model.control(jax.random.fold_in(key, 1), data["x"], data["y"],
+                                    c.config, dtype)
+        ms = [check.moments(np.asarray(sub, np.float32), np.asarray(comb, np.float32))]
+        readings[dtype] = check.readings(ms, ref)[1]
+    assert not check.judge(readings[jnp.bfloat16], c.limits), readings
+    # one job of the plain sampler in float32 is within every per-job limit
+    assert check.judge(readings[jnp.float32], _per_job(c.limits)), readings
