@@ -27,10 +27,12 @@ e. Kernel parity: each Pallas kernel against its reference at phase b's
 on one device (mesh (1, 1)), in this process, and holds them to the
 contract ``tests/test_mesh_stream.py`` pins for the fused stream.
 
-Each phase prints one JSON line with its wall time, which ends after the
-results are on the host, and the XLA compile seconds inside it, reported
-apart. A failed phase makes the script exit 1. The last line of standard
-output is ``{"ok": true, "device": {...}}`` only when every phase passed.
+Each phase runs inside a ``smoke.<phase>`` span and prints one JSON line
+with its wall time, which ends after the results are on the host, and the
+XLA compile seconds (executables compiled or read from the cache) counted
+by its span and by the spans other threads ran meanwhile, reported apart.
+A failed phase makes the script exit 1. The last line of standard output
+is ``{"ok": true, "device": {...}}`` only when every phase passed.
 """
 
 from __future__ import annotations
@@ -40,7 +42,6 @@ import asyncio
 import json
 import math
 import sys
-import time
 import traceback
 from pathlib import Path
 
@@ -89,18 +90,15 @@ def _stop(msg: str) -> None:
     sys.exit(1)
 
 
-class CompileClock:
-    """Sums XLA's backend-compile durations as JAX reports them."""
+def _compile_s(phase):
+    """Compile seconds of ``phase``'s span and of the outermost spans that
+    other threads (the server's sampler and executor) opened and closed
+    inside it; the phase itself is the only outermost span of its thread."""
+    from repro.utils.spans import records
 
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        self.total = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **_):
-        if event == self.EVENT:
-            self.total += duration
+    return sum(r.counters.get("backend_compile_s", 0.0) for r in records()
+               if r.parent is None
+               and phase.start_ns <= r.start_ns and r.end_ns <= phase.end_ns)
 
 
 def _close(name, got, want, rtol, atol):
@@ -392,17 +390,17 @@ def main(argv=None) -> int:
         ("b_batch", phase_batch), ("c_fused_stream", phase_stream),
         ("d_serve", phase_serve), ("e_kernels", phase_kernels),
     ])
-    clock = CompileClock()
+    from repro.utils.spans import span
+
     all_ok = True
     for name, fn in phases:
-        c0, t0 = clock.total, time.perf_counter()
-        try:
-            info, ok = fn(), True
-        except Exception:  # noqa: BLE001 — report the phase, run the rest
-            info, ok = {"error": traceback.format_exc(limit=8)}, False
-        rec = {"phase": name, "ok": ok,
-               "wall_s": time.perf_counter() - t0,
-               "xla_compile_s": clock.total - c0, **info}
+        with span(f"smoke.{name}") as phase:
+            try:
+                info, ok = fn(), True
+            except Exception:  # noqa: BLE001 — report the phase, run the rest
+                info, ok = {"error": traceback.format_exc(limit=8)}, False
+        rec = {"phase": name, "ok": ok, "wall_s": phase.seconds,
+               "xla_compile_s": _compile_s(phase), **info}
         print(json.dumps(rec, default=float), flush=True)
         all_ok &= ok
     if not all_ok:

@@ -67,6 +67,7 @@ from repro.core.combiners import (
 )
 from repro.models.bayes import get_model
 from repro.samplers import sampler_spec
+from repro.utils.spans import span
 
 PyTree = Any
 
@@ -105,6 +106,7 @@ def gather_draws(theta: jnp.ndarray) -> jnp.ndarray:
     return jax.device_put(theta, jax.devices()[0])
 
 
+@span("combine.stage")
 def combine_spec_draws(
     spec: RunSpec,
     base_key: jax.Array,
@@ -118,7 +120,9 @@ def combine_spec_draws(
     scoreboard entries, and it also makes each combiner's result independent
     of which subset ``names`` selects); options merge the spec's
     ``combiner_options`` over the driver defaults and are filtered per
-    combiner signature by the ``combine_gathered`` backend.
+    combiner signature by the ``combine_gathered`` backend. The call is the
+    ``combine.stage`` span (:mod:`repro.utils.spans`), each combiner a
+    ``combine.<name>`` span inside it.
     """
     # late import — epmcmc pulls the heavy LM stack
     from repro.distributed.epmcmc import combine_gathered
@@ -129,9 +133,10 @@ def combine_spec_draws(
     out: Dict[str, CombineResult] = {}
     for name in names if names is not None else spec.combiner_names():
         k_name = jax.random.fold_in(kc, zlib.crc32(name.encode()) & 0x7FFFFFFF)
-        out[name] = combine_gathered(
-            k_name, theta, spec.T, combiner=name, **options
-        )
+        with span(f"combine.{name}"):
+            out[name] = combine_gathered(
+                k_name, theta, spec.T, combiner=name, **options
+            )
     return out
 
 
@@ -264,7 +269,7 @@ class Pipeline:
             )
         self.checkpoint_every = checkpoint_every
         self.check_hlo = check_hlo
-        self.timings: Dict[str, float] = {}
+        self.timings: Dict[str, float] = {}  # from the pipeline.* spans
         self._model = get_model(spec.model)
         self._key = jax.random.PRNGKey(spec.seed)
         self._sharded: Optional[ShardedData] = None
@@ -319,72 +324,70 @@ class Pipeline:
             or bool(on_chunk)
         )
         sharded = self.partition()
-        t0 = time.time()
-        ndev = jax.device_count()
-        mesh_shape = spec.mesh_shape
-        if mesh_shape is None and ndev > 1 and spec.M % ndev == 0:
-            mesh_shape = (ndev, 1)
-        use_mesh = mesh_shape is not None and mesh_shape[0] > 1
-        if use_mesh and not wants_stream:
-            if max_steps is not None:
-                raise ValueError(
-                    "max_steps needs a checkpoint_dir: a partial sampling "
-                    "stage is only useful if it can be resumed"
+        with span("pipeline.sample") as rec:
+            ndev = jax.device_count()
+            mesh_shape = spec.mesh_shape
+            if mesh_shape is None and ndev > 1 and spec.M % ndev == 0:
+                mesh_shape = (ndev, 1)
+            use_mesh = mesh_shape is not None and mesh_shape[0] > 1
+            if use_mesh and not wants_stream:
+                if max_steps is not None:
+                    raise ValueError(
+                        "max_steps needs a checkpoint_dir: a partial sampling "
+                        "stage is only useful if it can be resumed"
+                    )
+                res = sample_subposteriors(
+                    jax.random.fold_in(self._key, 1),
+                    self._model,
+                    sharded.data,
+                    spec.M,
+                    spec.T,
+                    sampler=spec.sampler,
+                    warmup=spec.warmup,
+                    burn_in=spec.resolved_burn_in(),
+                    step_size=spec.step_size,
+                    sgld_batch=spec.sgld_batch,
+                    check_hlo=self.check_hlo,
+                    mesh_shape=mesh_shape,
+                    sampler_options=spec.sampler_options,
+                    shards=sharded.shards,
+                    counts=sharded.counts,
                 )
-            res = sample_subposteriors(
-                jax.random.fold_in(self._key, 1),
-                self._model,
-                sharded.data,
-                spec.M,
-                spec.T,
-                sampler=spec.sampler,
-                warmup=spec.warmup,
-                burn_in=spec.resolved_burn_in(),
-                step_size=spec.step_size,
-                sgld_batch=spec.sgld_batch,
-                check_hlo=self.check_hlo,
-                mesh_shape=mesh_shape,
-                sampler_options=spec.sampler_options,
-                shards=sharded.shards,
-                counts=sharded.counts,
-            )
-            t_done, complete = spec.T, True
-        else:
-            if max_steps is not None and self.checkpoint_dir is None:
-                raise ValueError(
-                    "max_steps needs a checkpoint_dir: a partial sampling "
-                    "stage is only useful if it can be resumed"
+                t_done, complete = spec.T, True
+            else:
+                if max_steps is not None and self.checkpoint_dir is None:
+                    raise ValueError(
+                        "max_steps needs a checkpoint_dir: a partial sampling "
+                        "stage is only useful if it can be resumed"
+                    )
+                rs = stream_sample(
+                    jax.random.fold_in(self._key, 1),
+                    self._model,
+                    sharded.data,
+                    spec.M,
+                    spec.T,
+                    sampler=spec.sampler,
+                    warmup=spec.warmup,
+                    burn_in=spec.resolved_burn_in(),
+                    step_size=spec.step_size,
+                    sgld_batch=spec.sgld_batch,
+                    sampler_options=spec.sampler_options,
+                    shards=sharded.shards,
+                    counts=sharded.counts,
+                    chunk_size=spec.stream_every,
+                    max_steps=max_steps,
+                    checkpoint_dir=self.checkpoint_dir,
+                    checkpoint_every=self.checkpoint_every,
+                    spec_id=spec.spec_id,
+                    on_chunk=on_chunk,
+                    mesh_shape=mesh_shape if use_mesh else None,
+                    check_hlo=self.check_hlo,
                 )
-            rs = stream_sample(
-                jax.random.fold_in(self._key, 1),
-                self._model,
-                sharded.data,
-                spec.M,
-                spec.T,
-                sampler=spec.sampler,
-                warmup=spec.warmup,
-                burn_in=spec.resolved_burn_in(),
-                step_size=spec.step_size,
-                sgld_batch=spec.sgld_batch,
-                sampler_options=spec.sampler_options,
-                shards=sharded.shards,
-                counts=sharded.counts,
-                chunk_size=spec.stream_every,
-                max_steps=max_steps,
-                checkpoint_dir=self.checkpoint_dir,
-                checkpoint_every=self.checkpoint_every,
-                spec_id=spec.spec_id,
-                on_chunk=on_chunk,
-                mesh_shape=mesh_shape if use_mesh else None,
-                check_hlo=self.check_hlo,
-            )
-            res, t_done, complete = rs.result, rs.t_done, rs.complete
-        # stage timers read the clock after the device finishes, not after
-        # the enqueue
-        jax.block_until_ready((res.theta, res.accept))
-        self.timings["sample_s"] = self.timings.get("sample_s", 0.0) + (
-            time.time() - t0
-        )
+                res, t_done, complete = rs.result, rs.t_done, rs.complete
+            # stage timers read the clock after the device finishes, not after
+            # the enqueue
+            jax.block_until_ready((res.theta, res.accept))
+        self.timings["sample_s"] = self.timings.get("sample_s", 0.0) + rec.seconds
         self._draws = SubposteriorDraws(
             res.theta, res.accept, res.counts, res.backend,
             res.collectives_checked, t_done, complete,
@@ -399,21 +402,21 @@ class Pipeline:
         if self._groundtruth is None:
             spec = self.spec
             gt_step = groundtruth_step_size(spec)
-            t0 = time.time()
-            self._groundtruth = groundtruth_chain(
-                jax.random.fold_in(self._key, 2),
-                self._model,
-                self.partition().data,
-                spec.groundtruth_T,
-                sampler=spec.sampler,
-                warmup=spec.warmup,
-                burn_in=spec.groundtruth_T // 6,
-                step_size=gt_step,
-                sgld_batch=spec.sgld_batch,
-                sampler_options=spec.sampler_options,
-            )
-            jax.block_until_ready(self._groundtruth)
-            self.timings["groundtruth_s"] = time.time() - t0
+            with span("pipeline.groundtruth") as rec:
+                self._groundtruth = groundtruth_chain(
+                    jax.random.fold_in(self._key, 2),
+                    self._model,
+                    self.partition().data,
+                    spec.groundtruth_T,
+                    sampler=spec.sampler,
+                    warmup=spec.warmup,
+                    burn_in=spec.groundtruth_T // 6,
+                    step_size=gt_step,
+                    sgld_batch=spec.sgld_batch,
+                    sampler_options=spec.sampler_options,
+                )
+                jax.block_until_ready(self._groundtruth)
+            self.timings["groundtruth_s"] = rec.seconds
         return self._groundtruth
 
     # -- stage 3b: combine-while-sampling ------------------------------------
@@ -567,22 +570,20 @@ class Pipeline:
 
         final: Dict[str, CombineResult] = {}
         if draws.complete:
-            t0 = time.time()
-            for name in names:
-                fn = scs[name].finalize
-                final[name] = fn(
-                    k_names[name], states[name], spec.T,
-                    **filter_options(fn, options),
-                )
-            jax.block_until_ready(final)
-            self.timings["stream_combine_s"] = time.time() - t0
+            with span("pipeline.stream_combine") as rec:
+                for name in names:
+                    fn = scs[name].finalize
+                    final[name] = fn(
+                        k_names[name], states[name], spec.T,
+                        **filter_options(fn, options),
+                    )
+                jax.block_until_ready(final)
+            self.timings["stream_combine_s"] = rec.seconds
             # the finals ARE the combine-stage results (bitwise for the
             # buffered implementations) — let score() reuse them
             if self._combined is None and set(names) == set(spec.combiner_names()):
                 self._combined = dict(final)
-                self.timings.setdefault(
-                    "combine_s", self.timings["stream_combine_s"]
-                )
+                self.timings.setdefault("combine_s", rec.seconds)
 
         label = ""
         if score:
@@ -631,67 +632,67 @@ class Pipeline:
         chunk = spec.stream_every
         counts_T = jnp.full((spec.M,), spec.T, jnp.int32)
 
-        t0 = time.time()
-        n_full, tail = divmod(spec.T, chunk)
-        boundaries = tuple(chunk * (i + 1) for i in range(n_full)) + (
-            (spec.T,) if tail else ()
-        )
-        est_keys = {
-            name: jnp.stack(
-                [jax.random.fold_in(k_names[name], t1) for t1 in boundaries]
+        with span("pipeline.stream_combine") as rec:
+            n_full, tail = divmod(spec.T, chunk)
+            boundaries = tuple(chunk * (i + 1) for i in range(n_full)) + (
+                (spec.T,) if tail else ()
             )
-            for name in names
-            if faces[name].estimate is not None and scs[name].estimate is not None
-        }
-        ff = fused_fold(
-            theta, {n: faces[n] for n in names}, est_keys, n_estimate,
-            chunk, options,
-        )
+            est_keys = {
+                name: jnp.stack(
+                    [jax.random.fold_in(k_names[name], t1) for t1 in boundaries]
+                )
+                for name in names
+                if faces[name].estimate is not None and scs[name].estimate is not None
+            }
+            ff = fused_fold(
+                theta, {n: faces[n] for n in names}, est_keys, n_estimate,
+                chunk, options,
+            )
 
-        rows: List[Dict[str, Any]] = []
-        estimates: List[Tuple[int, str, jnp.ndarray]] = []
-        for i, t1 in enumerate(ff.boundaries):
+            rows: List[Dict[str, Any]] = []
+            estimates: List[Tuple[int, str, jnp.ndarray]] = []
+            for i, t1 in enumerate(ff.boundaries):
+                for name in names:
+                    est_fn = scs[name].estimate
+                    if est_fn is None:
+                        continue  # no mid-stream row on the subscriber path either
+                    if name in est_keys:
+                        samples = ff.est_draws[name][i]
+                    else:
+                        prefix = BufferState(
+                            theta[:, :t1], jnp.full((spec.M,), t1, jnp.int32)
+                        )
+                        samples = est_fn(
+                            jax.random.fold_in(k_names[name], t1), prefix,
+                            n_estimate, **filter_options(est_fn, options),
+                        ).samples
+                    estimates.append((t1, name, samples))
+                    rows.append({
+                        "t": t1, "combiner": name, "error": None, "elapsed_s": None,
+                    })
+            # honest per-boundary stamps: each row's clock reads only after THAT
+            # row's estimate is device-complete, so elapsed_s is the row's true
+            # availability instant (monotone in landing order) — not one post-run
+            # stamp smeared across the trajectory. The fused program materializes
+            # estimates close together, so consecutive stamps may be near-equal;
+            # they are still each row's own wall-clock.
+            for row, (_, _, samples) in zip(rows, estimates):
+                jax.block_until_ready(samples)
+                row["elapsed_s"] = time.time() - t_start
+
+            final: Dict[str, CombineResult] = {}
             for name in names:
-                est_fn = scs[name].estimate
-                if est_fn is None:
-                    continue  # no mid-stream row on the subscriber path either
-                if name in est_keys:
-                    samples = ff.est_draws[name][i]
-                else:
-                    prefix = BufferState(
-                        theta[:, :t1], jnp.full((spec.M,), t1, jnp.int32)
-                    )
-                    samples = est_fn(
-                        jax.random.fold_in(k_names[name], t1), prefix,
-                        n_estimate, **filter_options(est_fn, options),
-                    ).samples
-                estimates.append((t1, name, samples))
-                rows.append({
-                    "t": t1, "combiner": name, "error": None, "elapsed_s": None,
-                })
-        # honest per-boundary stamps: each row's clock reads only after THAT
-        # row's estimate is device-complete, so elapsed_s is the row's true
-        # availability instant (monotone in landing order) — not one post-run
-        # stamp smeared across the trajectory. The fused program materializes
-        # estimates close together, so consecutive stamps may be near-equal;
-        # they are still each row's own wall-clock.
-        for row, (_, _, samples) in zip(rows, estimates):
-            jax.block_until_ready(samples)
-            row["elapsed_s"] = time.time() - t_start
-
-        final: Dict[str, CombineResult] = {}
-        for name in names:
-            fn = scs[name].finalize
-            host_state = faces[name].to_state(ff.states[name], theta, counts_T)
-            final[name] = fn(
-                k_names[name], host_state, spec.T,
-                **filter_options(fn, options),
-            )
-        jax.block_until_ready(final)
-        self.timings["stream_combine_s"] = time.time() - t0
+                fn = scs[name].finalize
+                host_state = faces[name].to_state(ff.states[name], theta, counts_T)
+                final[name] = fn(
+                    k_names[name], host_state, spec.T,
+                    **filter_options(fn, options),
+                )
+            jax.block_until_ready(final)
+        self.timings["stream_combine_s"] = rec.seconds
         if self._combined is None and set(names) == set(spec.combiner_names()):
             self._combined = dict(final)
-            self.timings.setdefault("combine_s", self.timings["stream_combine_s"])
+            self.timings.setdefault("combine_s", rec.seconds)
 
         label = ""
         if score:
@@ -721,10 +722,10 @@ class Pipeline:
                     f"sampling stage incomplete ({draws.t_done}/{spec.T} "
                     "draws) — call sample() until complete before combine()"
                 )
-            t0 = time.time()
-            self._combined = combine_spec_draws(spec, self._key, draws.theta)
-            jax.block_until_ready(self._combined)
-            self.timings["combine_s"] = time.time() - t0
+            with span("pipeline.combine") as rec:
+                self._combined = combine_spec_draws(spec, self._key, draws.theta)
+                jax.block_until_ready(self._combined)
+            self.timings["combine_s"] = rec.seconds
         return self._combined
 
     # -- stage 4: score ------------------------------------------------------

@@ -35,6 +35,7 @@ from repro.core.subposterior import make_subposterior_logpdf, partition_data
 from repro.models.bayes import BayesModel
 from repro.samplers import filter_options, run_chain, sampler_spec
 from repro.samplers.base import MCMCKernel
+from repro.utils.spans import count, span
 
 PyTree = Any
 
@@ -285,6 +286,7 @@ def make_shard_sampler(
     return one_shard
 
 
+@span("sample.stage")
 def sample_subposteriors(
     key: jax.Array,
     model: BayesModel,
@@ -313,7 +315,8 @@ def sample_subposteriors(
     ``data`` axis of a ``(ndev, 1)`` ("data", "model") mesh (override via
     ``mesh_shape``) and the compiled HLO is asserted collective-free across
     chains; otherwise the chains are vmapped on one device. Zero cross-chain
-    communication either way.
+    communication either way. The call is the ``sample.stage`` span
+    (:mod:`repro.utils.spans`); its ``steps`` count each chain's transitions.
     """
     sampler = sampler or model.default_sampler
     if shards is None or counts is None:
@@ -334,6 +337,7 @@ def sample_subposteriors(
         use_counts=padded,
         sampler_options=sampler_options,
     )
+    count("steps", warmup + burn_in + num_samples)
     keys = jax.random.split(key, num_shards)
     in_axes = (_shard_axes(shards, model.shard_keys, 0, None), 0, 0)
     vmapped = jax.vmap(one_shard, in_axes=in_axes)

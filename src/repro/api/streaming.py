@@ -74,6 +74,7 @@ from repro.api.backends import (  # noqa: F401  (historical homes re-exported)
     get_chunk_backend,
 )
 from repro.api.sampling import SampleResult, ShardKernel, is_padded
+from repro.utils.spans import count, span
 
 PyTree = Any
 
@@ -267,26 +268,28 @@ class ShardChainStream:
             t1 = min(t_done + chunk, T)
             if t1 > stop:
                 break  # ragged chunk would shift later boundaries; stop here
-            state, theta_c, acc_c = self.chunk_fn(
-                self.shards,
-                self.counts,
-                carry["eps"],
-                carry["state"],
-                collect_keys[:, t_done:t1],
-            )
-            carry = {
-                "state": state,
-                "eps": carry["eps"],
-                "k_collect": carry["k_collect"],
-                "theta": jnp.concatenate([carry["theta"], theta_c], axis=1),
-                "accept_sum": carry["accept_sum"] + acc_c,
-            }
-            t0, t_done = t_done, t1
-            # emitted chunks leave the backend's device layout (mesh
-            # sharding must not leak into subscriber/combiner numerics)
-            theta_l = self.backend.localize(theta_c)
-            acc_l = self.backend.localize(acc_c)
-            jax.block_until_ready(theta_l)  # honest landed_s: draws are real
+            with span("sample.chunk"):
+                count("steps", t1 - t_done)
+                state, theta_c, acc_c = self.chunk_fn(
+                    self.shards,
+                    self.counts,
+                    carry["eps"],
+                    carry["state"],
+                    collect_keys[:, t_done:t1],
+                )
+                carry = {
+                    "state": state,
+                    "eps": carry["eps"],
+                    "k_collect": carry["k_collect"],
+                    "theta": jnp.concatenate([carry["theta"], theta_c], axis=1),
+                    "accept_sum": carry["accept_sum"] + acc_c,
+                }
+                t0, t_done = t_done, t1
+                # emitted chunks leave the backend's device layout (mesh
+                # sharding must not leak into subscriber/combiner numerics)
+                theta_l = self.backend.localize(theta_c)
+                acc_l = self.backend.localize(acc_c)
+                jax.block_until_ready(theta_l)  # honest landed_s: draws are real
             yield StreamChunk(
                 theta_l, acc_l, t0, t1, T, carry,
                 landed_s=time.monotonic(),
@@ -319,6 +322,7 @@ def _restore_carry(checkpoint_dir, step, state_struct, d, num_shards):
     return restore(checkpoint_dir, step=step, template=template)
 
 
+@span("sample.stage")
 def stream_sample(
     key: jax.Array,
     model: BayesModel,
@@ -360,6 +364,10 @@ def stream_sample(
     checkpointing, and fused semantics, with each compiled program's HLO
     asserted collective-free across chain groups (``check_hlo=False`` skips
     the assert).
+
+    The call is the ``sample.stage`` span (:mod:`repro.utils.spans`), each
+    chunk a ``sample.chunk``; their ``steps`` count every chain's
+    sequential transitions (warmup and burn-in included).
     """
     chunk = chunk_size if chunk_size > 0 else checkpoint_every
     if checkpoint_every > 0 and chunk_size > 0 and checkpoint_every % chunk_size:
@@ -413,6 +421,7 @@ def stream_sample(
         and max_steps is None
         and 0 < chunk < num_samples
     ):
+        count("steps", warmup + burn_in + num_samples)
         theta, accept_sum = stream.fused_sample(chunk)
         return StreamedSample(
             result=SampleResult(
@@ -479,6 +488,7 @@ def stream_sample(
                 for sub in on_chunk:
                     sub(ev)
     else:
+        count("steps", warmup + burn_in)  # the setup's transitions
         carry = stream.fresh_carry()
         t_done = 0
         resumed_from = 0

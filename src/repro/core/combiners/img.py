@@ -78,6 +78,7 @@ from repro.core.gaussian import (
     log_normal_pdf,
     product_moments,
 )
+from repro.utils.spans import count, span
 
 Schedule = Callable[[jnp.ndarray], jnp.ndarray]
 
@@ -412,15 +413,18 @@ def run_img(
     n_batch = max(1, min(int(n_batch), int(n_draws)))
     n_sweeps = -(-n_draws // n_batch)  # ceil
 
-    if weight_eval == "kernel":
-        draws, n_acc = _run_batched_kernel(
-            key, samples, counts, n_sweeps, n_batch, schedule, model
-        )
-        draws = draws.reshape(n_sweeps * n_batch, d)
-        per_chain = n_acc / (n_sweeps * M)
-        n_acc = jnp.sum(n_acc)
-    elif weight_eval == "incremental":
-        if n_batch == 1:
+    if weight_eval not in ("kernel", "incremental"):
+        raise ValueError(f"unknown weight_eval {weight_eval!r}")
+    with span("combine.img.chain"):
+        count("img_sites", n_sweeps * n_batch * M)
+        if weight_eval == "kernel":
+            draws, n_acc = _run_batched_kernel(
+                key, samples, counts, n_sweeps, n_batch, schedule, model
+            )
+            draws = draws.reshape(n_sweeps * n_batch, d)
+            per_chain = n_acc / (n_sweeps * M)
+            n_acc = jnp.sum(n_acc)
+        elif n_batch == 1:
             draws, n_acc = _run_chain(key, samples, counts, n_sweeps, schedule, model)
             per_chain = (n_acc / (n_sweeps * M))[None]
         else:
@@ -435,8 +439,6 @@ def run_img(
             draws = jnp.swapaxes(draws, 0, 1).reshape(n_sweeps * n_batch, d)
             per_chain = n_acc / (n_sweeps * M)
             n_acc = jnp.sum(n_acc)
-    else:
-        raise ValueError(f"unknown weight_eval {weight_eval!r}")
 
     # ceil-rounding emits < n_batch surplus draws; drop the *earliest* (least
     # annealed) rows so the kept draws are the best of every chain.
@@ -547,7 +549,8 @@ def nonparametric(
     """Algorithm 1 — asymptotically exact sampling from ∏_m KDE(p_m)."""
     counts = counts_or_full(samples, counts)
     schedule = _resolve_schedule(samples, schedule, rescale)
-    model = nonparametric_model(samples)
+    with span("combine.img.model"):
+        model = nonparametric_model(samples)
     return run_img(
         key, samples, n_draws, model,
         counts=counts, schedule=schedule, n_batch=n_batch, weight_eval=weight_eval,
@@ -571,9 +574,10 @@ def semiparametric(
     """§3.3 semiparametric combiner (see :func:`semiparametric_model`)."""
     counts = counts_or_full(samples, counts)
     schedule = _resolve_schedule(samples, schedule, rescale)
-    model = semiparametric_model(
-        samples, counts, nonparametric_weights=nonparametric_weights
-    )
+    with span("combine.img.model"):
+        model = semiparametric_model(
+            samples, counts, nonparametric_weights=nonparametric_weights
+        )
     return run_img(
         key, samples, n_draws, model,
         counts=counts, schedule=schedule, n_batch=n_batch, weight_eval=weight_eval,

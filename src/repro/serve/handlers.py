@@ -45,6 +45,7 @@ import numpy as np
 from repro.core.combiners import EstimateUnavailable, counts_or_full
 from repro.core.combiners.density import machine_kde_scores, masked_silverman
 from repro.serve.state import ServeState
+from repro.utils.spans import span
 
 DEFAULT_PROBS = (0.05, 0.25, 0.5, 0.75, 0.95)
 
@@ -139,6 +140,7 @@ HANDLERS = {
 }
 
 
+@span("serve.answer")
 def answer(state: ServeState, request: Dict[str, Any]) -> Dict[str, Any]:
     """Dispatch one request dict. Refused requests and unavailable estimates
     become typed ``{"ok": False, "error": ...}`` responses (still carrying

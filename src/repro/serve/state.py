@@ -44,6 +44,7 @@ from repro.core.combiners import (
     filter_options,
     streaming_estimate,
 )
+from repro.utils.spans import span
 
 
 class EstimateSnapshot(NamedTuple):
@@ -108,6 +109,7 @@ class ServeState:
 
     # -- folding (one writer) ------------------------------------------------
 
+    @span("serve.fold")
     def fold(self, ev: StreamChunk) -> None:
         """Fold one landed chunk into every combiner state (+ draw buffer).
 
@@ -133,6 +135,7 @@ class ServeState:
             self._draws_seen = int(ev.t1)
             self._last_fold_monotonic_s = landed
 
+    @span("serve.refresh")
     def refresh(self, names: Optional[Tuple[str, ...]] = None) -> None:
         """Recompute the snapshot for each named combiner (default: all that
         can). Keys are ``fold_in(key_name, draws_seen)`` — the trajectory
