@@ -56,20 +56,29 @@ Execution modes (:func:`run_img`):
     quantity the state-level correction log N(θ̄ | μ̂_M, Σ̂_M + h²/M I) +
     Σ_m aux needs — O(B·d) per site, the same asymptotics as the Gram
     precompute. The pure-``w_t`` models skip all of it at trace time.
+
+Programs: a registered combiner runs two jitted programs, the weight-model
+build (:func:`model_arrays`, under the ``combine.img.model`` span) and the
+chains (under ``combine.img.chain``). Their static arguments are the model's
+kind and the options that fix shapes, so every later call with the same
+shapes finds both programs in jit's in-memory cache: a weight model is
+arrays (:class:`ImgModelArrays`) plus a kind, and its callables are rebuilt
+inside the trace.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.core import bandwidth as bw
 from repro.core.combiners.api import (
     CombineResult,
     counts_or_full,
     register,
-    resolve_schedule as _resolve_schedule,
     valid_masks,
 )
 from repro.core.gaussian import (
@@ -390,6 +399,99 @@ def _run_batched_kernel(
 # ---------------------------------------------------------------------------
 
 
+# One program per shape, dtype and static option: later calls find it in
+# jit's in-memory cache instead of re-tracing the chain.
+@functools.partial(
+    jax.jit, static_argnames=("n_draws", "n_batch", "weight_eval", "schedule", "weights")
+)
+def _chain_program(
+    key: jax.Array,
+    samples: jnp.ndarray,
+    counts: Optional[jnp.ndarray],
+    arrays: ImgModelArrays,
+    *,
+    n_draws: int,
+    n_batch: int,
+    weight_eval: str,
+    schedule: Optional[Schedule],
+    weights,
+):
+    """The IMG chains → (draws, acceptance rate, diagnostics).
+
+    ``schedule=None`` anneals at Algorithm 1's rate times ``arrays.scale``;
+    a caller's schedule is a static argument, so the same object finds the
+    same program. ``weights``: see :func:`_weight_model`.
+    """
+    M, T, d = samples.shape
+    n_sweeps = -(-n_draws // n_batch)  # ceil
+    counts = counts_or_full(samples, counts)
+    model = _weight_model(weights, arrays, samples)
+    if schedule is None:
+        schedule = bw.annealed(d, scale=arrays.scale)
+
+    if weight_eval == "kernel":
+        draws, n_acc = _run_batched_kernel(
+            key, samples, counts, n_sweeps, n_batch, schedule, model
+        )
+        draws = draws.reshape(n_sweeps * n_batch, d)
+        per_chain = n_acc / (n_sweeps * M)
+        n_acc = jnp.sum(n_acc)
+    elif n_batch == 1:
+        draws, n_acc = _run_chain(key, samples, counts, n_sweeps, schedule, model)
+        per_chain = (n_acc / (n_sweeps * M))[None]
+    else:
+        keys = jax.random.split(key, n_batch)
+        offsets = jnp.arange(1, n_batch + 1, dtype=jnp.float32)
+        draws, n_acc = jax.vmap(
+            lambda k, off: _run_chain(
+                k, samples, counts, n_sweeps, schedule, model,
+                anneal_offset=off, anneal_stride=n_batch,
+            )
+        )(keys, offsets)
+        draws = jnp.swapaxes(draws, 0, 1).reshape(n_sweeps * n_batch, d)
+        per_chain = n_acc / (n_sweeps * M)
+        n_acc = jnp.sum(n_acc)
+
+    extras = {
+        "n_batch": jnp.asarray(n_batch),
+        "n_sweeps_per_chain": jnp.asarray(n_sweeps),
+        "per_chain_acceptance": per_chain,
+    }
+    # ceil-rounding emits < n_batch surplus draws; drop the *earliest* (least
+    # annealed) rows so the kept draws are the best of every chain.
+    return draws[-n_draws:], n_acc / (n_sweeps * n_batch * M), extras
+
+
+def _run(
+    key: jax.Array,
+    samples: jnp.ndarray,
+    n_draws: int,
+    arrays: ImgModelArrays,
+    weights,
+    *,
+    counts: Optional[jnp.ndarray],
+    schedule: Optional[Schedule],
+    n_batch: int,
+    weight_eval: str,
+) -> CombineResult:
+    """Run the chain program inside the ``combine.img.chain`` span."""
+    M = samples.shape[0]
+    n_draws = int(n_draws)
+    n_batch = max(1, min(int(n_batch), n_draws))
+    if weight_eval not in ("kernel", "incremental"):
+        raise ValueError(f"unknown weight_eval {weight_eval!r}")
+    with span("combine.img.chain"):
+        count("img_sites", -(-n_draws // n_batch) * n_batch * M)
+        draws, rate, extras = _chain_program(
+            key, samples, counts, arrays,
+            n_draws=n_draws, n_batch=n_batch, weight_eval=weight_eval,
+            schedule=schedule, weights=weights,
+        )
+    return CombineResult(
+        samples=draws, acceptance_rate=rate, moments=arrays.prod, extras=extras
+    )
+
+
 def run_img(
     key: jax.Array,
     samples: jnp.ndarray,
@@ -401,74 +503,138 @@ def run_img(
     n_batch: int = 1,
     weight_eval: str = "incremental",
 ) -> CombineResult:
-    """Run the IMG engine and package draws + diagnostics.
+    """Run the IMG engine on a caller's weight model; package draws + diagnostics.
 
     ``n_batch``: number of independent index-chains (each does
     ``ceil(n_draws/n_batch)`` sweeps). ``weight_eval``: ``"incremental"``
     (O(d) single-site recursion) or ``"kernel"`` (vectorized sweeps scored by
     the Pallas ``img_weights`` kernel; supports every registered weight model
-    including full semiparametric ``W_t``).
+    including full semiparametric ``W_t``). The model's callables and
+    ``schedule`` are static arguments of the chain program: the same objects
+    find the same program, new ones trace it anew.
     """
-    M, T, d = samples.shape
-    n_batch = max(1, min(int(n_batch), int(n_draws)))
-    n_sweeps = -(-n_draws // n_batch)  # ceil
-
-    if weight_eval not in ("kernel", "incremental"):
-        raise ValueError(f"unknown weight_eval {weight_eval!r}")
-    with span("combine.img.chain"):
-        count("img_sites", n_sweeps * n_batch * M)
-        if weight_eval == "kernel":
-            draws, n_acc = _run_batched_kernel(
-                key, samples, counts, n_sweeps, n_batch, schedule, model
-            )
-            draws = draws.reshape(n_sweeps * n_batch, d)
-            per_chain = n_acc / (n_sweeps * M)
-            n_acc = jnp.sum(n_acc)
-        elif n_batch == 1:
-            draws, n_acc = _run_chain(key, samples, counts, n_sweeps, schedule, model)
-            per_chain = (n_acc / (n_sweeps * M))[None]
-        else:
-            keys = jax.random.split(key, n_batch)
-            offsets = jnp.arange(1, n_batch + 1, dtype=jnp.float32)
-            draws, n_acc = jax.vmap(
-                lambda k, off: _run_chain(
-                    k, samples, counts, n_sweeps, schedule, model,
-                    anneal_offset=off, anneal_stride=n_batch,
-                )
-            )(keys, offsets)
-            draws = jnp.swapaxes(draws, 0, 1).reshape(n_sweeps * n_batch, d)
-            per_chain = n_acc / (n_sweeps * M)
-            n_acc = jnp.sum(n_acc)
-
-    # ceil-rounding emits < n_batch surplus draws; drop the *earliest* (least
-    # annealed) rows so the kept draws are the best of every chain.
-    draws = draws[-n_draws:]
-    return CombineResult(
-        samples=draws,
-        acceptance_rate=n_acc / (n_sweeps * n_batch * M),
-        moments=model.moments,
-        extras={
-            "n_batch": jnp.asarray(n_batch),
-            "n_sweeps_per_chain": jnp.asarray(n_sweeps),
-            "per_chain_acceptance": per_chain,
-        },
+    arrays = ImgModelArrays(
+        aux=model.aux, prod=model.moments, lam_m=None, eta_m=None, scale=jnp.float32(1.0)
+    )
+    return _run(
+        key, samples, n_draws, arrays, (model.extra_logweight, model.draw),
+        counts=counts, schedule=schedule, n_batch=n_batch, weight_eval=weight_eval,
     )
 
 
 # ---------------------------------------------------------------------------
-# weight models
+# weight models: arrays built from one job's draws, callables rebuilt in trace
 # ---------------------------------------------------------------------------
+
+NONPARAMETRIC = "nonparametric"  # w_t weights, KDE components (§3.2)
+SEMIPARAMETRIC = "semiparametric"  # W_t weights, semiparametric components
+SEMIPARAMETRIC_W = "semiparametric_w"  # w_t weights, semiparametric components
+
+
+class ImgModelArrays(NamedTuple):
+    """The arrays of a weight model, built from one job's draws.
+
+    ``aux`` as in :class:`ImgWeightModel`. ``prod``: the Gaussian product
+    (μ̂_M, Σ̂_M) of the subposterior moments, ``lam_m`` = Σ̂_M^{-1},
+    ``eta_m`` = Σ̂_M^{-1} μ̂_M (semiparametric kinds; None otherwise).
+    ``scale``: the default anneal's scale (the pooled sample scale under
+    ``rescale``, else 1).
+    """
+
+    aux: Optional[jnp.ndarray]
+    prod: Optional[GaussianMoments]
+    lam_m: Optional[jnp.ndarray]
+    eta_m: Optional[jnp.ndarray]
+    scale: jnp.ndarray
+
+
+def model_arrays(
+    samples: jnp.ndarray,
+    counts: Optional[jnp.ndarray],
+    *,
+    kind: str,
+    rescale: bool,
+) -> ImgModelArrays:
+    """Build the arrays of the ``kind`` weight model (§3.2 / §3.3)."""
+    d = samples.shape[-1]
+    scale = bw.pooled_scale(samples) if rescale else jnp.float32(1.0)
+    if kind == NONPARAMETRIC:
+        return ImgModelArrays(aux=None, prod=None, lam_m=None, eta_m=None, scale=scale)
+    masks = valid_masks(samples, counts_or_full(samples, counts))
+
+    # Parametric start: per-subposterior moments and their Gaussian product.
+    moments = jax.vmap(lambda s, mk: fit_moments(s, mk))(samples, masks)
+    prod = product_moments(moments.mean, moments.cov)
+    lam_m = jnp.linalg.inv(prod.cov + 1e-10 * jnp.eye(d))  # Σ̂_M^{-1}
+    eta_m = jnp.matmul(lam_m, prod.mean, precision=_F32)  # Σ̂_M^{-1} μ̂_M
+
+    aux = None
+    if kind == SEMIPARAMETRIC:
+        # term3: −Σ_m log N(θ^m_{t_m} | μ̂_m, Σ̂_m), gathered incrementally.
+        aux = -jax.vmap(lambda s, mom: log_normal_pdf(s, mom[0], mom[1]))(
+            samples, (moments.mean, moments.cov)
+        )  # (M, T)
+    return ImgModelArrays(aux=aux, prod=prod, lam_m=lam_m, eta_m=eta_m, scale=scale)
+
+
+def _nonparametric_draw(M, d, dtype, key, mean, h):
+    """§3.2 component: N(θ̄_t, h²/M I)."""
+    eps = jax.random.normal(key, (d,), dtype)
+    return mean + eps * h / jnp.sqrt(jnp.asarray(M, dtype))
+
+
+def _semiparametric_extra_logweight(arrays: ImgModelArrays, M, d, h):
+    """W_t's state-level term for bandwidth h, as ``term(mean, extra_sum)``."""
+    prod = arrays.prod
+    cov_i = prod.cov + (h**2 / M) * jnp.eye(d)
+
+    def term(mean, extra_sum):
+        # + log N(θ̄ | μ̂_M, Σ̂_M + h²/M I) + Σ_m aux  (aux already −logN)
+        return log_normal_pdf(mean, prod.mean, cov_i) + extra_sum
+
+    return term
+
+
+def _semiparametric_draw(arrays: ImgModelArrays, M, d, dtype, key, mean, h):
+    """§3.3 component N(μ_t, Σ_t), in precision form: P = M/h² I + Λ_M,
+    θ = μ_t + chol(P)^{-T} ε."""
+    h2 = h**2
+    prec = (M / h2) * jnp.eye(d) + arrays.lam_m
+    chol_p = jnp.linalg.cholesky(prec)
+    rhs = (M / h2) * mean + arrays.eta_m
+    mu_t = jax.scipy.linalg.cho_solve((chol_p, True), rhs)
+    eps = jax.random.normal(key, (d,), dtype)
+    return mu_t + jax.scipy.linalg.solve_triangular(chol_p.T, eps, lower=False)
+
+
+def _weight_model(weights, arrays: ImgModelArrays, samples: jnp.ndarray) -> ImgWeightModel:
+    """The weight model's callables over ``arrays``.
+
+    ``weights`` is a kind name, or the ``(extra_logweight, draw)`` callables
+    of a caller's :class:`ImgWeightModel`.
+    """
+    if not isinstance(weights, str):
+        extra_logweight, draw = weights
+        return ImgWeightModel(
+            aux=arrays.aux, extra_logweight=extra_logweight, draw=draw, moments=None
+        )
+    M, _, d = samples.shape
+    if weights == NONPARAMETRIC:
+        draw = functools.partial(_nonparametric_draw, M, d, samples.dtype)
+        return ImgWeightModel(aux=None, extra_logweight=None, draw=draw, moments=None)
+    extra_logweight = None
+    if weights == SEMIPARAMETRIC:
+        extra_logweight = functools.partial(_semiparametric_extra_logweight, arrays, M, d)
+    draw = functools.partial(_semiparametric_draw, arrays, M, d, samples.dtype)
+    return ImgWeightModel(
+        aux=arrays.aux, extra_logweight=extra_logweight, draw=draw, moments=arrays.prod
+    )
 
 
 def nonparametric_model(samples: jnp.ndarray) -> ImgWeightModel:
     """§3.2: weights w_t (Eq. 3.5), components N(θ̄_t, h²/M I)."""
-    M, _, d = samples.shape
-
-    def draw(key, mean, h):
-        eps = jax.random.normal(key, (d,), samples.dtype)
-        return mean + eps * h / jnp.sqrt(jnp.asarray(M, samples.dtype))
-
-    return ImgWeightModel(aux=None, extra_logweight=None, draw=draw, moments=None)
+    arrays = model_arrays(samples, None, kind=NONPARAMETRIC, rescale=False)
+    return _weight_model(NONPARAMETRIC, arrays, samples)
 
 
 def semiparametric_model(
@@ -486,51 +652,41 @@ def semiparametric_model(
     ``nonparametric_weights=True``: the paper's second variant — weights w_t
         (higher IMG acceptance), same semiparametric components.
     """
-    M, T, d = samples.shape
-    masks = valid_masks(samples, counts)
-
-    # Parametric start: per-subposterior moments and their Gaussian product.
-    moments = jax.vmap(lambda s, mk: fit_moments(s, mk))(samples, masks)
-    prod = product_moments(moments.mean, moments.cov)
-    lam_m = jnp.linalg.inv(prod.cov + 1e-10 * jnp.eye(d))  # Σ̂_M^{-1}
-    eta_m = jnp.matmul(lam_m, prod.mean, precision=_F32)  # Σ̂_M^{-1} μ̂_M
-
-    if nonparametric_weights:
-        aux = None
-        extra_logweight = None
-    else:
-        # term3: −Σ_m log N(θ^m_{t_m} | μ̂_m, Σ̂_m), gathered incrementally.
-        aux = -jax.vmap(lambda s, mom: log_normal_pdf(s, mom[0], mom[1]))(
-            samples, (moments.mean, moments.cov)
-        )  # (M, T)
-
-        def extra_logweight(h):
-            cov_i = prod.cov + (h**2 / M) * jnp.eye(d)
-
-            def term(mean, extra_sum):
-                # + log N(θ̄ | μ̂_M, Σ̂_M + h²/M I) + Σ_m aux  (aux already −logN)
-                return log_normal_pdf(mean, prod.mean, cov_i) + extra_sum
-
-            return term
-
-    def draw(key, mean, h):
-        # Precision form: P = M/h² I + Λ_M, θ = μ_t + chol(P)^{-T} ε.
-        h2 = h**2
-        prec = (M / h2) * jnp.eye(d) + lam_m
-        chol_p = jnp.linalg.cholesky(prec)
-        rhs = (M / h2) * mean + eta_m
-        mu_t = jax.scipy.linalg.cho_solve((chol_p, True), rhs)
-        eps = jax.random.normal(key, (d,), samples.dtype)
-        return mu_t + jax.scipy.linalg.solve_triangular(chol_p.T, eps, lower=False)
-
-    return ImgWeightModel(
-        aux=aux, extra_logweight=extra_logweight, draw=draw, moments=prod
-    )
+    kind = SEMIPARAMETRIC_W if nonparametric_weights else SEMIPARAMETRIC
+    arrays = model_arrays(samples, counts, kind=kind, rescale=False)
+    return _weight_model(kind, arrays, samples)
 
 
 # ---------------------------------------------------------------------------
 # registered combiners
 # ---------------------------------------------------------------------------
+
+
+# the model build's program, like the chain's: one per shape and static option
+_model_program = jax.jit(model_arrays, static_argnames=("kind", "rescale"))
+
+
+def _combine(
+    kind: str,
+    key: jax.Array,
+    samples: jnp.ndarray,
+    n_draws: int,
+    *,
+    counts: Optional[jnp.ndarray],
+    schedule: Optional[Schedule],
+    rescale: bool,
+    n_batch: int,
+    weight_eval: str,
+) -> CombineResult:
+    """A registered IMG combiner: the model program, then the chain program."""
+    with span("combine.img.model"):
+        arrays = _model_program(
+            samples, counts, kind=kind, rescale=bool(rescale) and schedule is None
+        )
+    return _run(
+        key, samples, n_draws, arrays, kind,
+        counts=counts, schedule=schedule, n_batch=n_batch, weight_eval=weight_eval,
+    )
 
 
 @register("nonparametric", "nonparametric_img")
@@ -547,13 +703,9 @@ def nonparametric(
     **_ignored,
 ) -> CombineResult:
     """Algorithm 1 — asymptotically exact sampling from ∏_m KDE(p_m)."""
-    counts = counts_or_full(samples, counts)
-    schedule = _resolve_schedule(samples, schedule, rescale)
-    with span("combine.img.model"):
-        model = nonparametric_model(samples)
-    return run_img(
-        key, samples, n_draws, model,
-        counts=counts, schedule=schedule, n_batch=n_batch, weight_eval=weight_eval,
+    return _combine(
+        NONPARAMETRIC, key, samples, n_draws, counts=counts, schedule=schedule,
+        rescale=rescale, n_batch=n_batch, weight_eval=weight_eval,
     )
 
 
@@ -572,15 +724,10 @@ def semiparametric(
     **_ignored,
 ) -> CombineResult:
     """§3.3 semiparametric combiner (see :func:`semiparametric_model`)."""
-    counts = counts_or_full(samples, counts)
-    schedule = _resolve_schedule(samples, schedule, rescale)
-    with span("combine.img.model"):
-        model = semiparametric_model(
-            samples, counts, nonparametric_weights=nonparametric_weights
-        )
-    return run_img(
-        key, samples, n_draws, model,
-        counts=counts, schedule=schedule, n_batch=n_batch, weight_eval=weight_eval,
+    return _combine(
+        SEMIPARAMETRIC_W if nonparametric_weights else SEMIPARAMETRIC,
+        key, samples, n_draws, counts=counts, schedule=schedule,
+        rescale=rescale, n_batch=n_batch, weight_eval=weight_eval,
     )
 
 
