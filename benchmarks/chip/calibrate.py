@@ -10,7 +10,7 @@ print (the lower reading is the largest of these); for each of
 ``--control-seeds`` the control, the plain reference sampler put in the
 program's place in bfloat16, over ``--control-jobs`` jobs, and the same
 sampler in float32 beside it as a witness. One JSON line per reading on
-standard output; ``--save`` keeps every job's moments. The benchmark's own
+standard output; ``--save`` keeps every job's summary. The benchmark's own
 runs never run this.
 """
 
@@ -30,7 +30,7 @@ os.environ["JAX_COMPILATION_CACHE_DIR"] = str(HERE.parents[1] / ".jax_cache")
 
 
 def _save(save, name: str, ms, ref) -> None:
-    """Every job's moments and the reference, to recompute any number."""
+    """Every job's summary and the reference, to recompute any number."""
     import numpy as np
 
     if save is None:
@@ -46,25 +46,23 @@ def control_reading(c, seed: int, dtype_name: str, jobs: int, save=None) -> dict
     ``jobs`` jobs with keys of their own on one seed's data."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
     from chipbench import check
     from chipbench.jobs import seed_key
 
+    m, cfg = c.model, c.config
     key = seed_key(seed)
-    data = c.model.make_data(jax.random.fold_in(key, 0), c.config)
+    data = m.make_data(jax.random.fold_in(key, 0), cfg)
     t0 = time.perf_counter()
-    ms = []
-    for j in range(1, jobs + 1):
-        sub, combined = c.model.control(
-            jax.random.fold_in(jax.random.fold_in(key, 1), j), data["x"], data["y"],
-            c.config, getattr(jnp, dtype_name),
-        )
-        ms.append(check.moments(np.asarray(sub, np.float32), np.asarray(combined, np.float32)))
+    summaries = [
+        m.summarize(m.control(jax.random.fold_in(jax.random.fold_in(key, 1), j), data,
+                              cfg, getattr(jnp, dtype_name)), cfg)
+        for j in range(1, jobs + 1)
+    ]
     seconds = (time.perf_counter() - t0) / jobs
-    ref = c.model.laplace(np.asarray(data["x"]), np.asarray(data["y"]), c.config)
-    _save(save, f"{c.name}_control_{dtype_name}_{seed}", ms, ref)
-    per_job, worst = check.readings(ms, ref)
+    ref = m.reference(data, cfg)
+    _save(save, f"{c.name}_control_{dtype_name}_{seed}", summaries, ref)
+    per_job, worst = m.readings(summaries, ref, cfg)
     return {"workload": c.name, "who": f"control_{dtype_name}", "seed": seed,
             "jobs": jobs, "seconds_per_job": seconds, "reading": worst,
             "correct": check.judge(worst, c.limits)}
@@ -73,20 +71,19 @@ def control_reading(c, seed: int, dtype_name: str, jobs: int, save=None) -> dict
 def program_reading(c, seed: int, jobs_per_seed: int, save=None) -> dict:
     """The program's jobs on one seed's data, checked as a run checks them."""
     import jax
-    import numpy as np
 
     from chipbench import check
     from chipbench.jobs import Jobs, seed_key
 
+    m, cfg = c.model, c.config
     key = seed_key(seed)
-    data = c.model.make_data(jax.random.fold_in(key, 0), c.config)
-    jobs = Jobs(c.config, c.traffic, c.chips, data, key)
-    outputs = [jobs.run(j) for j in range(1, jobs_per_seed + 1)]
-    ms = [check.moments(np.asarray(o.theta), np.asarray(o.combined)) for o in outputs]
-    ref = c.model.laplace(np.asarray(data["x"]), np.asarray(data["y"]), c.config)
-    _save(save, f"{c.name}_program_{seed}", ms, ref)
-    per_job, worst = check.readings(ms, ref)
-    return {"workload": c.name, "who": "program", "seed": seed, "jobs": len(outputs),
+    data = m.make_data(jax.random.fold_in(key, 0), cfg)
+    jobs = Jobs(c, data, key)
+    summaries = [m.summarize(jobs.run(j), cfg) for j in range(1, jobs_per_seed + 1)]
+    ref = m.reference(data, cfg)
+    _save(save, f"{c.name}_program_{seed}", summaries, ref)
+    per_job, worst = m.readings(summaries, ref, cfg)
+    return {"workload": c.name, "who": "program", "seed": seed, "jobs": len(summaries),
             "reading": worst, "correct": check.judge(worst, c.limits)}
 
 
@@ -98,7 +95,7 @@ def main(argv=None) -> int:
     ap.add_argument("--control-seeds", default="")
     ap.add_argument("--control-jobs", type=int, default=1)
     ap.add_argument("--save", type=Path, default=None,
-                    help="write every job's moments and the reference here")
+                    help="write every job's summary and the reference here")
     args = ap.parse_args(argv)
 
     from chipbench import cell, device

@@ -5,10 +5,12 @@ part of a cell is a file of its own, found by that name:
 
 - ``configs/<file>.json``: the configuration as it is run (model, N, d, M,
   sampler, warmup, T, burn-in, prior) and ``model_file``, the module beside
-  it with the data generator, the work count and the plain reference;
+  it with the data generator, the work count, the plain reference and the
+  check's numbers (its contract is in ``chipbench.harness``);
 - ``traffic/<traffic>.json``: what one job is (the combiner and its options,
   the stream cadence) and how jobs are issued;
-- ``limits/<workload>.json``: the limit of each number the check compares;
+- ``limits/<workload>.json``: the limit of each number the check compares,
+  among those the model file's ``NUMBERS`` name;
 - ``metrics/<metric>.py``: one reader per per-layer metric.
 """
 
@@ -62,14 +64,19 @@ def find(workload: str, bench: Dict[str, Any] | None = None) -> Cell:
     entry = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
     config = read_json(REPO / configs[entry["config"]]["file"])
-    model_file = BENCH_DIR / "configs" / config["model_file"]
+    model = load_module(BENCH_DIR / "configs" / config["model_file"])
+    limits = read_json(BENCH_DIR / "limits" / f"{workload}.json")
+    unknown = set(limits) - set(model.NUMBERS)
+    if unknown:
+        raise ValueError(f"limits of {workload!r} name no number of its model file: "
+                         f"{sorted(unknown)}")
     return Cell(
         name=workload,
         chips=int(entry["chips"]),
         config=config,
         traffic=read_json(BENCH_DIR / "traffic" / f"{entry['traffic']}.json"),
-        limits=read_json(BENCH_DIR / "limits" / f"{workload}.json"),
-        model=load_module(model_file),
+        limits=limits,
+        model=model,
         end_to_end=[m for m in bench["end_to_end"] if applies(m, workload)],
         per_layer=[m for m in bench["per_layer"] if applies(m, workload)],
     )

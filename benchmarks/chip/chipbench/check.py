@@ -1,4 +1,8 @@
-"""The comparison that decides ``correct``: draws against the reference.
+"""Draws against a reference, by their moments; and ``judge``.
+
+The library of the comparison that a model file whose jobs yield draws
+calls (``configs/logistic_regression.py``); :func:`judge` holds any
+model file's numbers to a cell's limits.
 
 A job yields the subposterior draws ``(M, T, d)`` (the sampler and model
 layer) and the combined draws ``(T, d)`` (the combine layer). The reference
@@ -24,8 +28,7 @@ the wrong rows moves every job's mean the same way).
 A root mean square over a shard's coordinates is steady from seed to seed
 where a single coordinate's error is not, and a shard whose chain goes wrong
 moves it as a whole. A number that is not finite reads as infinite, so it
-fails every limit. A cell's ``limits/<workload>.json`` names the numbers it
-compares, each with its limit.
+fails every limit.
 """
 
 from __future__ import annotations

@@ -5,8 +5,28 @@ one whole job, which compiles every program the window uses. The window
 then runs whole jobs back to back for ``--seconds``. With ``--trace 1`` the
 traffic mix's ``trace_jobs`` jobs follow under the profiler, and the
 per-layer metrics are read from their trace. Then the device's peak memory
-is read, the reference is computed on the host, and every job is compared
-with it: the check is the same with and without the trace.
+is read, and every job is compared with the reference: the check is the
+same with and without the trace.
+
+The check belongs to the configuration's model file, which provides:
+
+- ``make_data(key, cfg)``: the data, on the device, from the seed;
+- ``job_flops(cfg)``: the algorithm's work in one job;
+- ``handoff(sample)``: what the combine stage takes from the sample
+  stage's result (see ``chipbench.jobs``);
+- ``reference(data, cfg)``: the plain reference, once a run, after the
+  window, from the arrays ``make_data`` made;
+- ``summarize(output, cfg)``: one job's ``jobs.Output`` reduced to what
+  the check keeps;
+- ``readings(summaries, ref, cfg)``: ``(per_job, window)``, a dict of the
+  job-scope numbers for each job, and a dict of every number over the
+  window (a job-scope number's worst job);
+- ``NUMBERS``: ``{name: {"layer": "sampling" | "combine", "scope": "job" |
+  "window"}}``, the numbers a cell's limits may name.
+
+A job fails where one of its job-scope numbers is over its limit; every
+job fails where a window-scope number is. Summaries and the reference are
+dicts of arrays.
 """
 
 from __future__ import annotations
@@ -21,7 +41,6 @@ from pathlib import Path
 from typing import Any, Dict, List, Optional
 
 import jax
-import numpy as np
 
 from chipbench import check, trace
 from chipbench.cell import Cell, reader
@@ -51,6 +70,17 @@ def _traced(jobs: Jobs, first: int, n: int, chips: int, keep: Optional[Path]):
             shutil.rmtree(log_dir, ignore_errors=True)
 
 
+def failures(numbers: Dict[str, Dict[str, str]], limits: Dict[str, float],
+             per_job: List[Dict[str, float]], window: Dict[str, float]) -> int:
+    """The jobs that fail the cell's limits, judged by each number's scope."""
+    def scoped(scope):
+        return {k: v for k, v in limits.items() if numbers[k]["scope"] == scope}
+
+    if not check.judge(window, scoped("window")):
+        return len(per_job)  # the window's number is every job's
+    return sum(not check.judge(r, scoped("job")) for r in per_job)
+
+
 def execute(cell: Cell, seed: int, seconds: float, traced: bool,
             devices: List[jax.Device], t_start: float,
             keep_trace: Optional[Path] = None) -> Dict[str, Any]:
@@ -59,7 +89,7 @@ def execute(cell: Cell, seed: int, seconds: float, traced: bool,
     cfg = cell.config
     key = seed_key(seed)
     data = cell.model.make_data(jax.random.fold_in(key, 0), cfg)
-    jobs = Jobs(cfg, cell.traffic, cell.chips, data, key)
+    jobs = Jobs(cell, data, key)
     warm = jobs.run(0)  # compiles every program the window drives
     del warm
     setup_compiles = clock.snapshot()
@@ -96,19 +126,18 @@ def execute(cell: Cell, seed: int, seconds: float, traced: bool,
         for m in cell.end_to_end:
             metrics[m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
 
-    # the check: every job of the window against the reference, on the host
+    # the check: every job of the window against the reference
     t_ref = time.perf_counter()
-    ref = cell.model.laplace(np.asarray(data["x"]), np.asarray(data["y"]), cfg)
-    ms = [check.moments(np.asarray(o.theta), np.asarray(o.combined)) for o in outputs]
-    per_job, worst = check.readings(ms, ref)
-    failed = sum(not check.judge(r, cell.limits) for r in per_job)
-    if not check.judge({k: worst[k] for k in check.WINDOW_NAMES}, cell.limits):
-        failed = len(outputs)  # the window's average is every job's
+    model = cell.model
+    ref = model.reference(data, cfg)
+    per_job, window = model.readings([model.summarize(o, cfg) for o in outputs], ref, cfg)
+    failed = failures(model.NUMBERS, cell.limits, per_job, window)
+    job_numbers = [k for k, n in model.NUMBERS.items() if n["scope"] == "job"]
     _log(info="run", workload=cell.name, seed=seed, trace=int(traced), setup_s=setup_s,
          setup_compiles=setup_compiles, window_compiles=window_compiles,
          jobs=len(outputs), sample_s=[o.sample_s for o in outputs],
          combine_s=[o.combine_s for o in outputs], reference_s=time.perf_counter() - t_ref,
-         readings={k: [r[k] for r in per_job] for k in check.JOB_NAMES}, **timing)
+         readings={k: [r[k] for r in per_job] for k in job_numbers}, **timing)
     result: Dict[str, Any] = {
         "correct": bool(outputs) and failed == 0,
         "attempted": len(outputs),
@@ -119,7 +148,7 @@ def execute(cell: Cell, seed: int, seconds: float, traced: bool,
     if breakdown is not None:
         result["breakdown"] = breakdown
     result["check"] = {
-        k: {"value": worst[k], "limit": float(limit)} for k, limit in cell.limits.items()
+        k: {"value": window[k], "limit": float(limit)} for k, limit in cell.limits.items()
     }
     return result
 

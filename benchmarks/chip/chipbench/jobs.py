@@ -9,6 +9,11 @@ set-up, by the program's own ``partition_data``. Every job has a sampling
 key of its own, derived from the seed and the job's index, with the
 pipeline's key discipline (sampling ``fold_in(key, 1)``, combine streams
 under ``fold_in(key, 3)``).
+
+What the combine stage takes from the sample stage's result is the
+configuration's to say: its model file's ``handoff``. The sample stage's
+time ends when that is ready, the combine stage's when the combined draws
+are.
 """
 
 from __future__ import annotations
@@ -26,6 +31,8 @@ from repro.api.streaming import stream_sample
 from repro.core.subposterior import partition_data
 from repro.models.bayes import get_model
 
+from chipbench.cell import Cell
+
 
 def seed_key(seed: int) -> jax.Array:
     """A key from the whole seed: both 32-bit halves count."""
@@ -34,8 +41,8 @@ def seed_key(seed: int) -> jax.Array:
 
 
 class Output(NamedTuple):
-    theta: jax.Array  # (M, T, d) subposterior draws
-    combined: jax.Array  # (T, d) combined draws
+    sample: Any  # the sample stage's result, as the program returns it
+    combine: Dict[str, Any]  # the combine stage's result: {combiner: CombineResult}
     sample_s: float
     combine_s: float
 
@@ -43,10 +50,11 @@ class Output(NamedTuple):
 class Jobs:
     """The program set up for one cell's configuration and traffic mix."""
 
-    def __init__(self, config: Dict[str, Any], traffic: Dict[str, Any],
-                 chips: int, data: Dict[str, jax.Array], key: jax.Array):
+    def __init__(self, cell: Cell, data: Dict[str, jax.Array], key: jax.Array):
+        config, traffic, chips = cell.config, cell.traffic, cell.chips
         if traffic["loop"] != "closed":
             raise ValueError(f"unknown loop {traffic['loop']!r}")
+        self.handoff = cell.model.handoff
         self.model = get_model(config["model"])
         self.combiner = traffic["combiner"]
         self.spec = RunSpec(
@@ -62,6 +70,8 @@ class Jobs:
             stream_every=int(traffic["stream_every"]),
             mesh_shape=(chips, 1),
             combiner_options=traffic.get("combiner_options", {}),
+            # RunSpec's defaults where the configuration states none
+            **{k: config[k] for k in ("sampler_options", "sgld_batch") if k in config},
         ).validate()
         self.use_mesh = chips > 1
         self.data = data
@@ -74,7 +84,7 @@ class Jobs:
     def job_key(self, j: int) -> jax.Array:
         return jax.random.fold_in(jax.random.fold_in(self.key, 1), j)
 
-    def sample(self, key: jax.Array) -> jax.Array:
+    def sample(self, key: jax.Array):
         """The sample stage, routed as ``Pipeline.sample`` routes it."""
         spec, k = self.spec, jax.random.fold_in(key, 1)
         common = dict(
@@ -95,12 +105,14 @@ class Jobs:
                 mesh_shape=spec.mesh_shape if self.use_mesh else None,
                 **common,
             ).result
-        return jax.block_until_ready(res.theta)
+        jax.block_until_ready(self.handoff(res))
+        return res
 
-    def combine(self, key: jax.Array, theta: jax.Array) -> jax.Array:
+    def combine(self, key: jax.Array, sample) -> Dict[str, Any]:
         """The combine stage, as ``Pipeline.combine`` runs it."""
-        res = combine_spec_draws(self.spec, key, theta, (self.combiner,))
-        return jax.block_until_ready(res[self.combiner].samples)
+        res = combine_spec_draws(self.spec, key, self.handoff(sample), (self.combiner,))
+        jax.block_until_ready(res[self.combiner].samples)
+        return res
 
     def run(self, j: int) -> Output:
         """Job ``j``, under the spans the trace attributes device time to."""
@@ -108,12 +120,12 @@ class Jobs:
         with TraceAnnotation("job"):
             t0 = time.perf_counter()
             with TraceAnnotation("sample"):
-                theta = self.sample(key)
+                sample = self.sample(key)
             t1 = time.perf_counter()
             with TraceAnnotation("combine"):
-                combined = self.combine(key, theta)
+                combined = self.combine(key, sample)
             t2 = time.perf_counter()
-        return Output(theta, combined, t1 - t0, t2 - t1)
+        return Output(sample, combined, t1 - t0, t2 - t1)
 
     def loop(self, first: int, seconds: float, min_jobs: int = 1) -> tuple:
         """Jobs back to back from index ``first`` until ``seconds`` have

@@ -1,7 +1,9 @@
 """Bayesian logistic regression (arXiv:1311.4780 §8.1): data, work and reference.
 
 Shared by every configuration file here whose ``model_file`` names it. It holds
-what the benchmark needs about the model and takes nothing from the program:
+what the benchmark needs about the model, in the contract of
+``chipbench.harness``, and takes nothing from the program but the results
+it checks:
 
 - ``make_data``: the data set from a key, in one jitted call on the device.
   The two designs are the paper's synthetic set (§8.1.1: X, β ~ N(0, 1),
@@ -17,6 +19,11 @@ what the benchmark needs about the model and takes nothing from the program:
   Tierney & Kadane 1990). At the paper's signal strength the mode alone sits
   ~0.45 sd from a 5,000-row subposterior's mean; the corrected mean is within
   the chains' Monte Carlo error of it.
+- ``reference``: ``laplace`` on the data, on the host.
+- ``handoff``, ``summarize``, ``readings``, ``NUMBERS``: a job hands the
+  combine stage its ``(M, T, d)`` subposterior draws; each job's
+  subposterior and combined draws are reduced to their means and sds and
+  compared with the reference by ``chipbench.check``'s six numbers.
 - ``control``: a plain MALA chain per shard and the Gaussian product of the
   draws, in jnp at any dtype. Run in bfloat16 it is the check's control.
 """
@@ -24,13 +31,23 @@ what the benchmark needs about the model and takes nothing from the program:
 from __future__ import annotations
 
 import math
-from typing import Dict, Tuple
+from types import SimpleNamespace
+from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import check
+
 HIGHEST = jax.lax.Precision.HIGHEST
+
+NUMBERS = {
+    **{k: {"layer": "sampling", "scope": "job"} for k in ("sub_mean", "sub_sd")},
+    **{k: {"layer": "combine", "scope": "job"} for k in ("comb_mean", "comb_sd")},
+    "sub_mean_window": {"layer": "sampling", "scope": "window"},
+    "comb_mean_window": {"layer": "combine", "scope": "window"},
+}
 
 
 def make_data(key: jax.Array, cfg: Dict) -> Dict[str, jax.Array]:
@@ -130,14 +147,36 @@ def laplace(x, y, cfg: Dict) -> Dict[str, np.ndarray]:
             "full_mean": full_mean, "full_cov": full_cov}
 
 
-def control(key: jax.Array, x, y, cfg: Dict, dtype) -> Tuple[jax.Array, jax.Array]:
+def reference(data: Dict[str, jax.Array], cfg: Dict) -> Dict[str, np.ndarray]:
+    return laplace(np.asarray(data["x"]), np.asarray(data["y"]), cfg)
+
+
+def handoff(sample) -> jax.Array:
+    """The subposterior draws ``(M, T, d)`` of the program's sample result."""
+    return sample.theta
+
+
+def summarize(output, cfg: Dict) -> check.Moments:
+    (combined,) = output.combine.values()
+    return check.moments(np.asarray(output.sample.theta), np.asarray(combined.samples))
+
+
+def readings(summaries: List[check.Moments], ref, cfg: Dict):
+    return check.readings(summaries, ref)
+
+
+def control(key: jax.Array, data: Dict[str, jax.Array], cfg: Dict, dtype) -> SimpleNamespace:
     """Plain MALA on each shard, then the Gaussian product, at ``dtype``.
 
-    Returns ``(sub_draws (M, T, d), combined (T, d))``. The chain (data,
-    position, log density, gradient, proposal) is held in ``dtype``; only the
-    step-size bookkeeping of the warmup is float32. The product's Cholesky
-    algebra runs in float32, as jnp.linalg has no bfloat16 path.
+    Returns a job's output in the program's place, as ``summarize`` reads
+    it: ``sample.theta`` the subposterior draws ``(M, T, d)``, and
+    ``combine`` one result whose ``samples`` are the combined draws
+    ``(T, d)``. The chain (data, position, log density, gradient, proposal)
+    is held in ``dtype``; only the step-size bookkeeping of the warmup is
+    float32. The product's Cholesky algebra runs in float32, as jnp.linalg
+    has no bfloat16 path.
     """
+    x, y = data["x"], data["y"]
     n, d = x.shape
     m, t = int(cfg["M"]), int(cfg["T"])
     warmup, burn = int(cfg["warmup"]), int(cfg["burn_in"])
@@ -208,4 +247,5 @@ def control(key: jax.Array, x, y, cfg: Dict, dtype) -> Tuple[jax.Array, jax.Arra
     chol = jnp.linalg.cholesky(full_cov)
     z = jax.random.normal(jax.random.fold_in(key, 1), (t, d))
     combined = (full_mean + z @ chol.T).astype(dtype)
-    return sub, combined
+    return SimpleNamespace(sample=SimpleNamespace(theta=sub),
+                           combine={"control": SimpleNamespace(samples=combined)})

@@ -6,11 +6,16 @@ size, and the covertype cells' traffic on the covertype design cut to a
 twelfth of its rows, against those cells' limits. A sound run gets a few
 jobs in its window; a broken one gets one.
 Each fault breaks the timed path underneath the harness, in the program,
-and the run has to come out not correct.
+and the run has to come out not correct. The check belongs to the model
+file: through it the logistic-regression cells read what the harness read
+before, and a linear-Gaussian configuration that lives in these tests
+alone is judged by numbers of its own.
 """
 
 from __future__ import annotations
 
+import json
+import shutil
 import sys
 import time
 from pathlib import Path
@@ -203,15 +208,99 @@ def test_a_broken_timed_path_is_not_correct(fault, workload, fresh_programs):
 @pytest.mark.parametrize("workload", [LOGREG, COVTYPE])
 def test_the_control_is_not_correct_and_its_float32_witness_is(workload):
     c = _cell(workload)
+    m, cfg = c.model, c.config
     key = seed_key(SEED)
-    data = c.model.make_data(jax.random.fold_in(key, 0), c.config)
-    ref = c.model.laplace(np.asarray(data["x"]), np.asarray(data["y"]), c.config)
+    data = m.make_data(jax.random.fold_in(key, 0), cfg)
+    ref = m.reference(data, cfg)
     readings = {}
     for dtype in (jnp.bfloat16, jnp.float32):
-        sub, comb = c.model.control(jax.random.fold_in(key, 1), data["x"], data["y"],
-                                    c.config, dtype)
-        ms = [check.moments(np.asarray(sub, np.float32), np.asarray(comb, np.float32))]
-        readings[dtype] = check.readings(ms, ref)[1]
+        job = m.control(jax.random.fold_in(key, 1), data, cfg, dtype)
+        readings[dtype] = m.readings([m.summarize(job, cfg)], ref, cfg)[1]
     assert not check.judge(readings[jnp.bfloat16], c.limits), readings
     # one job of the plain sampler in float32 is within every per-job limit
     assert check.judge(readings[jnp.float32], _per_job(c.limits)), readings
+
+
+# -- the model file's contract ------------------------------------------------
+
+
+@pytest.mark.parametrize("fault", [None, "half_batch"])
+def test_the_contract_reads_what_the_draw_moments_read(fault, fresh_programs):
+    """The harness's check through the model file equals the comparison the
+    harness made itself before the check moved there: ``check.moments`` and
+    ``check.readings`` on ``laplace``, for the same outputs, of a sound and
+    of a broken sampler (at a size where the cell's limits need not hold)."""
+    from chipbench.jobs import Jobs
+
+    if fault:
+        FAULTS[fault][0](fresh_programs)
+    c = _cell(LOGREG)
+    cfg = dict(c.config, N=2000, M=2, T=300, warmup=50, burn_in=50)
+    c = c._replace(config=cfg)
+    outputs = []
+    loop = Jobs.loop
+
+    def kept(self, *args, **kwargs):
+        out, seconds = loop(self, *args, **kwargs)
+        outputs.extend(out)
+        return out, seconds
+
+    fresh_programs.setattr(Jobs, "loop", kept)
+    result = _run(c, seconds=0.5)
+
+    data = c.model.make_data(jax.random.fold_in(seed_key(SEED), 0), cfg)
+    ref = c.model.laplace(np.asarray(data["x"]), np.asarray(data["y"]), cfg)
+    ms = [check.moments(np.asarray(o.sample.theta),
+                        np.asarray(o.combine["semiparametric"].samples)) for o in outputs]
+    per_job, worst = check.readings(ms, ref)
+    failed = sum(not check.judge(r, c.limits) for r in per_job)
+    if not check.judge({k: worst[k] for k in check.WINDOW_NAMES}, c.limits):
+        failed = len(outputs)
+    assert result["check"] == {
+        k: {"value": worst[k], "limit": float(v)} for k, v in c.limits.items()}
+    assert result["attempted"] == len(outputs) >= 1
+    assert result["failed"] == failed
+    assert result["correct"] == (failed == 0)
+
+
+LINEAR_LIMITS = {"shard_z": 0.6, "shard_log_sd": 0.3, "product_z": 1.0,
+                 "product_log_sd": 0.25, "shard_z_mean": 0.4}
+
+
+def _linear_cell(tmp_path, monkeypatch):
+    """A configuration of the program's linear_gaussian model that lives in
+    this test alone: its model file (``testdata/linear_gaussian.py``, exact
+    conjugate reference, numbers of its own), traffic and limits."""
+    for part in ("configs", "traffic", "limits"):
+        (tmp_path / part).mkdir()
+    shutil.copy(HERE / "testdata" / "linear_gaussian.py", tmp_path / "configs")
+    config = {"name": "linear", "model": "linear_gaussian",
+              "model_file": "linear_gaussian.py", "N": 4000, "d": 10, "M": 4,
+              "sampler": "mala", "warmup": 200, "T": 1000, "burn_in": 100,
+              "step_size": 0.1}
+    files = {
+        "configs/linear.json": config,
+        "traffic/batch.json": {"loop": "closed", "combiner": "parametric",
+                               "stream_every": 0, "trace_jobs": 1},
+        "limits/linear.batch.json": LINEAR_LIMITS,
+    }
+    for name, body in files.items():
+        (tmp_path / name).write_text(json.dumps(body))
+    bench = {"configs": [{"name": "linear", "file": str(tmp_path / "configs/linear.json")}],
+             "workloads": [{"name": "linear.batch", "config": "linear",
+                            "traffic": "batch", "chips": 1}],
+             "end_to_end": [], "per_layer": []}
+    monkeypatch.setattr(cell, "BENCH_DIR", tmp_path)
+    return cell.find("linear.batch", bench)
+
+
+@pytest.mark.parametrize("fault", [None, "half_batch"])
+def test_a_configuration_with_a_check_of_its_own(fault, tmp_path, fresh_programs):
+    c = _linear_cell(tmp_path, fresh_programs)
+    assert not set(c.model.NUMBERS) & set(check.NAMES)
+    if fault:
+        FAULTS[fault][0](fresh_programs)
+    result = _run(c, seconds=0.5 if fault is None else 0.0)
+    assert set(result["check"]) == set(LINEAR_LIMITS)
+    assert result["correct"] == (fault is None), result["check"]
+    assert result["failed"] == (0 if fault is None else result["attempted"])

@@ -5,6 +5,7 @@ from __future__ import annotations
 
 import json
 import re
+import shutil
 import sys
 from pathlib import Path
 
@@ -57,21 +58,24 @@ def test_names_units_and_keys():
         assert m["source"] in ("device_trace", "program_span", "program_counter", "host_clock")
 
 
+CONTRACT = ("make_data", "job_flops", "handoff", "reference", "summarize", "readings")
+
+
 @pytest.mark.parametrize("workload", WORKLOADS)
 def test_cell_resolves_by_name(workload):
     c = cell.find(workload, BENCH)
     assert c.chips in (1, 4)
-    for key in ("model", "model_file", "N", "d", "M", "sampler", "warmup", "T",
-                "burn_in", "step_size", "prior_sigma"):
+    for key in ("model", "model_file", "N", "M", "sampler", "warmup", "T", "burn_in", "step_size"):
         assert key in c.config, key
-    for fn in ("make_data", "transition_flops", "job_flops", "laplace", "control"):
+    for fn in CONTRACT:
         assert callable(getattr(c.model, fn)), fn
     assert c.traffic["loop"] == "closed" and c.traffic["trace_jobs"] >= 1
-    from chipbench import check
-
+    numbers = c.model.NUMBERS
+    assert all(n["layer"] in ("sampling", "combine") and n["scope"] in ("job", "window")
+               for n in numbers.values())
     # the numbers compared cover the sampler layer and the combine layer
-    assert set(c.limits) <= set(check.NAMES)
-    assert {"sub_mean", "sub_sd"} & set(c.limits) and {"comb_mean", "comb_sd"} & set(c.limits)
+    assert set(c.limits) <= set(numbers)
+    assert {numbers[k]["layer"] for k in c.limits} == {"sampling", "combine"}
     assert all(v > 0 for v in c.limits.values())
     assert {m["name"] for m in c.end_to_end} >= {"setup_s", "job_s"}
     assert c.per_layer, "every cell reports a per-layer metric"
@@ -98,6 +102,18 @@ def test_every_per_layer_metric_has_a_reader(metric):
 def test_unknown_workload_is_refused():
     with pytest.raises(KeyError):
         cell.find("no-such-config.no-such-traffic", BENCH)
+
+
+def test_a_limit_on_a_number_the_model_file_lacks_is_refused(tmp_path, monkeypatch):
+    # a copy of the benchmark's own files, with one limit its model file lacks
+    for part in ("configs", "traffic", "limits"):
+        shutil.copytree(HERE / part, tmp_path / part)
+    workload = WORKLOADS[0]
+    path = tmp_path / "limits" / f"{workload}.json"
+    path.write_text(json.dumps({**json.loads(path.read_text()), "no_such_number": 1.0}))
+    monkeypatch.setattr(cell, "BENCH_DIR", tmp_path)
+    with pytest.raises(ValueError, match="no_such_number"):
+        cell.find(workload, BENCH)
 
 
 def test_every_traffic_file_and_limit_file_is_used():

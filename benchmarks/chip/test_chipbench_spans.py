@@ -100,15 +100,17 @@ def test_too_few_records_for_the_traced_jobs_read_nothing(monkeypatch):
 
 @pytest.fixture(scope="module")
 def traced_job():
-    """One tiny semiparametric job under the profiler, after a warm-up job."""
+    """One tiny semiparametric job under the profiler that has to obtain its
+    programs anew: a warm-up job, then JAX's in-memory caches cleared."""
     import jax
 
     c = cell.find("logreg-paper.batch-semiparametric")
     cfg = dict(c.config, N=800, M=2, T=60, warmup=10, burn_in=10)
     key = seed_key(2**33 + 12345)
     data = c.model.make_data(jax.random.fold_in(key, 0), cfg)
-    jobs = Jobs(cfg, c.traffic, 1, data, key)
+    jobs = Jobs(c._replace(config=cfg, chips=1), data, key)
     jobs.run(0)
+    jax.clear_caches()
     _, tr = harness._traced(jobs, 1, 1, 1, None)
     return tr
 
