@@ -13,7 +13,12 @@ under ``fold_in(key, 3)``).
 What the combine stage takes from the sample stage's result is the
 configuration's to say: its model file's ``handoff``. The sample stage's
 time ends when that is ready, the combine stage's when the combined draws
-are.
+are. The model the jobs sample is the model file's too, where it builds one
+(``bayes_model``); otherwise the program's registered ``config["model"]``.
+
+A run keeps of each job only the model file's summary of it, made as the job
+ends and outside the window's clock, so the device holds one job's output at
+a time.
 """
 
 from __future__ import annotations
@@ -47,6 +52,15 @@ class Output(NamedTuple):
     combine_s: float
 
 
+class Done(NamedTuple):
+    """What a run keeps of one job once it has ended."""
+
+    summary: Any  # the model file's summarize(output, cfg)
+    sample_s: float
+    combine_s: float
+    summarize_s: float
+
+
 class Jobs:
     """The program set up for one cell's configuration and traffic mix."""
 
@@ -55,7 +69,10 @@ class Jobs:
         if traffic["loop"] != "closed":
             raise ValueError(f"unknown loop {traffic['loop']!r}")
         self.handoff = cell.model.handoff
-        self.model = get_model(config["model"])
+        self._summarize = cell.model.summarize
+        self.config = config
+        build = getattr(cell.model, "bayes_model", None)
+        self.model = build(config) if build else get_model(config["model"])
         self.combiner = traffic["combiner"]
         self.spec = RunSpec(
             model=config["model"],
@@ -127,13 +144,30 @@ class Jobs:
             t2 = time.perf_counter()
         return Output(sample, combined, t1 - t0, t2 - t1)
 
+    def summarize(self, output: Output) -> Done:
+        """The model file's summary of ``output``, finished on the device."""
+        t0 = time.perf_counter()
+        summary = jax.block_until_ready(self._summarize(output, self.config))
+        return Done(summary, output.sample_s, output.combine_s, time.perf_counter() - t0)
+
     def loop(self, first: int, seconds: float, min_jobs: int = 1) -> tuple:
-        """Jobs back to back from index ``first`` until ``seconds`` have
-        passed and ``min_jobs`` have ended: ``(outputs, window_s)``."""
-        outputs: List[Output] = []
+        """Jobs back to back from index ``first`` until ``seconds`` of job
+        time have passed and ``min_jobs`` have ended: ``(done, window_s)``.
+
+        Each job is summarized as it ends and its output dropped before the
+        next job starts. The clock stops while a job is summarized:
+        ``window_s`` is the time from the first job's start to the last
+        job's end, less the time spent in summaries.
+        """
+        done: List[Done] = []
+        paused = 0.0
         start = time.perf_counter()
         while True:
-            outputs.append(self.run(first + len(outputs)))
-            elapsed = time.perf_counter() - start
-            if elapsed >= seconds and len(outputs) >= min_jobs:
-                return outputs, elapsed
+            output = self.run(first + len(done))
+            ended = time.perf_counter()
+            done.append(self.summarize(output))
+            del output  # its device buffers go before the next job starts
+            elapsed = ended - start - paused
+            paused += time.perf_counter() - ended
+            if elapsed >= seconds and len(done) >= min_jobs:
+                return done, elapsed

@@ -3,8 +3,10 @@
 The control and the faults run a whole cell on the CPU, with the harness's
 look for a chip skipped: ``logreg-paper.batch-semiparametric`` at its own
 size, and the covertype cells' traffic on the covertype design cut to a
-twelfth of its rows, against those cells' limits. A sound run gets a few
-jobs in its window; a broken one gets one.
+twelfth of its rows, against those cells' limits. A sound run asks for
+two jobs in its window; a broken one gets one. A run keeps of each job only
+its summary, made as the job ends with the window's clock stopped, and
+samples the model that its model file builds, where it builds one.
 Each fault breaks the timed path underneath the harness, in the program,
 and the run has to come out not correct. The check belongs to the model
 file: through it the logistic-regression cells read what the harness read
@@ -30,7 +32,7 @@ sys.path.insert(0, str(HERE))
 sys.path.insert(0, str(HERE.parents[1] / "src"))
 
 from chipbench import cell, check, harness  # noqa: E402
-from chipbench.jobs import seed_key  # noqa: E402
+from chipbench.jobs import Jobs, seed_key  # noqa: E402
 
 SEED = 2**33 + 12345
 
@@ -115,8 +117,9 @@ def _cell(workload):
     return c._replace(chips=1)
 
 
-def _run(c, seconds=0.0):
-    return harness.execute(c, SEED, seconds, False, jax.devices()[:1], time.perf_counter())
+def _run(c, seconds=0.0, min_jobs=1):
+    return harness.execute(c, SEED, seconds, False, jax.devices()[:1], time.perf_counter(),
+                           min_jobs=min_jobs)
 
 
 @pytest.fixture
@@ -183,8 +186,9 @@ def _per_job(limits):
 
 
 def test_a_sound_run_is_correct():
-    # a few jobs, so the window's average sheds some Monte Carlo error
-    result = _run(_cell(LOGREG), seconds=8.0)
+    # two jobs, however fast the machine, so the window's average sheds some
+    # Monte Carlo error
+    result = _run(_cell(LOGREG), min_jobs=2)
     assert result["correct"], result["check"]
     assert result["attempted"] >= 2 and result["failed"] == 0
 
@@ -224,28 +228,33 @@ def test_the_control_is_not_correct_and_its_float32_witness_is(workload):
 # -- the model file's contract ------------------------------------------------
 
 
+def _kept_outputs(monkeypatch):
+    """The window's job outputs, kept as they end (the warm-up job's not)."""
+    outputs = []
+    run = Jobs.run
+
+    def kept(self, j):
+        out = run(self, j)
+        if j > 0:
+            outputs.append(out)
+        return out
+
+    monkeypatch.setattr(Jobs, "run", kept)
+    return outputs
+
+
 @pytest.mark.parametrize("fault", [None, "half_batch"])
 def test_the_contract_reads_what_the_draw_moments_read(fault, fresh_programs):
     """The harness's check through the model file equals the comparison the
     harness made itself before the check moved there: ``check.moments`` and
     ``check.readings`` on ``laplace``, for the same outputs, of a sound and
     of a broken sampler (at a size where the cell's limits need not hold)."""
-    from chipbench.jobs import Jobs
-
     if fault:
         FAULTS[fault][0](fresh_programs)
     c = _cell(LOGREG)
     cfg = dict(c.config, N=2000, M=2, T=300, warmup=50, burn_in=50)
     c = c._replace(config=cfg)
-    outputs = []
-    loop = Jobs.loop
-
-    def kept(self, *args, **kwargs):
-        out, seconds = loop(self, *args, **kwargs)
-        outputs.extend(out)
-        return out, seconds
-
-    fresh_programs.setattr(Jobs, "loop", kept)
+    outputs = _kept_outputs(fresh_programs)
     result = _run(c, seconds=0.5)
 
     data = c.model.make_data(jax.random.fold_in(seed_key(SEED), 0), cfg)
@@ -267,17 +276,19 @@ LINEAR_LIMITS = {"shard_z": 0.6, "shard_log_sd": 0.3, "product_z": 1.0,
                  "product_log_sd": 0.25, "shard_z_mean": 0.4}
 
 
-def _linear_cell(tmp_path, monkeypatch):
+def _linear_cell(tmp_path, monkeypatch, **changes):
     """A configuration of the program's linear_gaussian model that lives in
     this test alone: its model file (``testdata/linear_gaussian.py``, exact
-    conjugate reference, numbers of its own), traffic and limits."""
+    conjugate reference, numbers of its own), traffic and limits, beside the
+    benchmark's metric readers; ``changes`` go into the configuration."""
     for part in ("configs", "traffic", "limits"):
         (tmp_path / part).mkdir()
+    shutil.copytree(HERE / "metrics", tmp_path / "metrics")
     shutil.copy(HERE / "testdata" / "linear_gaussian.py", tmp_path / "configs")
     config = {"name": "linear", "model": "linear_gaussian",
               "model_file": "linear_gaussian.py", "N": 4000, "d": 10, "M": 4,
               "sampler": "mala", "warmup": 200, "T": 1000, "burn_in": 100,
-              "step_size": 0.1}
+              "step_size": 0.1, **changes}
     files = {
         "configs/linear.json": config,
         "traffic/batch.json": {"loop": "closed", "combiner": "parametric",
@@ -289,7 +300,8 @@ def _linear_cell(tmp_path, monkeypatch):
     bench = {"configs": [{"name": "linear", "file": str(tmp_path / "configs/linear.json")}],
              "workloads": [{"name": "linear.batch", "config": "linear",
                             "traffic": "batch", "chips": 1}],
-             "end_to_end": [], "per_layer": []}
+             "end_to_end": [{"name": "job_s", "unit": "s"}, {"name": "setup_s", "unit": "s"}],
+             "per_layer": []}
     monkeypatch.setattr(cell, "BENCH_DIR", tmp_path)
     return cell.find("linear.batch", bench)
 
@@ -304,3 +316,111 @@ def test_a_configuration_with_a_check_of_its_own(fault, tmp_path, fresh_programs
     assert set(result["check"]) == set(LINEAR_LIMITS)
     assert result["correct"] == (fault is None), result["check"]
     assert result["failed"] == (0 if fault is None else result["attempted"])
+
+
+# -- each job summarized as it ends -------------------------------------------
+
+
+def test_the_device_holds_one_job_at_a_time(tmp_path, fresh_programs):
+    """The live device arrays, and their bytes, are the same at every window
+    job's start: a job's output is dropped once it is summarized."""
+    c = _linear_cell(tmp_path, fresh_programs)
+    live = []
+    run = Jobs.run
+
+    def counted(self, j):
+        arrays = jax.live_arrays()
+        live.append((len(arrays), sum(a.nbytes for a in arrays)))
+        return run(self, j)
+
+    fresh_programs.setattr(Jobs, "run", counted)
+    result = _run(c, min_jobs=6)
+    assert result["attempted"] == 6 and len(live) == 7
+    assert len(set(live[1:])) == 1, live
+
+
+def test_summaries_stop_neither_clock(tmp_path, fresh_programs):
+    """A summary that takes 0.5 s moves neither ``job_s`` nor ``setup_s``."""
+    c = _linear_cell(tmp_path, fresh_programs, N=2000, T=200, warmup=50, burn_in=50)
+    starts = []
+    summarize = c.model.summarize
+
+    def slow(output, cfg):
+        starts.append(time.perf_counter())
+        time.sleep(0.5)
+        return summarize(output, cfg)
+
+    fresh_programs.setattr(c.model, "summarize", slow)
+    t_start = time.perf_counter()
+    result = harness.execute(c, SEED, 0.0, False, jax.devices()[:1], t_start, min_jobs=4)
+    metric = {k: v["value"] for k, v in result["metrics"].items()}
+    jobs = result["attempted"]
+    assert jobs == 4 and len(starts) == 5  # the warm-up job's summary first
+    assert metric["job_s"] * jobs < jobs * 0.5  # window_s < jobs x 0.5 s
+    assert t_start + metric["setup_s"] <= starts[0]
+
+
+def test_a_traced_run_summarizes_its_traced_jobs_after_the_profiler(
+        tmp_path, fresh_programs):
+    """The traced jobs are judged with the window's, and no summary runs
+    while the profiler records."""
+    c = _linear_cell(tmp_path, fresh_programs)
+    c = c._replace(per_layer=[{"name": "sample_s", "unit": "s"},
+                              {"name": "combine_s", "unit": "s"}])
+    profiling, calls = [False], []
+    for name, on in (("start_trace", True), ("stop_trace", False)):
+        real = getattr(jax.profiler, name)
+
+        def switch(*args, real=real, on=on, **kwargs):
+            real(*args, **kwargs)
+            profiling[0] = on
+
+        fresh_programs.setattr(jax.profiler, name, switch)
+    summarize = c.model.summarize
+
+    def watched(output, cfg):
+        calls.append(profiling[0])
+        return summarize(output, cfg)
+
+    fresh_programs.setattr(c.model, "summarize", watched)
+    result = harness.execute(c, SEED, 0.0, True, jax.devices()[:1], time.perf_counter())
+    assert result["correct"], result["check"]
+    assert result["attempted"] == 1 + c.traffic["trace_jobs"]
+    assert set(result["metrics"]) == {"sample_s", "combine_s"}
+    assert calls == [False] * (1 + result["attempted"])  # the warm-up job's too
+
+
+def test_summaries_made_as_jobs_end_read_as_after_the_window(tmp_path, fresh_programs):
+    """Each job summarized as it ends gives the ``check`` that the same jobs'
+    outputs, kept and summarized after the window, give."""
+    c = _linear_cell(tmp_path, fresh_programs)
+    outputs = _kept_outputs(fresh_programs)
+    result = _run(c, min_jobs=3)
+    m, cfg = c.model, c.config
+    ref = m.reference(m.make_data(jax.random.fold_in(seed_key(SEED), 0), cfg), cfg)
+    per_job, window = m.readings([m.summarize(o, cfg) for o in outputs], ref, cfg)
+    assert result["attempted"] == len(outputs) == len(per_job) == 3
+    assert result["check"] == {
+        k: {"value": window[k], "limit": float(v)} for k, v in c.limits.items()}
+
+
+def test_the_model_file_builds_the_model_the_jobs_sample(tmp_path, fresh_programs):
+    """A prior scale stated by the configuration reaches the sampled model
+    through the model file's ``bayes_model``: the draws follow it, and the
+    registered model, with its default prior, fails the same reference."""
+    from repro.models.bayes import get_model, linear_gaussian
+
+    c = _linear_cell(tmp_path, fresh_programs, prior_sigma=0.05)
+    key = seed_key(SEED)
+    data = c.model.make_data(jax.random.fold_in(key, 0), c.config)
+    theta = jnp.full((10,), 0.1)
+    sampled = Jobs(c, data, key).model
+    assert float(sampled.log_prior(theta)) == pytest.approx(
+        float(linear_gaussian.log_prior(theta, tau=0.05)))
+    result = _run(c)
+    assert result["correct"], result["check"]
+
+    fresh_programs.delattr(c.model, "bayes_model")
+    assert Jobs(c, data, key).model is get_model("linear_gaussian")
+    result = _run(c)
+    assert not result["correct"], result["check"]

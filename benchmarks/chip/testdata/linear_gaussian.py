@@ -1,11 +1,14 @@
 """Bayesian linear regression with known noise: a model file for the tests.
 
-y = Xβ + ε with ε ~ N(0, 1) and β ~ N(0, 3² I), as the program's
-``linear_gaussian`` model states it. Every subposterior, with the prior to
-the power 1/M, and the full posterior are Gaussian, so the reference is
-exact and in closed form (float64, on the host). It keeps to the contract
-of ``chipbench.harness`` and shares no code with the logistic-regression
-model file or ``chipbench.check``: its numbers are its own.
+y = Xβ + ε with ε ~ N(0, 1) and β ~ N(0, τ² I), as the program's
+``linear_gaussian`` model states it. τ is the configuration's
+``prior_sigma``, 3 (the program's default) where it states none:
+``bayes_model`` builds the sampled model with it. Every subposterior, with
+the prior to the power 1/M, and the full posterior are Gaussian, so the
+reference is exact and in closed form (float64, on the host). It keeps to
+the contract of ``chipbench.harness`` and shares no code with the
+logistic-regression model file or ``chipbench.check``: its numbers are its
+own.
 
 - ``shard_z``: the worst |draw mean − exact mean| / exact sd over the
   shards and coordinates of one job;
@@ -18,6 +21,8 @@ model file or ``chipbench.check``: its numbers are its own.
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 from typing import Dict, List
 
 import jax
@@ -33,6 +38,20 @@ NUMBERS = {
     "product_log_sd": {"layer": "combine", "scope": "job"},
     "shard_z_mean": {"layer": "sampling", "scope": "window"},
 }
+
+
+def _tau(cfg: Dict) -> float:
+    return float(cfg.get("prior_sigma", TAU))
+
+
+def bayes_model(cfg: Dict):
+    """The program's linear-Gaussian model under the configuration's prior."""
+    from repro.models.bayes import get_model, linear_gaussian
+
+    tau = _tau(cfg)
+    return dataclasses.replace(
+        get_model("linear_gaussian"), name=f"linear.prior_sigma={tau!r}",
+        log_prior=functools.partial(linear_gaussian.log_prior, tau=tau))
 
 
 def make_data(key: jax.Array, cfg: Dict) -> Dict[str, jax.Array]:
@@ -69,10 +88,10 @@ def reference(data: Dict[str, jax.Array], cfg: Dict) -> Dict[str, np.ndarray]:
     the full posterior (the configuration's N divides by its M)."""
     x = np.asarray(data["x"], np.float64)
     y = np.asarray(data["y"], np.float64)
-    m = int(cfg["M"])
-    subs = [_gaussian(xs, ys, 1.0 / (m * TAU**2))
+    m, tau = int(cfg["M"]), _tau(cfg)
+    subs = [_gaussian(xs, ys, 1.0 / (m * tau**2))
             for xs, ys in zip(np.split(x, m), np.split(y, m))]
-    full_mean, full_sd = _gaussian(x, y, 1.0 / TAU**2)
+    full_mean, full_sd = _gaussian(x, y, 1.0 / tau**2)
     return {"sub_mean": np.stack([s[0] for s in subs]),
             "sub_sd": np.stack([s[1] for s in subs]),
             "full_mean": full_mean, "full_sd": full_sd}
