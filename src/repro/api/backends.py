@@ -150,11 +150,12 @@ def _setup_one(sk: ShardKernel, shard, count, key, *, burn_in, warmup, step_size
     discipline exactly so chunked draws match the one-shot path bitwise."""
     k_init, k_run = jax.random.split(key)
     pos0 = sk.init_position(k_init, shard)
+    prepared = sk.prepare(shard)
     if sk.adaptive and warmup > 0:
         k_run, k_warm = jax.random.split(k_run)
         kernel, pos0, eps = warmup_chain(
             k_warm,
-            lambda e: sk.build(shard, count, e),
+            lambda e: sk.build(prepared, count, e),
             pos0,
             warmup,
             initial_step_size=step_size,
@@ -163,7 +164,7 @@ def _setup_one(sk: ShardKernel, shard, count, key, *, burn_in, warmup, step_size
         burn = burn_in
     else:
         eps = jnp.asarray(step_size, jnp.float32)
-        kernel = sk.build(shard, count, step_size)
+        kernel = sk.build(prepared, count, step_size)
         burn = burn_in + (0 if sk.adaptive else warmup)
     state = kernel.init(pos0)
     if burn > 0:
@@ -180,7 +181,7 @@ def _setup_one(sk: ShardKernel, shard, count, key, *, burn_in, warmup, step_size
 
 def _chunk_one(sk: ShardKernel, shard, count, eps, state, keys):
     """Advance one chain by ``len(keys)`` draws from a live kernel state."""
-    kernel = sk.build(shard, count, eps)
+    kernel = sk.build(sk.prepare(shard), count, eps)
 
     def collect(s, k):
         s, info = kernel.step(k, s)

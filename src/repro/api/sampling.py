@@ -50,12 +50,20 @@ class SampleResult(NamedTuple):
     collectives_checked: Optional[int]  # HLO collectives verified chain-local
 
 
+def _identity(shard):
+    return shard
+
+
 class ShardKernel(NamedTuple):
     """One (model, sampler) pairing, ready to instantiate per shard.
 
-    ``build(shard, count, step_size)`` must be pure and accept a traced
-    ``step_size`` — the resumable driver re-invokes it from a checkpointed
-    (possibly warmup-adapted) ε, and the matrix runner from a runtime scalar.
+    ``build(prepare(shard), count, step_size)`` must be pure and accept a
+    traced ``step_size`` — the resumable stream re-invokes it from a
+    checkpointed (possibly warmup-adapted) ε, and the matrix runner from a
+    runtime scalar. ``run_shard_chain`` and the chunk backends call
+    ``prepare`` once per program, before their chain scans: it makes
+    whatever the kernels read of the shard in a form of its own (the
+    model's ``shard_form``), so no scan step makes it.
     """
 
     init_position: Callable[[jax.Array, PyTree], PyTree]
@@ -63,6 +71,36 @@ class ShardKernel(NamedTuple):
     extract: Callable[[PyTree], jnp.ndarray]  # stacked positions -> (T, d) θ
     adaptive: bool  # eligible for dual-averaging warmup
     target_accept: float
+    prepare: Callable[[PyTree], PyTree] = _identity
+
+
+def shard_rows(model: BayesModel, shards: PyTree, axis: int = 0) -> int:
+    """Rows per shard: the per-datum leaves' ``axis`` (1 on stacked shards)."""
+    per_datum = (
+        shards if model.shard_keys is None
+        else {k: shards[k] for k in model.shard_keys}
+    )
+    return jax.tree.leaves(per_datum)[0].shape[axis]
+
+
+def loglik_fused(model: BayesModel, sampler: str, rows: int) -> bool:
+    """Whether a chain's every transition takes its gradient from the model's
+    fused likelihood pass: a gradient sampler, and a model whose
+    ``fused_rows`` holds for shards of ``rows`` rows (for logistic
+    regression: on a TPU, and at least ``MIN_KERNEL_N`` rows)."""
+    return bool(
+        sampler_spec(sampler).gradient
+        and model.fused_rows is not None
+        and model.fused_rows(rows)
+    )
+
+
+def count_steps(n: int, fused: bool) -> None:
+    """Count ``n`` sequential transitions of every chain into the open span:
+    ``steps``, and ``loglik_fused``, those whose gradient came from the fused
+    likelihood pass."""
+    count("steps", n)
+    count("loglik_fused", n if fused else 0)
 
 
 def _shard_axes(shards: PyTree, shard_keys, per_datum_leaf, broadcast_leaf):
@@ -131,7 +169,9 @@ def make_shard_kernel(
             target_accept=spec.target_accept,
         )
 
-    def make_logpdf(shard, count):
+    def make_logpdf(shard, count, form=None):
+        # no form, no keyword: the raw rows' call stays the one that a
+        # wrapper written to the older signature also serves
         return make_subposterior_logpdf(
             model.log_prior,
             model.log_lik,
@@ -139,6 +179,7 @@ def make_shard_kernel(
             num_shards,
             count=count if use_counts else None,
             per_datum=model.shard_keys,
+            **({} if form is None else {"form": form}),
         )
 
     if spec.name == "sgld":
@@ -186,9 +227,14 @@ def make_shard_kernel(
             target_accept=spec.target_accept,
         )
 
-    def build_mh(shard, count, step_size):
+    def prepare_mh(shard):
+        fused = loglik_fused(model, spec.name, shard_rows(model, shard))
+        return shard, (model.shard_form(shard) if fused else None)
+
+    def build_mh(prepared, count, step_size):
+        shard, form = prepared
         return spec.factory(
-            make_logpdf(shard, count), step_size=step_size, **extra
+            make_logpdf(shard, count, form), step_size=step_size, **extra
         )
 
     return ShardKernel(
@@ -197,6 +243,7 @@ def make_shard_kernel(
         extract=lambda pos: pos,
         adaptive=spec.adaptive,
         target_accept=spec.target_accept,
+        prepare=prepare_mh,
     )
 
 
@@ -220,10 +267,11 @@ def run_shard_chain(
     """
     k_init, k_run = jax.random.split(key)
     pos0 = sk.init_position(k_init, shard)
+    prepared = sk.prepare(shard)
     if sk.adaptive and warmup > 0:
         pos, info = run_chain(
             k_run,
-            lambda eps: sk.build(shard, count, eps),
+            lambda eps: sk.build(prepared, count, eps),
             pos0,
             num_samples,
             burn_in=burn_in,
@@ -232,7 +280,7 @@ def run_shard_chain(
             target_accept=sk.target_accept,
         )
     else:
-        kern = sk.build(shard, count, step_size)
+        kern = sk.build(prepared, count, step_size)
         pos, info = run_chain(
             k_run,
             kern,
@@ -316,7 +364,8 @@ def sample_subposteriors(
     ``mesh_shape``) and the compiled HLO is asserted collective-free across
     chains; otherwise the chains are vmapped on one device. Zero cross-chain
     communication either way. The call is the ``sample.stage`` span
-    (:mod:`repro.utils.spans`); its ``steps`` count each chain's transitions.
+    (:mod:`repro.utils.spans`); its ``steps`` count each chain's transitions,
+    its ``loglik_fused`` those whose gradient came from the fused pass.
     """
     sampler = sampler or model.default_sampler
     if shards is None or counts is None:
@@ -337,7 +386,10 @@ def sample_subposteriors(
         use_counts=padded,
         sampler_options=sampler_options,
     )
-    count("steps", warmup + burn_in + num_samples)
+    count_steps(
+        warmup + burn_in + num_samples,
+        loglik_fused(model, sampler, shard_rows(model, shards, axis=1)),
+    )
     keys = jax.random.split(key, num_shards)
     in_axes = (_shard_axes(shards, model.shard_keys, 0, None), 0, 0)
     vmapped = jax.vmap(one_shard, in_axes=in_axes)
@@ -361,11 +413,8 @@ def sample_subposteriors(
 
 def is_padded(model, shards, counts, sampler) -> bool:
     """Whether any shard carries replicated pad rows (and guard gibbs)."""
-    shard_rows = jax.tree.leaves(
-        shards if model.shard_keys is None
-        else {k: shards[k] for k in model.shard_keys}
-    )[0].shape[1]
-    padded = bool(jax.device_get(jnp.any(counts != shard_rows)))
+    rows = shard_rows(model, shards, axis=1)
+    padded = bool(jax.device_get(jnp.any(counts != rows)))
     if (
         padded
         and sampler_spec(sampler).name == "gibbs"

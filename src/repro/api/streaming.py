@@ -73,8 +73,15 @@ from repro.api.backends import (  # noqa: F401  (historical homes re-exported)
     _setup_one,
     get_chunk_backend,
 )
-from repro.api.sampling import SampleResult, ShardKernel, is_padded
-from repro.utils.spans import count, span
+from repro.api.sampling import (
+    SampleResult,
+    ShardKernel,
+    count_steps,
+    is_padded,
+    loglik_fused,
+    shard_rows,
+)
+from repro.utils.spans import span
 
 PyTree = Any
 
@@ -146,6 +153,10 @@ class ShardChainStream:
         self.num_shards = num_shards
         self.num_samples = num_samples
         sampler = sampler or model.default_sampler
+        # whether each transition's gradient comes from the fused pass
+        self.fused = loglik_fused(
+            model, sampler, shard_rows(model, shards, axis=1)
+        )
         self.backend = get_chunk_backend(
             model,
             num_shards,
@@ -269,7 +280,7 @@ class ShardChainStream:
             if t1 > stop:
                 break  # ragged chunk would shift later boundaries; stop here
             with span("sample.chunk"):
-                count("steps", t1 - t_done)
+                count_steps(t1 - t_done, self.fused)
                 state, theta_c, acc_c = self.chunk_fn(
                     self.shards,
                     self.counts,
@@ -367,7 +378,8 @@ def stream_sample(
 
     The call is the ``sample.stage`` span (:mod:`repro.utils.spans`), each
     chunk a ``sample.chunk``; their ``steps`` count every chain's
-    sequential transitions (warmup and burn-in included).
+    sequential transitions (warmup and burn-in included), their
+    ``loglik_fused`` those whose gradient came from the fused pass.
     """
     chunk = chunk_size if chunk_size > 0 else checkpoint_every
     if checkpoint_every > 0 and chunk_size > 0 and checkpoint_every % chunk_size:
@@ -421,7 +433,7 @@ def stream_sample(
         and max_steps is None
         and 0 < chunk < num_samples
     ):
-        count("steps", warmup + burn_in + num_samples)
+        count_steps(warmup + burn_in + num_samples, stream.fused)
         theta, accept_sum = stream.fused_sample(chunk)
         return StreamedSample(
             result=SampleResult(
@@ -488,7 +500,7 @@ def stream_sample(
                 for sub in on_chunk:
                     sub(ev)
     else:
-        count("steps", warmup + burn_in)  # the setup's transitions
+        count_steps(warmup + burn_in, stream.fused)  # the setup's transitions
         carry = stream.fresh_carry()
         t_done = 0
         resumed_from = 0

@@ -104,6 +104,7 @@ def make_subposterior_logpdf(
     *,
     count: jnp.ndarray | int | None = None,
     per_datum: tuple[str, ...] | None = None,
+    form: PyTree | None = None,
 ) -> LogDensityFn:
     """Build the shard-m subposterior log-density (paper Eq. 2.1).
 
@@ -118,13 +119,17 @@ def make_subposterior_logpdf(
     traced scalar — the correction is O(1), vmap/shard_map friendly.
     ``per_datum`` names the dict keys holding per-datum arrays (same meaning
     as ``partition_data``'s ``only``; ``None`` = every leaf).
+    ``form``, where given, is what ``log_lik`` reads for the whole shard (the
+    model's ``shard_form`` of ``data_shard``); the correction's final row is
+    still sliced from ``data_shard``'s raw rows.
     """
 
     inv_m = 1.0 / float(num_shards)
+    whole = data_shard if form is None else form
 
     if count is None:
         def logpdf(theta: PyTree) -> jnp.ndarray:
-            return inv_m * log_prior(theta) + log_lik(theta, data_shard)
+            return inv_m * log_prior(theta) + log_lik(theta, whole)
 
         return logpdf
 
@@ -139,7 +144,7 @@ def make_subposterior_logpdf(
     n_pad = jnp.asarray(shard_size, jnp.float32) - jnp.asarray(count, jnp.float32)
 
     def logpdf(theta: PyTree) -> jnp.ndarray:
-        full = log_lik(theta, data_shard)
+        full = log_lik(theta, whole)
         pad_ll = log_lik(theta, last_row)
         return inv_m * log_prior(theta) + full - n_pad * pad_ll
 

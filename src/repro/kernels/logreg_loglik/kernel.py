@@ -1,20 +1,23 @@
-"""Pallas TPU kernel: fused logistic log-likelihood + gradient.
+"""Pallas TPU kernel: the logistic log-likelihood and its gradient in one pass.
 
-TPU-native design (vs the CPU/Stan loop the paper ran):
+The likelihood reads each row only through ``u_i = s_i · x_i`` (s = 2y − 1):
 
-- grid = (N // block_n,): one sequential pass over row blocks. Each step
-  pulls a (block_n, d) tile of X into VMEM, does the matvec on the MXU
-  (block_n × d @ d × 1), the log-sigmoid on the VPU, and accumulates BOTH
-  the scalar ℓ and the d-vector ∇ℓ in f32 VMEM scratch — X is read ONCE
-  from HBM for value+grad (arithmetic intensity 2× the naive two-pass).
-- d stays resident (d ≤ ~8k fits VMEM alongside the row tile; the paper's
-  experiments are d ≤ 54 — sampling-regime posteriors are low-dim).
-- ``w`` is a {0,1} row mask so ops.py can pad N without biasing ℓ: a padded
-  row would otherwise add log σ(0) = −log 2.
+    ℓ(β)  = Σ_i log σ(βᵀu_i)        ∇ℓ(β) = Σ_i σ(−βᵀu_i) · u_i
 
-The matvec-as-matmul shape (block_n, d)·(d, 1) keeps the MXU utilized when
-callers batch multiple chains: beta may be (d, C) for C parallel chains
-(vmapped subposterior chains on one device), giving a true matmul.
+so the kernel takes the shard as one lane-dense array ``ut = (s ⊙ X)ᵀ`` of
+shape (d, N): rows on lanes, features on sublanes (d = 54 pads to 56
+sublanes, not to 128 lanes). A grid step DMAs a (d, block_n) block into VMEM;
+the body walks it one 128-lane tile at a time (``sub`` lanes a loop step)
+and, on the VPU in float32,
+
+- reduces over sublanes for v = βᵀu (one value a row),
+- forms log σ(v) and σ(−v),
+- accumulates σ(−v)·u and log σ(v) into (d, 128) and (1, 128) carries,
+
+so both reductions come from the one read of the block; the carries reduce
+across lanes once per block. The columns past N in the last block hold
+whatever the DMA left there; the kernel masks them, so the caller never pads
+``ut``.
 """
 
 from __future__ import annotations
@@ -26,72 +29,99 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128
 
-def _logreg_kernel(x_ref, y_ref, w_ref, beta_ref, loglik_ref, grad_ref, acc_l, acc_g, *, n_blocks: int):
+
+def _logreg_kernel(beta_ref, ut_ref, loglik_ref, grad_ref, *, n: int,
+                   block_n: int, sub: int):
     i = pl.program_id(0)
+    d = ut_ref.shape[0]
+    beta = jnp.broadcast_to(beta_ref[...], (d, LANES))  # hoisted
+
+    def chunk(start, carry, n_valid=None):
+        """``sub`` columns from ``start``, one 128-lane tile at a time; with
+        ``n_valid``, only that many count (the tail of the last block)."""
+        acc_l, acc_g = carry
+        for t in range(sub // LANES):
+            u = ut_ref[:, pl.ds(start + t * LANES, LANES)]  # (d, 128)
+            if n_valid is not None:  # garbage in, zero out
+                col = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+                valid = col + t * LANES < n_valid
+                u = jnp.where(valid, u, 0.0)
+            v = jnp.sum(u * beta, axis=0, keepdims=True)  # (1, 128) = βᵀu
+            ll = -jnp.logaddexp(0.0, -v)  # log σ(v), stably
+            if n_valid is not None:
+                ll = jnp.where(valid, ll, 0.0)
+            acc_l = acc_l + ll
+            acc_g = acc_g + u * jax.nn.sigmoid(-v)
+        return acc_l, acc_g
+
+    def block(n_cols: int):
+        """Accumulate the block's first ``n_cols`` columns (static)."""
+        full, tail = divmod(n_cols, sub)
+        carry = (jnp.zeros((1, LANES), jnp.float32),
+                 jnp.zeros((d, LANES), jnp.float32))
+        carry = jax.lax.fori_loop(
+            0, full,
+            lambda j, c: chunk(pl.multiple_of(j * sub, sub), c),
+            carry,
+        )
+        if tail:
+            carry = chunk(full * sub, carry, n_valid=tail)
+        acc_l, acc_g = carry
+        return (jnp.sum(acc_l, axis=1, keepdims=True),
+                jnp.sum(acc_g, axis=1, keepdims=True))
 
     @pl.when(i == 0)
     def _init():
-        acc_l[...] = jnp.zeros_like(acc_l)
-        acc_g[...] = jnp.zeros_like(acc_g)
+        loglik_ref[...] = jnp.zeros_like(loglik_ref)
+        grad_ref[...] = jnp.zeros_like(grad_ref)
 
-    x = x_ref[...].astype(jnp.float32)  # (block_n, d)
-    y = y_ref[...].astype(jnp.float32)  # (block_n, C)
-    w = w_ref[...].astype(jnp.float32)  # (block_n, 1)
-    beta = beta_ref[...].astype(jnp.float32)  # (d, C)
+    def add(n_cols):
+        l, g = block(n_cols)
+        loglik_ref[...] += l
+        grad_ref[...] += g
 
-    z = y * jax.lax.dot(x, beta, precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)  # (block_n, C)
-    # log σ(z) = −softplus(−z), computed stably on the VPU
-    loglik = -jnp.sum(w * jnp.logaddexp(0.0, -z), axis=0)  # (C,)
-    coeff = w * y * jax.nn.sigmoid(-z)  # (block_n, C)
-    grad = jax.lax.dot(x.T, coeff, precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)  # (d, C)
-
-    acc_l[...] += loglik
-    acc_g[...] += grad
-
-    @pl.when(i == n_blocks - 1)
-    def _finalize():
-        loglik_ref[...] = acc_l[...]
-        grad_ref[...] = acc_g[...]
+    n_blocks = -(-n // block_n)
+    last = n - (n_blocks - 1) * block_n  # columns of the last block
+    if n_blocks == 1 or last == block_n:  # one shape of block
+        add(last)
+    else:
+        pl.when(i < n_blocks - 1)(lambda: add(block_n))
+        pl.when(i == n_blocks - 1)(lambda: add(last))
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_n", "sub", "interpret"))
 def logreg_loglik_grad_kernel(
-    X: jnp.ndarray,  # (N, d) padded: N % block_n == 0
-    y: jnp.ndarray,  # (N, C)
-    w: jnp.ndarray,  # (N, 1) row mask
-    beta: jnp.ndarray,  # (d, C)
+    ut: jnp.ndarray,  # (d, N) float32: (s ⊙ X)ᵀ
+    beta: jnp.ndarray,  # (d, 1) float32
     *,
-    block_n: int = 1024,
+    block_n: int,
+    sub: int,
     interpret: bool = False,
 ):
-    N, d = X.shape
-    C = beta.shape[1]
-    n_blocks = N // block_n
-    kernel = functools.partial(_logreg_kernel, n_blocks=n_blocks)
+    """``(ℓ (1, 1), ∇ℓ (d, 1))``; ``block_n`` a multiple of ``sub``, and
+    ``sub`` of 128. Under ``vmap`` Pallas adds the batch as a parallel
+    leading grid axis."""
+    d, n = ut.shape
+    kernel = functools.partial(_logreg_kernel, n=n, block_n=block_n, sub=sub)
     return pl.pallas_call(
         kernel,
-        grid=(n_blocks,),
+        grid=(pl.cdiv(n, block_n),),
         in_specs=[
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_n, C), lambda i: (i, 0)),
-            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
-            pl.BlockSpec((d, C), lambda i: (0, 0)),  # beta resident
+            pl.BlockSpec((d, 1), lambda i: (0, 0)),  # β resident
+            pl.BlockSpec((d, block_n), lambda i: (0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((C,), lambda i: (0,)),
-            pl.BlockSpec((d, C), lambda i: (0, 0)),
+            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec((d, 1), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((C,), jnp.float32),
-            jax.ShapeDtypeStruct((d, C), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((C,), jnp.float32),
-            pltpu.VMEM((d, C), jnp.float32),
+            jax.ShapeDtypeStruct((1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((d, 1), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(X, y, w, beta)
+    )(beta, ut)

@@ -1,61 +1,62 @@
-"""jit'd wrapper: padding, masking, single-chain and multi-chain entry points."""
+"""Public entry points: the lane-dense layout, the fused op, and when to use it."""
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import default_interpret
 from repro.kernels.logreg_loglik.kernel import logreg_loglik_grad_kernel
-from repro.kernels.logreg_loglik.ref import logreg_loglik_grad_ref
+
+# Fewer rows than this stay on the jnp form: the one-row padding correction,
+# SGLD's 256-row minibatches, anything XLA reads in a few microseconds.
+MIN_KERNEL_N = 1024
+SUB = 512  # lanes the kernel body takes at a time (a multiple of 128)
+BLOCK_BYTES = 4 << 20  # one (d, block_n) float32 block of ut, at most
 
 
-def _round_up(n: int, k: int) -> int:
-    return (n + k - 1) // k * k
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret", "min_kernel_n"))
+def use_fused(n_rows: int) -> bool:
+    """Whether a likelihood over ``n_rows`` rows takes the fused kernel: on a
+    TPU, for at least :data:`MIN_KERNEL_N` rows."""
+    return on_tpu() and n_rows >= MIN_KERNEL_N
+
+
+def lane_dense(x: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:
+    """The kernel's layout of a shard: ``(s ⊙ X)ᵀ``, (d, N) float32, from
+    ``x`` (N, d) and signs ``s`` (N,) = 2y − 1. The product is exact."""
+    return (x.astype(jnp.float32) * s.astype(jnp.float32)[:, None]).T
+
+
+def _block_n(n: int, d: int) -> int:
+    """Lanes a grid step reads: the whole shard when it fits
+    :data:`BLOCK_BYTES` (rounded up to :data:`SUB`), else the most that do."""
+    d_pad = -(-d // 8) * 8
+    most = max(SUB, BLOCK_BYTES // (4 * d_pad) // SUB * SUB)
+    return min(-(-n // SUB) * SUB, most)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def logreg_loglik_grad(
-    X: jnp.ndarray,  # (N, d)
-    y: jnp.ndarray,  # (N,) in {-1, +1}
-    beta: jnp.ndarray,  # (d,) or (d, C) for C chains
+    ut: jnp.ndarray,  # (d, N): lane_dense(X, s)
+    beta: jnp.ndarray,  # (d,)
     *,
-    scale: float | jnp.ndarray = 1.0,
-    block_n: int = 1024,
-    interpret: bool | None = None,  # None -> repro.kernels.default_interpret()
-    min_kernel_n: int = 256,
+    interpret: Optional[bool] = None,  # None -> repro.kernels.default_interpret()
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Fused (ℓ, ∇ℓ) of the logistic likelihood; matches ``ref.py`` exactly.
-
-    Returns ((), (d,)) for 1-D beta and ((C,), (d, C)) for 2-D beta.
-    """
+    """Fused ``(ℓ (), ∇ℓ (d,))`` of the logistic likelihood in one pass over
+    ``ut``; ``ref.py`` on the row layout is its reference. Chains batch by
+    ``vmap``."""
     if interpret is None:
         interpret = default_interpret()
-    N, d = X.shape
-    single = beta.ndim == 1
-    if N < min_kernel_n:
-        if single:
-            l, g = logreg_loglik_grad_ref(X, y, beta, scale=scale)
-            return l, g
-        ls, gs = jax.vmap(
-            lambda b: logreg_loglik_grad_ref(X, y, b, scale=scale), in_axes=1, out_axes=0
-        )(beta)
-        return ls, gs.T
-
-    beta2 = beta[:, None] if single else beta
-    block_n = min(block_n, _round_up(N, 8))
-    Np = _round_up(N, block_n)
-    Xp = jnp.zeros((Np, d), X.dtype).at[:N].set(X)
-    yp = jnp.ones((Np, beta2.shape[1]), jnp.float32)
-    yp = yp.at[:N].set(y.astype(jnp.float32)[:, None])
-    w = jnp.zeros((Np, 1), jnp.float32).at[:N].set(1.0)
+    d, n = ut.shape
     loglik, grad = logreg_loglik_grad_kernel(
-        Xp, yp, w, beta2, block_n=block_n, interpret=interpret
+        ut, beta.astype(jnp.float32)[:, None],
+        block_n=_block_n(n, d), sub=SUB, interpret=interpret,
     )
-    s = jnp.asarray(scale, jnp.float32)
-    if single:
-        return s * loglik[0], s * grad[:, 0]
-    return s * loglik, s * grad
+    return loglik[0, 0], grad[:, 0]

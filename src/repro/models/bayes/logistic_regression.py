@@ -17,6 +17,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.logreg_loglik import lane_dense, logreg_loglik_grad, use_fused
 from repro.models.bayes import registry
 
 Data = Dict[str, jnp.ndarray]
@@ -56,15 +57,45 @@ def log_prior(theta: jnp.ndarray, sigma: float = 5.0) -> jnp.ndarray:
     )
 
 
+def _log_lik_jnp(theta: jnp.ndarray, x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
+    s = 2.0 * y - 1.0
+    return jnp.sum(jax.nn.log_sigmoid(s * (x @ theta)))
+
+
+@jax.custom_vjp
+def _log_lik_fused(theta, x, y, ut):
+    return _log_lik_jnp(theta, x, y)
+
+
+def _fused_fwd(theta, x, y, ut):
+    return logreg_loglik_grad(ut, theta)  # ℓ, and ∇ℓ kept for the backward
+
+
+def _fused_bwd(grad, ct):
+    return ct * grad, None, None, None  # the data take no cotangent
+
+
+_log_lik_fused.defvjp(_fused_fwd, _fused_bwd)
+
+
+def shard_form(shard: Data) -> Data:
+    """The shard with its lane-dense copy ``ut = (s ⊙ X)ᵀ`` for the fused
+    kernel (``BayesModel.shard_form``)."""
+    return {**shard, "ut": lane_dense(shard["x"], 2.0 * shard["y"] - 1.0)}
+
+
 def log_lik(theta: jnp.ndarray, data: Data) -> jnp.ndarray:
     """Σ_i log p(y_i | x_i, β) = Σ_i log σ(s_i · x_i β) with s_i = 2y_i − 1.
 
-    The fused Pallas version (matvec + log-sigmoid reduce, never materializing
-    logits in HBM) is ``repro.kernels.logreg_loglik`` — this jnp form is its
-    reference oracle and the CPU path.
+    The value is always this jnp form, the CPU path and the reference. On a
+    :func:`shard_form` (made where ``repro.kernels.logreg_loglik.use_fused``
+    holds: a TPU, a shard of at least ``MIN_KERNEL_N`` rows) the gradient
+    comes with the value from one pass of the fused Pallas kernel over the
+    lane-dense copy: ``value_and_grad`` (MALA, HMC) reads X once, not twice.
     """
-    s = 2.0 * data["y"] - 1.0
-    return jnp.sum(jax.nn.log_sigmoid(s * (data["x"] @ theta)))
+    if "ut" in data:
+        return _log_lik_fused(theta, data["x"], data["y"], data["ut"])
+    return _log_lik_jnp(theta, data["x"], data["y"])
 
 
 def predictive_accuracy(
@@ -95,6 +126,8 @@ registry.register_model(
         d=50,
         default_n=50_000,
         default_sampler="mala",
+        fused_rows=use_fused,
+        shard_form=shard_form,
     ),
     "logistic_regression",
 )
@@ -108,5 +141,7 @@ registry.register_model(
         d=54,
         default_n=581_012,
         default_sampler="mala",
+        fused_rows=use_fused,
+        shard_form=shard_form,
     )
 )

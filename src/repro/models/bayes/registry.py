@@ -24,6 +24,12 @@ partition → sample → combine → score — without per-model branching:
   ``count=`` (the edge-pad valid-prefix convention) and masks the padded
   replicated rows out of its conditionals — such models run ``--sampler
   gibbs`` on non-divisible N; models without it keep requiring divisible N.
+- optional fused likelihood pass: where ``fused_rows(n)`` holds for a shard
+  of n rows, a gradient sampler's program makes ``shard_form(shard)`` once,
+  before its chain scans, and hands it to ``log_lik`` for the whole-shard
+  term; ``log_lik`` then gives its value and gradient from one pass over
+  that form. ``log_lik`` still reads raw rows too (the padding correction's
+  last row, SGLD's minibatches).
 
 Models self-register at import time via :func:`register_model` (importing
 :mod:`repro.models.bayes` populates the registry); consumers resolve them by
@@ -60,6 +66,8 @@ class BayesModel:
     gibbs_init: Optional[Callable[[jax.Array, Data], PyTree]] = None
     gibbs_extract: Optional[Callable[[PyTree], jnp.ndarray]] = None
     gibbs_counts: bool = False  # gibbs_blocks masks padded rows via count=
+    fused_rows: Optional[Callable[[int], bool]] = None
+    shard_form: Optional[Callable[[Data], Data]] = None
 
     def initial_position(self, key: jax.Array, data_shard: Data) -> jnp.ndarray:
         """θ0 for one chain: model-provided init or jittered origin."""

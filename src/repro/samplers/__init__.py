@@ -19,7 +19,7 @@ reachable from every consumer at once.
 
 Warmup convention
 -----------------
-Registered samplers carry a ``SamplerSpec(adaptive, target_accept)``.
+Registered samplers carry a ``SamplerSpec(adaptive, target_accept, gradient)``.
 Adaptive kernels are warmed up by dual averaging: pass a *factory*
 ``step_size -> MCMCKernel`` plus ``warmup=n`` to ``run_chain`` and the step
 size adapts toward ``target_accept`` per chain under ``lax.scan`` (vmap- and

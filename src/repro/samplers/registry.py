@@ -20,6 +20,9 @@ Each registration carries metadata in a :class:`SamplerSpec`:
   treat warmup steps as extra burn-in.
 - ``target_accept``: the warmup's target acceptance rate (sampler-specific
   optima: ~0.35 for random-walk MH, ~0.55 for MALA, 0.8 for HMC).
+- ``gradient``: whether every transition takes the log-density's value and
+  gradient together (MALA, HMC), so a model's fused likelihood pass
+  (``BayesModel.fused_rows``) can serve it.
 
 Option-forwarding follows the combiners' convention: callers broadcasting one
 option dict over many samplers filter it per factory signature with
@@ -58,6 +61,7 @@ class SamplerSpec(NamedTuple):
     factory: SamplerFactory
     adaptive: bool
     target_accept: float
+    gradient: bool
 
 
 _REGISTRY: Dict[str, SamplerSpec] = {}
@@ -69,12 +73,14 @@ def register_sampler(
     *aliases: str,
     adaptive: bool = True,
     target_accept: float = 0.8,
+    gradient: bool = False,
 ) -> Callable[[SamplerFactory], SamplerFactory]:
     """Decorator: add a sampler factory to the registry under ``name``."""
 
     def deco(fn: SamplerFactory) -> SamplerFactory:
         spec = SamplerSpec(
-            name=name, factory=fn, adaptive=adaptive, target_accept=target_accept
+            name=name, factory=fn, adaptive=adaptive,
+            target_accept=target_accept, gradient=gradient,
         )
         for key in (name, *aliases):
             if key in _REGISTRY:
@@ -139,7 +145,7 @@ def rwmh(
     return rwmh_kernel(logpdf, step_size=step_size, proposal_fn=proposal_fn)
 
 
-@register_sampler("mala", target_accept=0.55)
+@register_sampler("mala", target_accept=0.55, gradient=True)
 def mala(
     logpdf: LogDensityFn, *, step_size: float | jnp.ndarray = 0.05, **_ignored
 ) -> MCMCKernel:
@@ -147,7 +153,7 @@ def mala(
     return mala_kernel(logpdf, step_size=step_size)
 
 
-@register_sampler("hmc", target_accept=0.8)
+@register_sampler("hmc", target_accept=0.8, gradient=True)
 def hmc(
     logpdf: LogDensityFn,
     *,

@@ -7,7 +7,7 @@ import pytest
 
 from repro.kernels.img_weights import img_log_weights, img_log_weights_ref
 from repro.kernels.kde_density import kde_log_density, kde_log_density_ref
-from repro.kernels.logreg_loglik import logreg_loglik_grad, logreg_loglik_grad_ref
+from repro.kernels.logreg_loglik import lane_dense, logreg_loglik_grad, logreg_loglik_grad_ref
 
 
 @pytest.mark.parametrize("P,M,d", [(300, 10, 50), (256, 4, 512), (100, 20, 7), (64, 2, 1), (65, 3, 130)])
@@ -41,8 +41,8 @@ def test_logreg_kernel_matches_ref(N, d, dtype):
     X = jax.random.normal(kx, (N, d), dtype)
     beta = (jax.random.normal(kb, (d,)) * 0.3).astype(dtype)
     y = jnp.where(jax.random.uniform(ky, (N,)) < 0.5, 1.0, -1.0)
-    l, g = logreg_loglik_grad(X, y, beta, scale=1.7)
-    lr, gr = logreg_loglik_grad_ref(X, y, beta, scale=1.7)
+    l, g = logreg_loglik_grad(lane_dense(X, y), beta)
+    lr, gr = logreg_loglik_grad_ref(X, y, beta)
     rtol = 1e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(l, lr, rtol=rtol)
     np.testing.assert_allclose(g, gr, rtol=max(rtol, 1e-4), atol=0.3 if dtype == jnp.bfloat16 else 1e-3)
@@ -53,11 +53,12 @@ def test_logreg_kernel_multichain():
     X = jax.random.normal(k, (2048, 20))
     y = jnp.where(jax.random.uniform(jax.random.fold_in(k, 1), (2048,)) < 0.5, 1.0, -1.0)
     B = jax.random.normal(jax.random.fold_in(k, 2), (20, 5)) * 0.2
-    ls, gs = logreg_loglik_grad(X, y, B)
+    ut = lane_dense(X, y)
+    ls, gs = jax.vmap(lambda b: logreg_loglik_grad(ut, b))(B.T)
     for c in range(5):
         lc, gc = logreg_loglik_grad_ref(X, y, B[:, c])
         np.testing.assert_allclose(ls[c], lc, rtol=1e-5)
-        np.testing.assert_allclose(gs[:, c], gc, rtol=1e-4, atol=1e-3)
+        np.testing.assert_allclose(gs[c], gc, rtol=1e-4, atol=1e-3)
 
 
 def test_logreg_kernel_grad_is_true_gradient():
@@ -66,7 +67,7 @@ def test_logreg_kernel_grad_is_true_gradient():
     X = jax.random.normal(k, (512, 9))
     y = jnp.where(jax.random.uniform(jax.random.fold_in(k, 1), (512,)) < 0.5, 1.0, -1.0)
     beta = jax.random.normal(jax.random.fold_in(k, 2), (9,)) * 0.5
-    _, g = logreg_loglik_grad(X, y, beta)
+    _, g = logreg_loglik_grad(lane_dense(X, y), beta)
     g_ad = jax.grad(lambda b: logreg_loglik_grad_ref(X, y, b)[0])(beta)
     np.testing.assert_allclose(g, g_ad, rtol=1e-4, atol=1e-4)
 

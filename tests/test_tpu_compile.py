@@ -78,7 +78,17 @@ def _cases():
     )
     cases["logreg_loglik"] = (
         functools.partial(logreg_loglik_grad, interpret=False),
-        [((N_SHARD, D), f32), ((N_SHARD,), f32), ((D,), f32)],
+        [((D, N_SHARD), f32), ((D,), f32)],
+    )
+    # covtype: 48 chains over 12,105-row shards of d=54, and the full-data
+    # chain's 581,012 rows, which take many blocks
+    cases["logreg_loglik_chains"] = (
+        jax.vmap(functools.partial(logreg_loglik_grad, interpret=False)),
+        [((48, 54, 12105), f32), ((48, 54), f32)],
+    )
+    cases["logreg_loglik_full_data"] = (
+        functools.partial(logreg_loglik_grad, interpret=False),
+        [((54, 581012), f32), ((54,), f32)],
     )
     return cases
 
