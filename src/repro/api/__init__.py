@@ -16,8 +16,7 @@ model × sampler × combiner × mesh scenario a *value* instead of a script:
   distinct signature (seeds/step sizes are runtime inputs) and emits a tidy
   results table (stdout + JSON);
 - :func:`combine_draws` — registry-dispatched combination for callers that
-  already hold an ``(M, T, d)`` stack (backed by
-  ``repro.distributed.epmcmc.combine_gathered``, same as the mesh run).
+  already hold an ``(M, T, d)`` stack (the combine stage's own dispatch).
 
 Quickstart::
 
@@ -48,10 +47,6 @@ from repro.api.pipeline import (  # noqa: F401
     SubposteriorDraws,
     combine_draws,
 )
-from repro.api.resumable import (  # noqa: F401
-    ResumableSample,
-    sample_subposteriors_resumable,
-)
 from repro.api.streaming import (  # noqa: F401
     ShardChainStream,
     StreamChunk,
@@ -63,8 +58,6 @@ from repro.api.sampling import (  # noqa: F401
     ShardKernel,
     groundtruth_chain,
     make_shard_kernel,
-    make_shard_sampler,
-    run_shard_chain,
     sample_subposteriors,
 )
 from repro.api.spec import RunSpec  # noqa: F401
@@ -89,7 +82,6 @@ __all__ = [
     "Pipeline",
     "VmapChunkBackend",
     "get_chunk_backend",
-    "ResumableSample",
     "RunSpec",
     "SampleResult",
     "Scoreboard",
@@ -104,10 +96,7 @@ __all__ = [
     "combine_draws",
     "groundtruth_chain",
     "make_shard_kernel",
-    "make_shard_sampler",
     "run_matrix",
-    "run_shard_chain",
     "sample_subposteriors",
-    "sample_subposteriors_resumable",
     "stream_sample",
 ]

@@ -1,36 +1,34 @@
-"""Pluggable chunk-emitting execution backends for the sampling stage.
+"""Chunk-emitting execution backends: where the M chains of the sampling
+stage run.
 
-Before this module the sampling stage had three forked paths — the chunked
-vmap driver, its fused whole-run variant, and a one-shot ``shard_map`` mesh
-program — and the forks leaked upward: ``Pipeline.sample`` raised on any
-spec that asked for both a mesh and a stream. The fork is now a *backend*:
-one :class:`ChunkBackend` contract (jitted ``setup`` + ``next_chunk`` chunk
-programs, a ``fused_program`` runner, a backend-id constructor, an HLO
-assert hook) with two implementations —
+Every way of sampling M chains goes through one :class:`ChunkBackend`
+contract (jitted ``setup`` + ``next_chunk`` chunk programs, a
+``fused_program`` runner, a backend-id constructor, an HLO assert hook),
+built from the one-chain pair of :mod:`repro.api.sampling`
+(``_setup_one``, ``_chunk_one``) and driven by :mod:`repro.api.streaming`.
+Two implementations:
 
-- :class:`VmapChunkBackend` — M chains vmapped on one device, the classic
-  driver behind ``"vmap[chunked]"`` / ``"vmap[fused]"`` / ``"vmap[resumable]"``;
+- :class:`VmapChunkBackend` — M chains vmapped on one device, behind
+  ``"vmap[chunked]"`` / ``"vmap[fused]"`` / ``"vmap[resumable]"``;
 - :class:`MeshChunkBackend` — the *same* vmapped per-chain programs wrapped
   in ``shard_map`` over the ``data`` axis of a ``(ndata, nmodel)`` mesh, so
-  every chunk is a compiled SPMD program whose post-SPMD HLO is asserted
-  collective-free across chains (lazily, once per chunk shape) exactly like
-  the historical one-shot path. Chunks land as dense ``(M, C, d)`` device
-  slices — the same streaming-gather layout
-  :func:`repro.distributed.epmcmc.gather_subset_samples` produces with
-  ``chunk=`` — so every chunk subscriber (checkpointing, streaming
-  combiners, :func:`repro.api.streaming.fused_fold`) drives either backend
-  unchanged.
+  every program is a compiled SPMD program whose post-SPMD HLO is asserted
+  collective-free across chains (lazily, once per program and input
+  shape). Chunks land as dense ``(M, C, d)`` device slices, so every chunk
+  subscriber (checkpointing, streaming combiners,
+  :func:`repro.api.streaming.fused_fold`) drives either backend unchanged.
+
+:func:`auto_mesh_shape` is the one rule for which backend a stage runs on
+when its caller names no mesh.
 
 :class:`BackendId` is the one constructor for ``Scoreboard.backend``
-strings; call sites must not assemble them ad hoc. The historical strings
-are preserved exactly (``"vmap"``, ``"vmap[chunked]"``,
-``"shard_map(4 devices)"``, …); mesh streaming adds the bracketed variants
-(``"shard_map[chunked](4 devices)"``) and the multi-controller launch path
+strings; call sites must not assemble them ad hoc (``"vmap[chunked]"``,
+``"shard_map[chunked](4 devices)"``, …); the multi-controller launch path
 (:mod:`repro.api.launch`) adds ``"jax.distributed(2 processes)"``.
 
 Backends are cached per compile-relevant statics (the run_matrix compile-
 hygiene convention): a serving loop instantiating one stream per request
-re-traces nothing, and the HLO assert runs once per (program, chunk shape)
+re-traces nothing, and the HLO assert runs once per (program, input shape)
 per process.
 """
 
@@ -42,9 +40,17 @@ from typing import Any, Dict, Optional, Protocol, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.api.sampling import ShardKernel, _shard_axes, chain_mesh, make_shard_kernel
+from repro.api.hlo import assert_no_cross_chain_collectives
+from repro.api.sampling import (
+    ShardKernel,
+    _chunk_one,
+    _freeze_options,
+    _setup_one,
+    _shard_axes,
+    chain_mesh,
+    make_shard_kernel,
+)
 from repro.models.bayes import BayesModel
-from repro.samplers.adaptation import warmup_chain
 
 PyTree = Any
 
@@ -60,7 +66,7 @@ class BackendId:
 
     ``Scoreboard.backend`` / ``SampleResult.backend`` values are assembled
     here and nowhere else — tests assert call sites against these exact
-    spellings, so the historical strings are load-bearing.
+    spellings, so they are load-bearing.
     """
 
     @staticmethod
@@ -73,18 +79,18 @@ class BackendId:
 
     @staticmethod
     def vmap(mode: Optional[str] = None) -> str:
-        """``"vmap"`` or ``"vmap[chunked|fused|resumable]"``."""
+        """``"vmap"`` (the matrix runner) or ``"vmap[chunked|fused|resumable]"``."""
         BackendId._check_mode(mode)
         return "vmap" if mode is None else f"vmap[{mode}]"
 
     @staticmethod
-    def mesh(ndata: int, mode: Optional[str] = None) -> str:
-        """``"shard_map(<ndata> devices)"`` (one-shot) or the bracketed
-        chunk-streaming variants; ``ndata`` is the mesh data-axis size —
-        the number of chain groups, the historical spelling."""
+    def mesh(ndata: int, mode: str) -> str:
+        """``"shard_map[chunked|fused|resumable](<ndata> devices)"``;
+        ``ndata`` is the mesh data-axis size — the number of chain groups."""
+        if mode is None:
+            raise ValueError("a mesh stage reports its mode")
         BackendId._check_mode(mode)
-        tag = "" if mode is None else f"[{mode}]"
-        return f"shard_map{tag}({int(ndata)} devices)"
+        return f"shard_map[{mode}]({int(ndata)} devices)"
 
     @staticmethod
     def mesh_fanout(ndev: int) -> str:
@@ -119,7 +125,9 @@ class ChunkBackend(Protocol):
         """This backend's :class:`BackendId` string for ``mode``."""
 
     def setup(self, shards, counts, keys):
-        """Jitted init + warmup + burn-in → ``(state, eps, k_collect)``."""
+        """Jitted init + warmup + burn-in → ``(state, eps, k_collect)``;
+        must be callable under an outer trace (the fused program calls it).
+        Concrete calls run the backend's HLO-assert hook lazily."""
 
     def next_chunk(self, shards, counts, eps, state, keys):
         """Jitted chunk program → ``(state, theta (M, C, d), accept (M,))``;
@@ -143,57 +151,6 @@ class ChunkBackend(Protocol):
         """Execute a fused whole-run program (jitted ``run(shards, counts,
         keys) -> (theta, accept_sum)``), applying the backend's compilation
         strategy and HLO assert; cached per ``prog_key``."""
-
-
-def _setup_one(sk: ShardKernel, shard, count, key, *, burn_in, warmup, step_size):
-    """Warmup + burn-in for one shard; mirrors ``run_shard_chain``'s RNG
-    discipline exactly so chunked draws match the one-shot path bitwise."""
-    k_init, k_run = jax.random.split(key)
-    pos0 = sk.init_position(k_init, shard)
-    prepared = sk.prepare(shard)
-    if sk.adaptive and warmup > 0:
-        k_run, k_warm = jax.random.split(k_run)
-        kernel, pos0, eps = warmup_chain(
-            k_warm,
-            lambda e: sk.build(prepared, count, e),
-            pos0,
-            warmup,
-            initial_step_size=step_size,
-            target_accept=sk.target_accept,
-        )
-        burn = burn_in
-    else:
-        eps = jnp.asarray(step_size, jnp.float32)
-        kernel = sk.build(prepared, count, step_size)
-        burn = burn_in + (0 if sk.adaptive else warmup)
-    state = kernel.init(pos0)
-    if burn > 0:
-        keys = jax.random.split(k_run, burn + 1)
-        k_run = keys[0]
-
-        def warm(s, k):
-            s, _ = kernel.step(k, s)
-            return s, None
-
-        state, _ = jax.lax.scan(warm, state, keys[1:])
-    return state, eps, k_run
-
-
-def _chunk_one(sk: ShardKernel, shard, count, eps, state, keys):
-    """Advance one chain by ``len(keys)`` draws from a live kernel state."""
-    kernel = sk.build(sk.prepare(shard), count, eps)
-
-    def collect(s, k):
-        s, info = kernel.step(k, s)
-        return s, (s.position, info.is_accepted)
-
-    state, (pos, acc) = jax.lax.scan(collect, state, keys)
-    return state, sk.extract(pos), acc.astype(jnp.float32).sum()
-
-
-def _freeze_options(options) -> Tuple:
-    items = options.items() if hasattr(options, "items") else options
-    return tuple(sorted((str(k), v) for k, v in items))
 
 
 def _is_traced(*trees) -> bool:
@@ -266,11 +223,12 @@ class MeshChunkBackend:
 
     Each device owns ``M/ndata`` chains + their data shards (broadcast
     leaves replicated). Every compiled program this backend runs — the
-    chunk program (lazily, once per chunk shape) and the fused whole-run
-    program — has its post-SPMD HLO asserted collective-free across chain
-    groups via :func:`repro.distributed.epmcmc.assert_no_cross_chain_collectives`,
-    the machine-checked "embarrassingly parallel" property the one-shot
-    path established. ``collectives_checked`` accumulates across programs.
+    setup program (init, warmup and burn-in) and the chunk program, each
+    lazily once per input shape, and the fused whole-run program — has its
+    post-SPMD HLO asserted collective-free across chain groups via
+    :func:`repro.api.hlo.assert_no_cross_chain_collectives`: the
+    machine-checked "embarrassingly parallel" property.
+    ``collectives_checked`` accumulates across programs.
     """
 
     kind = "mesh"
@@ -309,7 +267,7 @@ class MeshChunkBackend:
         chunk_v = jax.vmap(
             functools.partial(_chunk_one, sk), in_axes=(axes, 0, 0, 0, 0)
         )
-        self.setup = jax.jit(
+        self._setup = jax.jit(
             jax.shard_map(
                 setup_v,
                 mesh=self.mesh,
@@ -339,26 +297,28 @@ class MeshChunkBackend:
         return BackendId.mesh(self.mesh_shape[0], mode)
 
     def _assert_hlo(self, hlo_text: str) -> None:
-        # late import: epmcmc pulls the heavy LM stack
-        from repro.distributed.epmcmc import assert_no_cross_chain_collectives
-
         self._n_checked += assert_no_cross_chain_collectives(
             hlo_text, self.mesh
         )
 
-    def next_chunk(self, shards, counts, eps, state, keys):
-        # the per-chunk HLO assert: lazily, once per chunk shape, and only
-        # outside a trace (the fused program scans this method — its whole-
-        # run HLO is asserted by run_fused instead)
-        if self._check_hlo and not _is_traced(shards, eps, state, keys):
-            shape_key = ("chunk", keys.shape)
+    def _run(self, name: str, prog, *args):
+        # the HLO assert: lazily, once per program and input shape, and only
+        # outside a trace (the fused program calls setup and next_chunk
+        # under its own trace — its whole-run HLO is asserted by run_fused)
+        if self._check_hlo and not _is_traced(*args):
+            shape_key = (name,) + tuple(
+                (x.shape, x.dtype) for x in jax.tree.leaves(args)
+            )
             if shape_key not in self._checked:
                 self._checked.add(shape_key)
-                compiled = self._chunk.lower(
-                    shards, counts, eps, state, keys
-                ).compile()
-                self._assert_hlo(compiled.as_text())
-        return self._chunk(shards, counts, eps, state, keys)
+                self._assert_hlo(prog.lower(*args).compile().as_text())
+        return prog(*args)
+
+    def setup(self, shards, counts, keys):
+        return self._run("setup", self._setup, shards, counts, keys)
+
+    def next_chunk(self, shards, counts, eps, state, keys):
+        return self._run("chunk", self._chunk, shards, counts, eps, state, keys)
 
     def _put(self, tree, specs):
         from jax.sharding import NamedSharding
@@ -405,6 +365,19 @@ class MeshChunkBackend:
                 self._assert_hlo(compiled.as_text())
             self._fused[prog_key] = compiled
         return compiled(shards, counts, keys)
+
+
+def auto_mesh_shape(
+    mesh_shape: Optional[Sequence[int]], num_shards: int
+) -> Optional[Sequence[int]]:
+    """The mesh a stage of ``num_shards`` chains runs on: ``mesh_shape`` if
+    given, else a ``(ndev, 1)`` mesh when more than one device is visible
+    and the chains divide evenly over them, else ``None`` (the vmap
+    backend)."""
+    ndev = jax.device_count()
+    if mesh_shape is None and ndev > 1 and num_shards % ndev == 0:
+        return (ndev, 1)
+    return mesh_shape
 
 
 # Per-process backend cache, keyed by every compile-relevant static (plus

@@ -54,12 +54,14 @@ from repro.api.pipeline import (
     groundtruth_step_size,
     resolve_metric,
 )
+from repro.api.backends import BackendId
+from repro.api.hlo import assert_no_cross_chain_collectives
 from repro.api.sampling import (
+    _shard_axes,
+    _whole_chain,
     chain_mesh,
     is_padded,
-    _shard_axes,
     make_shard_kernel,
-    run_shard_chain,
 )
 from repro.core.subposterior import partition_data
 from repro.models.bayes import get_model
@@ -135,7 +137,7 @@ class ExecutableCache:
             T, burn, warm = spec.T, spec.resolved_burn_in(), spec.warmup
 
             def run(shards, counts, keys, step_size):
-                one = lambda s, c, k: run_shard_chain(
+                one = lambda s, c, k: _whole_chain(
                     sk, s, c, k,
                     num_samples=T, burn_in=burn, warmup=warm,
                     step_size=step_size,
@@ -163,7 +165,7 @@ class ExecutableCache:
             gt_T, warm = spec.groundtruth_T, spec.warmup
 
             def run(data, key, step_size):
-                theta, _ = run_shard_chain(
+                theta, _ = _whole_chain(
                     sk, data, jnp.zeros((), jnp.int32), key,
                     num_samples=gt_T, burn_in=gt_T // 6, warmup=warm,
                     step_size=step_size,
@@ -204,8 +206,6 @@ def _fanout_sample(
     cells must stay independent on the mesh. Returns the program count.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from repro.distributed.epmcmc import assert_no_cross_chain_collectives
 
     ndev = jax.device_count()
     if ndev < 2:
@@ -378,8 +378,6 @@ def run_matrix(
         if verbose:
             print(f"# cell {spec.spec_id} ({spec.model}/{spec.resolved_sampler()}) "
                   f"done in {time.time() - t0:.1f}s", flush=True)
-
-    from repro.api.backends import BackendId  # late: backends pulls sampling
 
     backend_id = (
         BackendId.mesh_fanout(jax.device_count())

@@ -29,16 +29,13 @@ scoreboard trajectory, and finalizes estimates that are bitwise the
 gather-then-combine result for the buffered combiners. Which *execution
 backend* emits the chunks is a :mod:`repro.api.backends` decision: the
 vmap backend on one device, or — ``mesh_shape`` (explicit or the >1-device
-auto-mesh) — the mesh chunk backend, which ``shard_map``\\ s the same chunk
-programs over chain groups and asserts each compiled program's HLO
-collective-free across chains. A mesh spec with no stream/checkpoint
-request keeps the historical one-shot ``shard_map`` program
-(whole-chain HLO assert, ``backend="shard_map(N devices)"``).
+auto-mesh, :func:`repro.api.backends.auto_mesh_shape`) — the mesh chunk
+backend, which ``shard_map``\\ s the same chunk programs over chain groups
+and asserts each compiled program's HLO collective-free across chains.
 
-The batch combination stage dispatches through
-:func:`repro.distributed.epmcmc.combine_gathered` — the same registry-name
-backend the mesh EP-MCMC run uses — so scenario code and the distributed
-runtime share one combine path.
+The batch combination stage resolves each combiner by registry name
+(:func:`repro.core.combiners.get_combiner`), with the options each one's
+signature declares.
 """
 
 from __future__ import annotations
@@ -51,8 +48,9 @@ from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Tu
 import jax
 import jax.numpy as jnp
 
+from repro.api.backends import auto_mesh_shape
 from repro.api.spec import RunSpec
-from repro.api.sampling import groundtruth_chain, sample_subposteriors
+from repro.api.sampling import groundtruth_chain
 from repro.api.streaming import StreamChunk, stream_sample
 from repro.core import metrics
 from repro.core.subposterior import partition_data
@@ -120,13 +118,10 @@ def combine_spec_draws(
     scoreboard entries, and it also makes each combiner's result independent
     of which subset ``names`` selects); options merge the spec's
     ``combiner_options`` over the driver defaults and are filtered per
-    combiner signature by the ``combine_gathered`` backend. The call is the
+    combiner signature (:func:`combine_draws`). The call is the
     ``combine.stage`` span (:mod:`repro.utils.spans`), each combiner a
     ``combine.<name>`` span inside it.
     """
-    # late import — epmcmc pulls the heavy LM stack
-    from repro.distributed.epmcmc import combine_gathered
-
     theta = gather_draws(theta)
     kc = jax.random.fold_in(base_key, 3)
     options = dict({"rescale": True, "n_batch": 1}, **dict(spec.combiner_options))
@@ -134,7 +129,7 @@ def combine_spec_draws(
     for name in names if names is not None else spec.combiner_names():
         k_name = jax.random.fold_in(kc, zlib.crc32(name.encode()) & 0x7FFFFFFF)
         with span(f"combine.{name}"):
-            out[name] = combine_gathered(
+            out[name] = combine_draws(
                 k_name, theta, spec.T, combiner=name, **options
             )
     return out
@@ -307,90 +302,51 @@ class Pipeline:
         included (:meth:`stream_combine` is the built-in subscriber).
 
         Backend routing: the chunk-emitting driver
-        (:func:`repro.api.streaming.stream_sample`) everywhere, on the
-        backend the spec's ``mesh_shape`` selects (explicit, or the
-        >1-device auto-mesh when M divides evenly): mesh specs that
-        stream/checkpoint run the chunked mesh backend with per-program HLO
-        asserts; mesh specs with no stream/checkpoint request keep the
-        historical one-shot ``shard_map`` program and its whole-chain HLO
-        assert.
+        (:func:`repro.api.streaming.stream_sample`) on the backend the
+        spec's ``mesh_shape`` selects (explicit, or the >1-device auto-mesh
+        when M divides evenly), with per-program HLO asserts on a mesh.
         """
         if self._draws is not None and self._draws.complete:
             return self._draws
+        if max_steps is not None and self.checkpoint_dir is None:
+            raise ValueError(
+                "max_steps needs a checkpoint_dir: a partial sampling "
+                "stage is only useful if it can be resumed"
+            )
         spec = self.spec
-        wants_stream = (
-            spec.stream_every > 0
-            or self.checkpoint_dir is not None
-            or bool(on_chunk)
-        )
         sharded = self.partition()
         with span("pipeline.sample") as rec:
-            ndev = jax.device_count()
-            mesh_shape = spec.mesh_shape
-            if mesh_shape is None and ndev > 1 and spec.M % ndev == 0:
-                mesh_shape = (ndev, 1)
-            use_mesh = mesh_shape is not None and mesh_shape[0] > 1
-            if use_mesh and not wants_stream:
-                if max_steps is not None:
-                    raise ValueError(
-                        "max_steps needs a checkpoint_dir: a partial sampling "
-                        "stage is only useful if it can be resumed"
-                    )
-                res = sample_subposteriors(
-                    jax.random.fold_in(self._key, 1),
-                    self._model,
-                    sharded.data,
-                    spec.M,
-                    spec.T,
-                    sampler=spec.sampler,
-                    warmup=spec.warmup,
-                    burn_in=spec.resolved_burn_in(),
-                    step_size=spec.step_size,
-                    sgld_batch=spec.sgld_batch,
-                    check_hlo=self.check_hlo,
-                    mesh_shape=mesh_shape,
-                    sampler_options=spec.sampler_options,
-                    shards=sharded.shards,
-                    counts=sharded.counts,
-                )
-                t_done, complete = spec.T, True
-            else:
-                if max_steps is not None and self.checkpoint_dir is None:
-                    raise ValueError(
-                        "max_steps needs a checkpoint_dir: a partial sampling "
-                        "stage is only useful if it can be resumed"
-                    )
-                rs = stream_sample(
-                    jax.random.fold_in(self._key, 1),
-                    self._model,
-                    sharded.data,
-                    spec.M,
-                    spec.T,
-                    sampler=spec.sampler,
-                    warmup=spec.warmup,
-                    burn_in=spec.resolved_burn_in(),
-                    step_size=spec.step_size,
-                    sgld_batch=spec.sgld_batch,
-                    sampler_options=spec.sampler_options,
-                    shards=sharded.shards,
-                    counts=sharded.counts,
-                    chunk_size=spec.stream_every,
-                    max_steps=max_steps,
-                    checkpoint_dir=self.checkpoint_dir,
-                    checkpoint_every=self.checkpoint_every,
-                    spec_id=spec.spec_id,
-                    on_chunk=on_chunk,
-                    mesh_shape=mesh_shape if use_mesh else None,
-                    check_hlo=self.check_hlo,
-                )
-                res, t_done, complete = rs.result, rs.t_done, rs.complete
+            rs = stream_sample(
+                jax.random.fold_in(self._key, 1),
+                self._model,
+                sharded.data,
+                spec.M,
+                spec.T,
+                sampler=spec.sampler,
+                warmup=spec.warmup,
+                burn_in=spec.resolved_burn_in(),
+                step_size=spec.step_size,
+                sgld_batch=spec.sgld_batch,
+                sampler_options=spec.sampler_options,
+                shards=sharded.shards,
+                counts=sharded.counts,
+                chunk_size=spec.stream_every,
+                max_steps=max_steps,
+                checkpoint_dir=self.checkpoint_dir,
+                checkpoint_every=self.checkpoint_every,
+                spec_id=spec.spec_id,
+                on_chunk=on_chunk,
+                mesh_shape=auto_mesh_shape(spec.mesh_shape, spec.M),
+                check_hlo=self.check_hlo,
+            )
+            res = rs.result
             # stage timers read the clock after the device finishes, not after
             # the enqueue
             jax.block_until_ready((res.theta, res.accept))
         self.timings["sample_s"] = self.timings.get("sample_s", 0.0) + rec.seconds
         self._draws = SubposteriorDraws(
             res.theta, res.accept, res.counts, res.backend,
-            res.collectives_checked, t_done, complete,
+            res.collectives_checked, rs.t_done, rs.complete,
         )
         return self._draws
 
@@ -772,10 +728,11 @@ def combine_draws(
 ) -> CombineResult:
     """Registry-dispatched combination of a dense ``(M, T, d)`` stack.
 
-    The programmatic face of the combine stage for callers that already
-    hold subposterior draws (e.g. the LM-scale example's low-dim subset
-    history) — same backend as ``Pipeline.combine()``.
+    The combine stage's dispatch (``Pipeline.combine()`` runs every
+    combiner through it), and its programmatic face for callers that
+    already hold subposterior draws. The combiner is resolved by registry
+    name; options its signature does not declare are dropped, so one option
+    dict can drive rival combiners.
     """
-    from repro.distributed.epmcmc import combine_gathered
-
-    return combine_gathered(key, samples, n_draws, combiner=combiner, **options)
+    fn = get_combiner(combiner)
+    return fn(key, samples, n_draws, **filter_options(fn, options))

@@ -1,41 +1,37 @@
-"""The embarrassingly parallel sampling stage behind ``repro.api``.
+"""One subposterior chain, as every sampling driver of ``repro.api`` runs it.
 
-Moved here from ``repro.launch.mcmc_run`` (which now only adapts argparse
-flags onto a :class:`repro.api.RunSpec`) and factored into two layers:
+The stage has three layers, one module each: this module runs one chain,
+:mod:`repro.api.backends` places M chains on the devices, and
+:mod:`repro.api.streaming` drives them.
 
 - :func:`make_shard_kernel` packages one registry sampler for one model as a
   :class:`ShardKernel` — how to draw θ0, how to *build* the kernel from a
   concrete shard and a (possibly traced) step size, and how to project
-  stacked positions back to the shared ``(T, d)`` θ. Because ``build`` is a
-  pure function of ``(shard, count, step_size)``, the same ShardKernel
-  serves three drivers: the one-shot chain here, the chunk-emitting stream
-  driver (:mod:`repro.api.streaming` — checkpointing and combine-while-
-  sampling subscribe to it; it rebuilds the kernel from a checkpointed ε on
-  resume), and the compile-cached matrix runner (:mod:`repro.api.matrix`,
-  which traces ``step_size`` so specs differing only there share one
-  executable).
-- :func:`run_shard_chain` is the per-shard glue — RNG discipline, warmup
-  dispatch, burn-in accounting — shared by every driver so their draws are
-  bitwise identical.
-
-The public entry points keep their historical signatures:
-:func:`make_shard_sampler`, :func:`sample_subposteriors` (vmap on one
-device, ``shard_map`` over the mesh ``data`` axis with the compiled HLO
-asserted collective-free given more), and :func:`groundtruth_chain`.
+  stacked positions back to the shared ``(T, d)`` θ.
+- :func:`_setup_one` and :func:`_chunk_one` are the only code that runs one
+  chain: the per-shard RNG discipline, init, warmup, burn-in, and then the
+  collect of any number of draws from the live kernel state. The chunk
+  backends vmap them over the chains (and ``shard_map`` them over a mesh);
+  :func:`_whole_chain` composes them into one T-draw chain for the
+  full-data :func:`groundtruth_chain` and the matrix runner
+  (:mod:`repro.api.matrix`).
+- :func:`sample_subposteriors` is the whole stage in one call: the chunk
+  driver with one T-draw chunk and no subscriber.
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.subposterior import make_subposterior_logpdf, partition_data
+from repro.core.subposterior import make_subposterior_logpdf
 from repro.models.bayes import BayesModel
-from repro.samplers import filter_options, run_chain, sampler_spec
+from repro.samplers import filter_options, sampler_spec
+from repro.samplers.adaptation import warmup_chain
 from repro.samplers.base import MCMCKernel
-from repro.utils.spans import count, span
+from repro.utils.spans import count
 
 PyTree = Any
 
@@ -58,12 +54,12 @@ class ShardKernel(NamedTuple):
     """One (model, sampler) pairing, ready to instantiate per shard.
 
     ``build(prepare(shard), count, step_size)`` must be pure and accept a
-    traced ``step_size`` — the resumable stream re-invokes it from a
-    checkpointed (possibly warmup-adapted) ε, and the matrix runner from a
-    runtime scalar. ``run_shard_chain`` and the chunk backends call
-    ``prepare`` once per program, before their chain scans: it makes
-    whatever the kernels read of the shard in a form of its own (the
-    model's ``shard_form``), so no scan step makes it.
+    traced ``step_size`` — every chunk rebuilds it from the chain's
+    (possibly warmup-adapted) ε, a resumed stream from a checkpointed one,
+    and the matrix runner from a runtime scalar. :func:`_setup_one` and
+    :func:`_chunk_one` call ``prepare`` once per program, before their
+    chain scans: it makes whatever the kernels read of the shard in a form
+    of its own (the model's ``shard_form``), so no scan step makes it.
     """
 
     init_position: Callable[[jax.Array, PyTree], PyTree]
@@ -247,94 +243,73 @@ def make_shard_kernel(
     )
 
 
-def run_shard_chain(
-    sk: ShardKernel,
-    shard: PyTree,
-    count: jnp.ndarray,
-    key: jax.Array,
-    *,
-    num_samples: int,
-    burn_in: int,
-    warmup: int,
-    step_size: float | jnp.ndarray,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """One subposterior chain: ``(theta (T, d), mean_accept)``.
+def _setup_one(sk: ShardKernel, shard, count, key, *, burn_in, warmup, step_size):
+    """Init, warmup and burn-in of one chain: ``(state, eps, k_collect)``.
 
-    The single source of the per-shard RNG discipline (``k_init, k_run =
-    split(key)``) and of the warmup/burn-in accounting: adaptive kernels
-    spend ``warmup`` dual-averaging transitions, non-adaptive ones treat
-    them as extra burn-in (registry convention).
+    The per-shard RNG discipline: ``k_init, k_run = split(key)``; adaptive
+    kernels split ``k_warm`` off ``k_run`` and spend ``warmup``
+    dual-averaging transitions, non-adaptive ones treat them as extra
+    burn-in (registry convention); burn-in steps on ``split(k_run, burn +
+    1)[1:]`` and leaves the first key to collect with.
     """
     k_init, k_run = jax.random.split(key)
     pos0 = sk.init_position(k_init, shard)
     prepared = sk.prepare(shard)
     if sk.adaptive and warmup > 0:
-        pos, info = run_chain(
-            k_run,
-            lambda eps: sk.build(prepared, count, eps),
+        k_run, k_warm = jax.random.split(k_run)
+        kernel, pos0, eps = warmup_chain(
+            k_warm,
+            lambda e: sk.build(prepared, count, e),
             pos0,
-            num_samples,
-            burn_in=burn_in,
-            warmup=warmup,
+            warmup,
             initial_step_size=step_size,
             target_accept=sk.target_accept,
         )
+        burn = burn_in
     else:
-        kern = sk.build(prepared, count, step_size)
-        pos, info = run_chain(
-            k_run,
-            kern,
-            pos0,
-            num_samples,
-            burn_in=burn_in + (0 if sk.adaptive else warmup),
-        )
-    return sk.extract(pos), info.is_accepted.mean()
+        eps = jnp.asarray(step_size, jnp.float32)
+        kernel = sk.build(prepared, count, step_size)
+        burn = burn_in + (0 if sk.adaptive else warmup)
+    state = kernel.init(pos0)
+    if burn > 0:
+        keys = jax.random.split(k_run, burn + 1)
+        k_run = keys[0]
+
+        def warm(s, k):
+            s, _ = kernel.step(k, s)
+            return s, None
+
+        state, _ = jax.lax.scan(warm, state, keys[1:])
+    return state, eps, k_run
 
 
-def make_shard_sampler(
-    model: BayesModel,
-    num_shards: int,
-    sampler: str,
-    *,
-    num_samples: int,
-    burn_in: int,
-    warmup: int,
-    step_size: float,
-    sgld_batch: int = 256,
-    use_counts: bool = True,
-    sampler_options=(),
-) -> Callable[[PyTree, jnp.ndarray, jax.Array], Tuple[jnp.ndarray, jnp.ndarray]]:
-    """Build ``one_shard(shard, count, key) -> (theta (T, d), mean_accept)``.
+def _chunk_one(sk: ShardKernel, shard, count, eps, state, keys):
+    """Advance one chain by ``len(keys)`` draws from a live kernel state."""
+    kernel = sk.build(sk.prepare(shard), count, eps)
 
-    The returned function is pure and shape-uniform across shards, so the
-    launch layer can drive it under ``vmap`` (one device) or ``shard_map``
-    (chain groups over the mesh data axis) unchanged.
-    """
-    sk = make_shard_kernel(
-        model,
-        num_shards,
-        sampler,
-        sgld_batch=sgld_batch,
-        use_counts=use_counts,
-        sampler_options=sampler_options,
+    def collect(s, k):
+        s, info = kernel.step(k, s)
+        return s, (s.position, info.is_accepted)
+
+    state, (pos, acc) = jax.lax.scan(collect, state, keys)
+    return state, sk.extract(pos), acc.astype(jnp.float32).sum()
+
+
+def _whole_chain(sk: ShardKernel, shard, count, key, *, num_samples, burn_in,
+                 warmup, step_size):
+    """One chain of ``num_samples`` kept draws: ``(theta (T, d),
+    mean_accept)``. The setup, then one collect on ``split(k_collect, T)``
+    — the keys the chunk drivers hand out, chunk by chunk."""
+    state, eps, k_collect = _setup_one(
+        sk, shard, count, key,
+        burn_in=burn_in, warmup=warmup, step_size=step_size,
     )
-
-    def one_shard(shard, count, key):
-        return run_shard_chain(
-            sk,
-            shard,
-            count,
-            key,
-            num_samples=num_samples,
-            burn_in=burn_in,
-            warmup=warmup,
-            step_size=step_size,
-        )
-
-    return one_shard
+    _, theta, acc = _chunk_one(
+        sk, shard, count, eps, state, jax.random.split(k_collect, num_samples)
+    )
+    return theta, acc / max(num_samples, 1)
 
 
-@span("sample.stage")
 def sample_subposteriors(
     key: jax.Array,
     model: BayesModel,
@@ -355,60 +330,35 @@ def sample_subposteriors(
 ) -> SampleResult:
     """The embarrassingly parallel stage: M independent subposterior chains.
 
-    Partitions ``data`` (edge-padded — non-divisible N is fine), then runs
-    one chain per shard; a caller that already partitioned (e.g.
-    ``Pipeline.partition()``'s artifact) passes ``shards``/``counts`` to
-    skip the duplicate copy. With >1 local device and ``num_shards``
-    divisible by the device count, chains are ``shard_map``-ped over the
-    ``data`` axis of a ``(ndev, 1)`` ("data", "model") mesh (override via
-    ``mesh_shape``) and the compiled HLO is asserted collective-free across
-    chains; otherwise the chains are vmapped on one device. Zero cross-chain
-    communication either way. The call is the ``sample.stage`` span
-    (:mod:`repro.utils.spans`); its ``steps`` count each chain's transitions,
-    its ``loglik_fused`` those whose gradient came from the fused pass.
+    :func:`repro.api.streaming.stream_sample` with one T-draw chunk and no
+    subscriber, on the mesh :func:`repro.api.backends.auto_mesh_shape`
+    picks: ``mesh_shape``, else a ``(ndev, 1)`` mesh when more than one
+    device is visible and ``num_shards`` divides over them, else the vmap
+    backend. Partitions ``data`` unless the caller passes ``shards`` and
+    ``counts``. On a mesh every compiled program's HLO is asserted
+    collective-free across chains (``check_hlo=False`` skips the assert).
     """
-    sampler = sampler or model.default_sampler
-    if shards is None or counts is None:
-        shards, counts = partition_data(
-            data, num_shards, only=model.shard_keys, pad=True
-        )
-    padded = is_padded(model, shards, counts, sampler)
-    one_shard = make_shard_sampler(
+    # late imports: both modules import this one
+    from repro.api.backends import auto_mesh_shape
+    from repro.api.streaming import stream_sample
+
+    return stream_sample(
+        key,
         model,
+        data,
         num_shards,
-        sampler,
-        num_samples=num_samples,
-        burn_in=burn_in,
+        num_samples,
+        sampler=sampler,
         warmup=warmup,
+        burn_in=burn_in,
         step_size=step_size,
         sgld_batch=sgld_batch,
-        # divisible N ⇒ every row is real ⇒ skip the pad correction entirely
-        use_counts=padded,
         sampler_options=sampler_options,
-    )
-    count_steps(
-        warmup + burn_in + num_samples,
-        loglik_fused(model, sampler, shard_rows(model, shards, axis=1)),
-    )
-    keys = jax.random.split(key, num_shards)
-    in_axes = (_shard_axes(shards, model.shard_keys, 0, None), 0, 0)
-    vmapped = jax.vmap(one_shard, in_axes=in_axes)
-
-    # late import: backends imports this module (kernel layer) at load time
-    from repro.api.backends import BackendId
-
-    ndev = jax.device_count()
-    if mesh_shape is None and ndev > 1 and num_shards % ndev == 0:
-        mesh_shape = (ndev, 1)
-    if mesh_shape is not None and mesh_shape[0] > 1:
-        theta, acc, checked = _sample_on_mesh(
-            vmapped, shards, counts, keys, model, mesh_shape, check_hlo
-        )
-        return SampleResult(
-            theta, acc, counts, BackendId.mesh(mesh_shape[0]), checked
-        )
-    theta, acc = jax.jit(vmapped)(shards, counts, keys)
-    return SampleResult(theta, acc, counts, BackendId.vmap(), None)
+        shards=shards,
+        counts=counts,
+        mesh_shape=auto_mesh_shape(mesh_shape, num_shards),
+        check_hlo=check_hlo,
+    ).result
 
 
 def is_padded(model, shards, counts, sampler) -> bool:
@@ -443,39 +393,14 @@ def chain_mesh(shape: Tuple[int, ...], axis_names: Tuple[str, ...]):
     )
 
 
-def _sample_on_mesh(vmapped, shards, counts, keys, model, mesh_shape, check_hlo):
-    """shard_map the vmapped per-shard sampler over the mesh data axis.
+def _freeze_options(options) -> Tuple:
+    """A hashable form of an options mapping (or pair sequence), for cache keys."""
+    items = options.items() if hasattr(options, "items") else options
+    return tuple(sorted((str(k), v) for k, v in items))
 
-    Each device owns ``M/ndev`` chains + their data shards; broadcast leaves
-    are replicated. The jitted program is lowered AOT so the post-SPMD HLO
-    can be asserted collective-free *before* it runs — the machine-checked
-    "embarrassingly parallel" property.
-    """
-    from jax.sharding import NamedSharding, PartitionSpec as P
 
-    # late import: epmcmc pulls the (heavy) LM stack this path otherwise skips
-    from repro.distributed.epmcmc import assert_no_cross_chain_collectives
-
-    mesh = chain_mesh(mesh_shape, ("data", "model"))
-    shard_specs = _shard_axes(shards, model.shard_keys, P("data"), P())
-    body = jax.shard_map(
-        vmapped,
-        mesh=mesh,
-        in_specs=(shard_specs, P("data"), P("data")),
-        out_specs=(P("data"), P("data")),
-        check_vma=False,
-    )
-    compiled = jax.jit(body).lower(shards, counts, keys).compile()
-    checked = None
-    if check_hlo:
-        checked = assert_no_cross_chain_collectives(compiled.as_text(), mesh)
-    put = lambda tree, specs: jax.tree.map(
-        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), tree, specs
-    )
-    theta, acc = compiled(
-        put(shards, shard_specs), put(counts, P("data")), put(keys, P("data"))
-    )
-    return theta, acc, checked
+# full-data chain programs, one per signature
+_GROUNDTRUTH_CACHE: Dict[Tuple, Callable] = {}
 
 
 def groundtruth_chain(
@@ -491,18 +416,29 @@ def groundtruth_chain(
     sgld_batch: int = 256,
     sampler_options=(),
 ) -> jnp.ndarray:
-    """Single full-data chain (num_shards=1) with the same sampler surface."""
-    one = make_shard_sampler(
-        model,
-        1,
-        sampler or model.default_sampler,
-        num_samples=num_samples,
-        burn_in=burn_in,
-        warmup=warmup,
-        step_size=step_size,
-        sgld_batch=sgld_batch,
-        use_counts=False,  # full data: every row is real
-        sampler_options=sampler_options,
+    """Single full-data chain (num_shards=1) with the same sampler surface:
+    :func:`_whole_chain` in one program, obtained once per signature."""
+    sampler = sampler or model.default_sampler
+    sig = (
+        model.name, sampler, num_samples, warmup, burn_in, float(step_size),
+        sgld_batch, _freeze_options(sampler_options),
     )
-    theta, _ = jax.jit(lambda k: one(data, jnp.zeros((), jnp.int32), k))(key)
-    return theta
+    run = _GROUNDTRUTH_CACHE.get(sig)
+    if run is None:
+        sk = make_shard_kernel(
+            model, 1, sampler,
+            sgld_batch=sgld_batch,
+            use_counts=False,  # full data: every row is real
+            sampler_options=sampler_options,
+        )
+
+        def chain(full, k):
+            theta, _ = _whole_chain(
+                sk, full, jnp.zeros((), jnp.int32), k,
+                num_samples=num_samples, burn_in=burn_in, warmup=warmup,
+                step_size=step_size,
+            )
+            return theta
+
+        run = _GROUNDTRUTH_CACHE[sig] = jax.jit(chain)
+    return run(data, key)

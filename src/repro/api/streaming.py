@@ -1,18 +1,17 @@
 """The one chunk-emitting sampling driver behind every ``repro.api`` run.
 
-Historically the sampling stage had two bodies: the one-shot ``lax.scan``
-drivers in :mod:`repro.api.sampling` and a separate chunked loop in
-:mod:`repro.api.resumable`. This module is the merge: **one** generator
-(:meth:`ShardChainStream.chunks`) advances all M chains in global chunks and
-yields each landed ``(M, C, d)`` slice, and everything else subscribes —
+**One** generator (:meth:`ShardChainStream.chunks`) advances all M chains in
+global chunks and yields each landed ``(M, C, d)`` slice, and everything
+else subscribes —
 
-- checkpoint persistence (:mod:`repro.api.resumable` is now a thin wrapper
-  that adds restore/validation and a save-at-boundary subscriber);
+- checkpoint persistence (restore/validation in :func:`stream_sample` and a
+  save-at-boundary subscriber);
 - streaming combination (``Pipeline.stream_combine`` folds every chunk into
   the registered :class:`~repro.core.combiners.api.StreamingCombiner`\\ s);
-- the plain sampling stage (one chunk of T draws when neither is asked for).
+- the plain sampling stage (one chunk of T draws when neither is asked for:
+  ``Pipeline.sample`` and :func:`repro.api.sampling.sample_subposteriors`).
 
-The bitwise-resume guarantee is unchanged and structural: per-step RNG keys
+The bitwise-resume guarantee is structural: per-step RNG keys
 are a pure function of the seed, chunk boundaries are global multiples of
 the cadence, and sessions advance in whole chunks — so an interrupted-then-
 resumed run replays exactly the same chunk programs on the same inputs as
@@ -63,19 +62,10 @@ import jax.numpy as jnp
 from repro.checkpoint import latest_step, restore, save
 from repro.core.subposterior import partition_data
 from repro.models.bayes import BayesModel
-from repro.api.backends import (  # noqa: F401  (historical homes re-exported)
-    CHUNKED,
-    FUSED,
-    RESUMABLE,
-    BackendId,
-    _chunk_one,
-    _freeze_options,
-    _setup_one,
-    get_chunk_backend,
-)
+from repro.api.backends import CHUNKED, FUSED, RESUMABLE, get_chunk_backend
 from repro.api.sampling import (
     SampleResult,
-    ShardKernel,
+    _freeze_options,
     count_steps,
     is_padded,
     loglik_fused,
@@ -308,7 +298,7 @@ class ShardChainStream:
 
 
 class StreamedSample(NamedTuple):
-    """Outcome of :func:`stream_sample` (superset of the resumable artifact)."""
+    """Outcome of :func:`stream_sample`: the stage's result and how far it got."""
 
     result: SampleResult
     t_done: int

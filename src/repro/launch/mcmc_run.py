@@ -22,7 +22,7 @@ over the ``data`` axis of a mesh, one chain group per device
 (``--mesh-shape``, or automatic when the device count divides ``--M``).
 Either way the stage contains zero cross-chain collectives; on the mesh
 path this is *asserted on the compiled HLO* via
-:func:`repro.distributed.epmcmc.assert_no_cross_chain_collectives` — the
+:func:`repro.api.hlo.assert_no_cross_chain_collectives` — the
 paper's "embarrassingly parallel" claim, machine-checked per run. Since the
 :mod:`repro.api.backends` unification the mesh composes with
 ``--stream-every`` and ``--checkpoint-dir``: chunk programs run on the mesh
@@ -35,8 +35,8 @@ readers (``--serve-readers`` self-probes, plus any external
 and machine-KDE log density with staleness metadata on every response.
 
 The sampling engine itself lives in :mod:`repro.api.sampling`; the historical
-module-level names (``make_shard_sampler``, ``sample_subposteriors``,
-``groundtruth_chain``, ``SampleResult``) are re-exported here with a
+module-level names (``sample_subposteriors``, ``groundtruth_chain``,
+``SampleResult``) are re-exported here with a
 ``DeprecationWarning`` — import them from ``repro.api`` instead.
 """
 
@@ -55,11 +55,9 @@ from repro.utils.compile_cache import enable_compile_cache
 # importing this CLI module stays cheap and old imports keep working (warned)
 _MOVED = (
     "SampleResult",
-    "make_shard_sampler",
     "sample_subposteriors",
     "groundtruth_chain",
     "_shard_axes",
-    "_sample_on_mesh",
     "LOG_L2_DIM",
 )
 

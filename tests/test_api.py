@@ -200,6 +200,84 @@ def test_sampler_options_reach_the_kernel_factory():
     assert bool(jnp.all(base.theta == ignored.theta))
 
 
+def test_sample_subposteriors_is_the_chunk_driver_with_one_chunk():
+    """The one-call stage runs the chunk driver: bitwise its draws, one
+    ``sample.stage`` record per call, and a second call with the same
+    statics obtains no executable (its programs are cached per backend)."""
+    from repro.api import sample_subposteriors, stream_sample
+    from repro.models.bayes import get_model
+    from repro.utils import spans
+
+    model = get_model("linear")
+    data, _ = model.generate_data(jax.random.PRNGKey(0), 256)
+    kw = dict(sampler="mala", warmup=8, burn_in=4, step_size=0.05)
+    stages = lambda: [r for r in spans.records() if r.name == "sample.stage"]
+
+    want = stream_sample(
+        jax.random.PRNGKey(1), model, data, 4, 20, chunk_size=0, **kw
+    ).result
+    n0 = len(stages())
+    got = sample_subposteriors(jax.random.PRNGKey(1), model, data, 4, 20, **kw)
+    again = sample_subposteriors(jax.random.PRNGKey(2), model, data, 4, 20, **kw)
+    recs = stages()
+    assert len(recs) == n0 + 2  # exactly one stage record per call
+    assert got.backend == want.backend == "vmap[chunked]"
+    assert got.collectives_checked is None
+    assert bool(jnp.all(got.theta == want.theta))
+    assert bool(jnp.all(got.accept == want.accept))
+    assert not bool(jnp.all(again.theta == got.theta))  # a new key's chains
+    assert recs[-1].counters.get("executables", 0) == 0
+    assert recs[-1].counters["steps"] == 8 + 4 + 20
+
+
+_IMPORTS_SCRIPT = """
+import sys
+import jax
+from repro.api import Pipeline, RunSpec
+
+spec = RunSpec(model="poisson", sampler="gibbs", M=2, T=20, warmup=0, n=200,
+               groundtruth_T=40, combiner=("parametric", "nonparametric"))
+board = Pipeline(spec).run()
+assert set(board.errors) == {"parametric", "nonparametric"}
+print("LOADED=" + ",".join(sorted(
+    m for m in sys.modules
+    if m.startswith(("repro.models.lm", "repro.distributed"))
+)))
+"""
+
+
+def test_a_pipeline_run_imports_no_lm_or_distributed_module():
+    """The EP-MCMC core (sample, combine, score) stands below the LM side
+    path: no module under ``repro.api`` imports ``repro.distributed``, and
+    a whole Pipeline run leaves ``repro.models.lm`` unimported."""
+    import os
+    import pathlib
+    import re
+    import subprocess
+    import sys
+
+    import repro.api
+
+    api_dir = pathlib.Path(repro.api.__file__).parent
+    for path in sorted(api_dir.glob("*.py")):
+        assert not re.search(
+            r"^\s*(from|import)\s+repro\.distributed", path.read_text(), re.M
+        ), f"{path.name} imports repro.distributed"
+
+    src = str(api_dir.parents[1])
+    env = dict(
+        os.environ, JAX_PLATFORMS="cpu",
+        PYTHONPATH=src + os.pathsep + os.environ.get("PYTHONPATH", ""),
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", _IMPORTS_SCRIPT], capture_output=True,
+        text=True, env=env, timeout=300,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    line = [ln for ln in out.stdout.splitlines() if ln.startswith("LOADED=")][-1]
+    assert line == "LOADED=", line
+
+
 # ---------------------------------------------------------------------------
 # run_matrix: compile-cache accounting + Pipeline agreement
 # ---------------------------------------------------------------------------

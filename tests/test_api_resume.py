@@ -5,10 +5,10 @@ reproduces the uninterrupted run **bitwise**. Chunk boundaries are global
 (k·checkpoint_every), sessions advance in whole chunks, and the per-step RNG
 keys derive from the spec seed alone — so resume replays exactly the same
 chunk programs on the same inputs as a run that was never interrupted. The
-chunked driver is additionally cross-checked against the one-shot vmap
-backend (numerically: XLA may fuse the one-big-scan program differently at
-the last ulp, which is why the bitwise contract is defined against the
-uninterrupted *chunked* run).
+chunked driver is additionally cross-checked against the plain stage's one
+T-draw chunk (numerically: XLA may fuse one big scan differently at the
+last ulp, which is why the bitwise contract is defined against the
+uninterrupted run at the same cadence).
 """
 
 import jax.numpy as jnp
@@ -31,8 +31,8 @@ SPEC = RunSpec(
 
 
 def test_interrupt_resume_is_bitwise_identical(tmp_path):
-    # uninterrupted references: chunked driver (the bitwise contract) and
-    # the one-shot vmap backend (numerical cross-check of the chunking)
+    # uninterrupted references: the same cadence (the bitwise contract) and
+    # one T-draw chunk (numerical cross-check of the chunking)
     uninterrupted = Pipeline(
         SPEC, checkpoint_dir=tmp_path / "ref", checkpoint_every=20
     ).sample()
@@ -57,7 +57,7 @@ def test_interrupt_resume_is_bitwise_identical(tmp_path):
     assert bool(jnp.all(full.theta == uninterrupted.theta))
     assert bool(jnp.all(full.accept == uninterrupted.accept))
     assert bool(jnp.all(partial.theta == uninterrupted.theta[:, :20]))
-    # and the chunked trajectory is the one-shot trajectory numerically
+    # and the chunked trajectory is the one-chunk trajectory numerically
     assert bool(jnp.allclose(full.theta, plain.theta, atol=1e-5))
 
 
@@ -118,6 +118,6 @@ def test_resumable_supports_gibbs_extended_positions(tmp_path):
     ).sample()
     assert full.theta.shape == (4, 40, 2)
     assert bool(jnp.all(full.theta == uninterrupted.theta))
-    # one-shot path agrees numerically (fusion may differ at the last ulp)
+    # one T-draw chunk agrees numerically (fusion may differ at the last ulp)
     plain = Pipeline(spec).sample()
     assert bool(jnp.allclose(full.theta, plain.theta, atol=1e-4))

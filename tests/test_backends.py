@@ -2,7 +2,7 @@
 
 The BackendId spellings are load-bearing — ``Scoreboard.backend`` strings
 are pinned by tests across the repo (``"vmap[resumable]"``,
-``"shard_map(4 devices)"``, …) and this module is their single
+``"shard_map[chunked](4 devices)"``, …) and this module is their single
 constructor. Everything here runs single-device; the mesh backend's
 *execution* is covered by tests/test_mesh_stream.py under a forced
 multi-device subprocess.
@@ -18,7 +18,7 @@ from repro.models.bayes import get_model
 
 
 # ---------------------------------------------------------------------------
-# BackendId — the exact strings, historical ones included
+# BackendId — the exact strings every backend reports
 # ---------------------------------------------------------------------------
 
 
@@ -30,11 +30,12 @@ def test_backend_id_vmap_spellings():
 
 
 def test_backend_id_mesh_spellings():
-    # the one-shot spelling predates the backend layer — load-bearing
-    assert BackendId.mesh(4) == "shard_map(4 devices)"
+    # every mesh stage runs the chunk driver, so a mesh id names its mode
     assert BackendId.mesh(4, CHUNKED) == "shard_map[chunked](4 devices)"
     assert BackendId.mesh(2, FUSED) == "shard_map[fused](2 devices)"
     assert BackendId.mesh(2, RESUMABLE) == "shard_map[resumable](2 devices)"
+    with pytest.raises(ValueError, match="reports its mode"):
+        BackendId.mesh(4, None)
 
 
 def test_backend_id_fanout_and_distributed_spellings():

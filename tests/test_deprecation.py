@@ -57,8 +57,6 @@ def test_mcmc_run_internals_warn_and_forward_to_api():
     from repro.api import sampling
 
     with pytest.warns(DeprecationWarning, match="repro.api"):
-        assert mcmc_run.make_shard_sampler is sampling.make_shard_sampler
-    with pytest.warns(DeprecationWarning, match="repro.api"):
         assert mcmc_run.sample_subposteriors is sampling.sample_subposteriors
     with pytest.warns(DeprecationWarning, match="repro.api"):
         assert mcmc_run.SampleResult is sampling.SampleResult

@@ -16,9 +16,10 @@ import pytest
 
 import repro.kernels.logreg_loglik.ops as ops
 from repro.api.sampling import (
+    _chunk_one,
+    _setup_one,
     loglik_fused,
     make_shard_kernel,
-    run_shard_chain,
 )
 from repro.api.streaming import stream_sample
 from repro.core.subposterior import make_subposterior_logpdf, partition_data
@@ -108,10 +109,19 @@ def test_vmap_over_chains():
 
 def _mala_draws(model, shards, counts, m):
     sk = make_shard_kernel(model, 2, "mala", use_counts=True)
-    run = jax.jit(lambda sh, c, k: run_shard_chain(
-        sk, sh, c, k, num_samples=12, burn_in=3, warmup=0, step_size=0.01))
+
+    def run(sh, c, k):
+        # the one-chain pair every sampling driver runs
+        state, eps, k_collect = _setup_one(
+            sk, sh, c, k, burn_in=3, warmup=0, step_size=0.01
+        )
+        _, theta, acc = _chunk_one(
+            sk, sh, c, eps, state, jax.random.split(k_collect, 12)
+        )
+        return theta, acc / 12
+
     shard = jax.tree.map(lambda a: a[m], shards)
-    return run(shard, counts[m], jax.random.PRNGKey(7))
+    return jax.jit(run)(shard, counts[m], jax.random.PRNGKey(7))
 
 
 def test_a_short_mala_chain_matches_the_jnp_path(monkeypatch):

@@ -25,6 +25,10 @@ What the scorecard pins, per ISSUE 7:
 - **run_matrix(backend="mesh_fanout")** executes 8 independent cells over
   mesh slices through ONE fanned-out program and reproduces the vmap
   sweep's scoreboard bitwise.
+- **the one-call stage on the mesh is the chunk driver**:
+  ``sample_subposteriors`` reports ``shard_map[chunked](4 devices)``, has
+  asserted both its setup and its chunk program, and a second call with
+  the same statics obtains no executable.
 """
 
 import json
@@ -126,6 +130,30 @@ _SCRIPT = textwrap.dedent("""
     )
     out["fanout_n_rows"] = len(res_f.rows)
 
+    # -- the one-call stage on the mesh: the chunk driver, cached ---------
+    from repro.api import get_chunk_backend, sample_subposteriors
+    from repro.core.subposterior import partition_data
+    from repro.models.bayes import get_model
+    from repro.utils import spans
+
+    model = get_model("poisson")
+    data, _ = model.generate_data(jax.random.PRNGKey(0), 512)
+    kw = dict(sampler="gibbs", warmup=0, burn_in=5, step_size=0.1)
+    calls = [
+        sample_subposteriors(jax.random.PRNGKey(s), model, data, 4, 20, **kw)
+        for s in (1, 2)
+    ]
+    stage = [r for r in spans.records() if r.name == "sample.stage"][-1]
+    shards, _ = partition_data(data, 4, only=model.shard_keys, pad=True)
+    be = get_chunk_backend(model, 4, "gibbs", use_counts=False, shards=shards,
+                           mesh_shape=(4, 1), **{k: v for k, v in kw.items()
+                                                 if k != "sampler"})
+    out["oneshot_backends"] = [r.backend for r in calls]
+    out["oneshot_collectives_checked"] = calls[-1].collectives_checked
+    out["oneshot_theta_shape"] = list(calls[-1].theta.shape)
+    out["oneshot_programs_checked"] = sorted({k[0] for k in be._checked})
+    out["oneshot_second_executables"] = stage.counters.get("executables", 0)
+
     print("SCORECARD=" + json.dumps(out))
 """)
 
@@ -211,6 +239,17 @@ def test_mesh_checkpoint_resume_bitwise(scorecard):
     assert scorecard["resume_partial_t"] == 40
     assert scorecard["resume_bitwise"] is True
     assert scorecard["resume_backend"] == "shard_map[resumable](4 devices)"
+
+
+def test_one_call_mesh_stage_asserts_setup_and_chunk_programs(scorecard):
+    assert scorecard["oneshot_backends"] == ["shard_map[chunked](4 devices)"] * 2
+    assert scorecard["oneshot_collectives_checked"] is not None
+    assert scorecard["oneshot_theta_shape"] == [4, 20, 2]
+    assert scorecard["oneshot_programs_checked"] == ["chunk", "setup"]
+
+
+def test_second_one_call_mesh_stage_obtains_no_executable(scorecard):
+    assert scorecard["oneshot_second_executables"] == 0
 
 
 def test_mesh_fanout_matrix_reproduces_vmap_sweep(scorecard):

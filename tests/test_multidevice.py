@@ -83,9 +83,9 @@ print(json.dumps({
 
 @pytest.mark.slow
 def test_sampling_stage_shard_maps_with_no_cross_chain_collectives():
-    """The mcmc_run sampling stage on a forced 4-device mesh: shard_map
-    backend, compiled-HLO collective check passes, chains produce finite
-    (M, T, d) θ — the tentpole's acceptance criterion, in CI."""
+    """The mcmc_run sampling stage on a forced 4-device mesh: the chunked
+    shard_map backend, compiled-HLO collective check passes, chains produce
+    finite (M, T, d) θ."""
     env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-c", _SAMPLER_SCRIPT], capture_output=True, text=True,
@@ -95,7 +95,7 @@ def test_sampling_stage_shard_maps_with_no_cross_chain_collectives():
     assert out.returncode == 0, out.stderr[-2000:]
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     assert rec["devices"] == 4
-    assert rec["backend"] == "shard_map(4 devices)"
+    assert rec["backend"] == "shard_map[chunked](4 devices)"
     assert rec["collectives_checked"] is not None  # HLO assert actually ran
     assert rec["theta_shape"] == [4, 100, 2]
     assert rec["finite"] is True
